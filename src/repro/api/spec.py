@@ -31,6 +31,8 @@ POLICIES = ("continuous", "deadline", "static")
 PLACEMENTS = ("least-loaded", "affinity", "round-robin", "class-affinity")
 QMODES = ("none", "f32", "f16", "int8")
 QUANT_BITS = (4, 8, 16)
+# model sizes: the reduced CPU preset, or the registered config as published
+WIDTHS = ("smoke", "published")
 # server-pool KV storage dtype: "int8" stores pool rows quantized with
 # per-(slot, layer, head) dequant scales (core/engine.py KV_DTYPES)
 KV_DTYPES = ("bf16", "int8")
@@ -53,7 +55,14 @@ def _check(cond: bool, msg: str) -> None:
 
 @dataclasses.dataclass(frozen=True)
 class ModelSpec:
-    """The draft/target model pair (reduced configs, deterministic init).
+    """The draft/target model pair (registered configs, deterministic init).
+
+    ``widths`` picks the size of both configs: ``"smoke"`` (the default) is
+    the config's reduced same-family preset (d_model 64, 4 heads) for CPU
+    runs; ``"published"`` is the registered config as published — every
+    width and its vocabulary.  ``target_layers``/``draft_layers`` cut depth
+    only.  ``vocab_size`` overrides the vocabulary of a smoke pair; None
+    keeps the config's own, and published widths require None.
 
     ``seed`` keys the target's init params; the draft uses ``seed + 1`` —
     one integer pins the whole weight state, which is what makes a spec a
@@ -64,8 +73,9 @@ class ModelSpec:
 
     arch: str = "qwen2-1.5b"
     draft_arch: str = "qwen2-1.5b"
-    vocab_size: int = 256
-    target_layers: Optional[int] = None  # None: the reduced config's own depth
+    widths: str = "smoke"
+    vocab_size: Optional[int] = 256
+    target_layers: Optional[int] = None  # None: the config's own depth
     draft_layers: Optional[int] = 1
     bits: int = 16
     draft_noise: float = 0.0
@@ -74,7 +84,16 @@ class ModelSpec:
     def validate(self) -> None:
         _check(bool(self.arch), "model.arch must name a config")
         _check(bool(self.draft_arch), "model.draft_arch must name a config")
-        _check(self.vocab_size >= 8, f"model.vocab_size {self.vocab_size} too small")
+        _check(self.widths in WIDTHS, f"model.widths {self.widths!r} not in {WIDTHS}")
+        _check(
+            self.vocab_size is None or self.vocab_size >= 8,
+            f"model.vocab_size {self.vocab_size} too small",
+        )
+        _check(
+            self.widths != "published" or self.vocab_size is None,
+            f"model.widths='published' keeps the config's vocabulary; "
+            f"vocab_size must be null, not {self.vocab_size}",
+        )
         _check(self.bits in QUANT_BITS, f"model.bits {self.bits} not in {QUANT_BITS}")
         _check(self.draft_noise >= 0.0, "model.draft_noise must be >= 0")
         for name in ("target_layers", "draft_layers"):

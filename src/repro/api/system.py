@@ -94,18 +94,32 @@ class ModelBundle:
         return self.target_cfg.vocab_size
 
 
+def model_config(mspec, arch: str, layers: Optional[int], **changes):
+    """One side of the spec's model pair as a config: the registered
+    ``arch`` at the spec's widths (smoke preset or published), its
+    vocabulary overridden only when the spec names one, its depth cut to
+    ``layers`` when given."""
+    cfg = get_config(arch)
+    if mspec.widths == "smoke":
+        cfg = cfg.reduced()
+    if mspec.vocab_size is not None:
+        changes["vocab_size"] = mspec.vocab_size
+    if layers is not None:
+        changes["num_layers"] = layers
+    return dataclasses.replace(cfg, **changes)
+
+
 def build_models(mspec) -> ModelBundle:
-    """Deterministically build the spec's reduced model pair: target from
+    """Deterministically build the spec's model pair: target from
     ``key(seed)`` (optionally weight-quantized), draft from ``key(seed+1)``
     (optionally noise-perturbed so greedy acceptance is non-trivial)."""
-    tcfg = dataclasses.replace(get_config(mspec.arch).reduced(), vocab_size=mspec.vocab_size)
-    if mspec.target_layers is not None:
-        tcfg = dataclasses.replace(tcfg, num_layers=mspec.target_layers)
-    dcfg = dataclasses.replace(
-        get_config(mspec.draft_arch).reduced(), name="edge-draft", vocab_size=mspec.vocab_size
-    )
-    if mspec.draft_layers is not None:
-        dcfg = dataclasses.replace(dcfg, num_layers=mspec.draft_layers)
+    tcfg = model_config(mspec, mspec.arch, mspec.target_layers)
+    dcfg = model_config(mspec, mspec.draft_arch, mspec.draft_layers, name="edge-draft")
+    if tcfg.vocab_size != dcfg.vocab_size:
+        raise ValueError(
+            f"draft {mspec.draft_arch} (vocab {dcfg.vocab_size}) and target "
+            f"{mspec.arch} (vocab {tcfg.vocab_size}) must share a vocabulary"
+        )
     target, draft = build_model(tcfg), build_model(dcfg)
     kw = {"max_pos": 256} if not tcfg.use_rope else {}
     tp = target.init_params(jax.random.key(mspec.seed), **kw)
@@ -121,11 +135,7 @@ def build_draft_variant(mspec, *, draft_layers: Optional[int], draft_noise: floa
     from ``key(seed+1)`` exactly like :func:`build_models`, so a class whose
     overrides equal the spec model's reproduces ``models.draft_params``
     bit-for-bit (System.build just reuses the shared bundle there)."""
-    dcfg = dataclasses.replace(
-        get_config(mspec.draft_arch).reduced(), name="edge-draft", vocab_size=mspec.vocab_size
-    )
-    if draft_layers is not None:
-        dcfg = dataclasses.replace(dcfg, num_layers=draft_layers)
+    dcfg = model_config(mspec, mspec.draft_arch, draft_layers, name="edge-draft")
     draft = build_model(dcfg)
     dp = perturb_params(draft.init_params(jax.random.key(mspec.seed + 1)), draft_noise)
     return dcfg, draft, dp
@@ -452,7 +462,7 @@ class System:
         cache = cache if cache is not None else KitCache()
         out: List[EdgeDeviceKit] = []
         for rc in spec.resolved_classes():
-            dkey = (mspec.draft_arch, rc.draft_layers, rc.draft_noise,
+            dkey = (mspec.draft_arch, mspec.widths, rc.draft_layers, rc.draft_noise,
                     mspec.vocab_size, mspec.seed)
             if (rc.draft_layers, rc.draft_noise) == (mspec.draft_layers, mspec.draft_noise):
                 bundle = (models.draft_cfg, models.draft, models.draft_params)

@@ -74,6 +74,9 @@ def main(argv: Optional[List[str]] = None) -> None:
         print(_USAGE, end="")
         return
     cmd, rest = argv[0], argv[1:]
+    from repro.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     if cmd == "serve":
         from repro.launch.serve import main as serve_main
 

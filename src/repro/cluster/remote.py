@@ -194,6 +194,20 @@ def kill_worker_proc(proc: Optional[subprocess.Popen], *, wait_s: float = 5.0) -
             pass
 
 
+def held_accelerator() -> Optional[str]:
+    """The accelerator platform this process has already initialised
+    (``"tpu"``, ``"gpu"``), or None while it holds none.  Asking never
+    initialises a backend."""
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        return None
+    import jax
+
+    platform = jax.default_backend()
+    return None if platform == "cpu" else platform
+
+
 def spawn_worker(
     address: Optional[str] = None,
     *,
@@ -205,7 +219,20 @@ def spawn_worker(
     Returns ``(proc, address)``.  Without an explicit address the worker
     listens on a fresh UDS socket under a private temp dir (no port to
     guess, no parsing of the worker's stdout); the dir is removed by
-    RemoteReplica.close()/drain(), or here if startup fails."""
+    RemoteReplica.close()/drain(), or here if startup fails.
+
+    Refuses at once when this process already holds an accelerator: a chip
+    belongs to one process at a time, so the worker would fail or hang
+    until the startup timeout."""
+    held = held_accelerator()
+    if held is not None:
+        raise RuntimeError(
+            f"cannot spawn a repro worker: this process already holds the "
+            f"{held} backend, and a chip belongs to one process at a time, so "
+            f"the worker could not reach it.  Serve with in-process replicas "
+            f"(flavor 'inproc'), or start workers from a parent that has not "
+            f"touched JAX and dial them by address."
+        )
     made_dir = None
     if address is None:
         made_dir = tempfile.mkdtemp(prefix="repro-worker-")

@@ -358,21 +358,29 @@ class Router:
         **engine_kw,
     ) -> "Router":
         """N homogeneous in-process replicas (``n_slots`` rows each) sharing
-        one jitted VerifySteps bundle — the fleet compiles once.  Pass
-        ``steps=`` to share an ALREADY-compiled bundle from another
-        homogeneous fleet (spec sweeps build every replica count on the same
-        executables).  Remote fleets are assembled by repro.api's
-        System.build instead (spawn/dial + PlaceReplica, then ``Router``)."""
+        one jitted VerifySteps bundle.  Pass ``steps=`` to share an
+        ALREADY-compiled bundle from another homogeneous fleet (spec sweeps
+        build every replica count on the same executables).  Remote fleets
+        are assembled by repro.api's System.build instead (spawn/dial +
+        PlaceReplica, then ``Router``).
+
+        On a host with several chips, replica i commits its params and pool
+        to ``jax.local_devices()[i % n]``, so each chip holds its own
+        replica; with one device everything stays on the default device."""
         if replicas < 1:
             raise ValueError(f"need at least 1 replica, got {replicas}")
+        devices = jax.local_devices()
         steps = engine_kw.pop("steps", None)
-        first = ServerEngine(model, params, n_slots=n_slots, steps=steps, **engine_kw)
-        rest = [
-            ServerEngine(model, params, n_slots=n_slots, steps=first.steps, **engine_kw)
-            for _ in range(replicas - 1)
-        ]
+        engines: List[ServerEngine] = []
+        for i in range(replicas):
+            engines.append(ServerEngine(
+                model, params, n_slots=n_slots, steps=steps,
+                device=devices[i % len(devices)] if len(devices) > 1 else None,
+                **engine_kw,
+            ))
+            steps = engines[0].steps
         return cls(
-            [first, *rest],
+            engines,
             placement=placement,
             migrate_on_retire=migrate_on_retire,
             faults=faults,
@@ -907,18 +915,18 @@ class Router:
         return verdicts or None
 
     def warmup(self, buckets=None) -> Dict[int, float]:
-        """Warm one local replica (an in-process fleet shares a single
-        VerifySteps bundle, so its executables are hot for every sibling)
-        plus EVERY remote replica — each worker process has its own compile
-        cache, and an un-warmed worker would pay XLA compilation inside its
-        first timed step."""
+        """Warm one local replica per device (in-process replicas share a
+        VerifySteps bundle, and a compiled executable serves every replica
+        on its device) plus EVERY remote replica — each worker process has
+        its own compile cache, and an un-warmed worker would pay XLA
+        compilation inside its first timed step."""
         out: Dict[int, float] = {}
-        warmed_local = False
+        warmed_devices = set()
         for r in self.alive:
             if r.flavor == "local":
-                if warmed_local:
+                if r.device in warmed_devices:
                     continue
-                warmed_local = True
+                warmed_devices.add(r.device)
             secs = r.warmup(buckets)
             for k, v in secs.items():
                 out[k] = max(out.get(k, 0.0), v)

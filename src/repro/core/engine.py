@@ -276,7 +276,14 @@ class EngineCore:
         paged_attention: bool = True,
         steps: Optional[VerifySteps] = None,
         kv_dtype: Any = "bf16",
+        device: Optional[jax.Device] = None,
     ):
+        # ``device`` commits this replica's params and pool to one chip, so
+        # replicas in one process each get their own; every jitted step then
+        # runs where its committed operands live.  None: the default device.
+        self.device = device
+        if device is not None:
+            params = jax.device_put(params, device)
         self.model = model
         self.params = params
         self.k_max = k_max
@@ -293,7 +300,7 @@ class EngineCore:
                     "serve it with kv_dtype='bf16'"
                 )
             cache_kw["kv_dtype"] = KV_DTYPES["int8"]
-        self.pool = PagedKVCache(model, n_slots, max_len, **cache_kw)
+        self.pool = PagedKVCache(model, n_slots, max_len, device=device, **cache_kw)
         if steps is not None:
             # a mismatched shared bundle would fail (or recompile every
             # bucket behind warmup's back) deep inside step(); fail at the
@@ -376,7 +383,10 @@ class EngineCore:
         return gather_slots(self.pool.cache, jnp.asarray([slot], jnp.int32))
 
     def import_row(self, slot: int, row_cache: Dict[str, jax.Array]) -> None:
-        """Install an exported row into pool row ``slot``."""
+        """Install an exported row into pool row ``slot`` (moved onto this
+        replica's device first when the row comes from another chip)."""
+        if self.device is not None:
+            row_cache = jax.device_put(row_cache, self.device)
         self.pool.write_slot(slot, row_cache)
 
     # -- compute -------------------------------------------------------------

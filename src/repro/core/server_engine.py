@@ -101,6 +101,7 @@ class ServerEngine:
         paged_attention: bool = True,
         steps: Optional[VerifySteps] = None,
         kv_dtype: Any = "bf16",
+        device: Optional[jax.Device] = None,
     ):
         cap = batch_size or n_slots
         self.core = EngineCore(
@@ -118,6 +119,7 @@ class ServerEngine:
             paged_attention=paged_attention,
             steps=steps,
             kv_dtype=kv_dtype,
+            device=device,
         )
         self.admission = AdmissionControl(
             batch_size=cap,
@@ -163,6 +165,10 @@ class ServerEngine:
     @property
     def steps(self) -> VerifySteps:
         return self.core.steps
+
+    @property
+    def device(self):
+        return self.core.device
 
     @property
     def paged_attention(self) -> bool:

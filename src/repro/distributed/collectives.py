@@ -26,8 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 from repro.models.layers import MeshContext, flash_attention
 
 # §Perf iteration A2: psum the flash-decoding partials in bf16 (halves the
@@ -88,7 +86,7 @@ def sp_append_attend(
             l_g.astype(jnp.float32), 1e-30)[..., None]  # (B, Sq, Hkv, G, D)
         return out.reshape(q.shape[0], Sq, Hq, D).astype(q.dtype), kc, vc
 
-    out, kc, vc = shard_map(
+    out, kc, vc = jax.shard_map(
         f,
         mesh=ctx.mesh,
         in_specs=(

@@ -22,7 +22,7 @@ def verify_attention(
     kv_valid: jax.Array,  # (B,)
     *,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """SLED verification attention (see verify_attn.py for the TPU design)."""
     B, Sq, Hq, D = q.shape
@@ -46,7 +46,7 @@ def verify_attention_paged(
     v_scale: Optional[jax.Array] = None,  # when the pool is int8
     *,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     """Slot-indexed verification attention straight out of the cache pool —
     the scalar-prefetched index maps pick pool row ``slots[b]`` per chunk,
@@ -73,7 +73,7 @@ def ssd_scan(
     h0: Optional[jax.Array] = None,
     *,
     chunk: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """Mamba2 SSD over a full sequence (chunked kernel). Returns (y, h_final)."""
     B, S, H, P = x.shape

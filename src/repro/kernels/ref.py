@@ -1,4 +1,9 @@
-"""Pure-jnp oracles for every Pallas kernel (the allclose ground truth)."""
+"""Pure-jnp oracles for every Pallas kernel (the allclose ground truth).
+
+Every contraction runs at ``Precision.HIGHEST``: on a TPU the default is one
+bf16 pass, which would round the oracles' f32 operands (softmax weights, the
+SSM state) and leave the ground truth less exact than the kernels it checks.
+"""
 from __future__ import annotations
 
 import math
@@ -7,6 +12,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+_HI = jax.lax.Precision.HIGHEST
 
 def verify_attention_ref(
     q: jax.Array,        # (B, Sq, Hq, D) — the K+1 verify tokens' queries
@@ -22,13 +28,13 @@ def verify_attention_ref(
     if scale is None:
         scale = 1.0 / math.sqrt(D)
     qg = q.reshape(B, Sq, Hkv, G, D).astype(jnp.float32)
-    s = jnp.einsum("bihgd,bjhd->bhgij", qg, k.astype(jnp.float32)) * scale
+    s = jnp.einsum("bihgd,bjhd->bhgij", qg, k.astype(jnp.float32), precision=_HI) * scale
     j = jnp.arange(Skv)
     q_pos = kv_valid[:, None] - Sq + jnp.arange(Sq)[None]  # (B, Sq)
     mask = j[None, None, :] <= q_pos[:, :, None]  # (B, Sq, Skv)
     s = jnp.where(mask[:, None, None, :, :], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhgij,bjhd->bihgd", p, v.astype(jnp.float32))
+    o = jnp.einsum("bhgij,bjhd->bihgd", p, v.astype(jnp.float32), precision=_HI)
     return o.reshape(B, Sq, Hq, D).astype(q.dtype)
 
 
@@ -82,8 +88,8 @@ def ssd_scan_ref(
         Bt = Bm[:, t].astype(jnp.float32)
         Ct = Cm[:, t].astype(jnp.float32)
         decay = jnp.exp(dtt * A[None])  # (B, H)
-        h = decay[..., None, None] * h + jnp.einsum("bh,bn,bhp->bhpn", dtt, Bt, xt)
-        y = jnp.einsum("bn,bhpn->bhp", Ct, h)
+        h = decay[..., None, None] * h + jnp.einsum("bh,bn,bhp->bhpn", dtt, Bt, xt, precision=_HI)
+        y = jnp.einsum("bn,bhpn->bhp", Ct, h, precision=_HI)
         return h, y
 
     h, ys = jax.lax.scan(step, h, jnp.arange(S))

@@ -31,9 +31,9 @@ in one shared pool of cache rows shaped ``(n_slots + 1, Skv, Hkv, D)`` (the
 ``slots`` vector names which pool row each batch entry attends against.
 ``slots`` is a *scalar-prefetch* operand (``pltpu.PrefetchScalarGridSpec``):
 it lands in SMEM before the kernel body runs, so the BlockSpec index maps
-can compute each K/V tile's HBM address as ``(slots[b], j, h, 0)`` — the
-grid walks ``(B, Hkv, n_blk)`` and every chunk DMA reads straight out of
-the pool row the slot map points at.  Nothing is ever gathered into a dense
+can compute each K/V tile's HBM address as ``(slots[b], j, 0, 0)`` — the
+grid walks ``(B, n_blk)``, each tile carries all Hkv heads, and every chunk
+DMA reads straight out of the pool row the slot map points at.  Nothing is ever gathered into a dense
 sub-batch and nothing but the O(K+1) fresh rows is ever written back, which
 deletes the gather/scatter paging tax the engine's verify step used to pay
 (benchmarks/verify_kernel.py --engine measures it).  Duplicate slot ids are
@@ -47,8 +47,9 @@ be slot-indexed by this kernel and keep riding ``kvcache.gather_slots`` —
 they are tiny next to the attention pool.
 
 Layouts: q is pre-packed to (B, Hkv, Sq*G, D) by ops.py (tiny transpose);
-k/v stay (B, Skv, Hkv, D) / (n_slots+1, Skv, Hkv, D) — BlockSpec index maps
-stride the head dim, so the multi-GB cache is never transposed.
+k/v stay (B, Skv, Hkv, D) / (n_slots+1, Skv, Hkv, D) — a K/V tile takes
+every head of its block_k positions, so the multi-GB cache is never
+transposed.
 """
 from __future__ import annotations
 
@@ -66,23 +67,29 @@ NEG_INF = -1e30
 
 def _attend_chunk(kv_valid, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
                   *, block_k: int, sq: int, skv: int, scale: float,
-                  k_scale=None, v_scale=None):
-    """One online-softmax step over the current kv chunk (grid axis 2).
+                  k_scales=None, v_scales=None):
+    """One online-softmax step over the current kv chunk (grid axis 1), for
+    every kv head of the tile.
 
     Shared by the dense and paged kernels — only how the chunk was addressed
     differs (BlockSpec index maps), never the math.  Requires
     ``kv_valid >= sq`` (the Sq fresh rows are in the cache), which makes the
     first chunk contain at least one valid position for every packed row.
 
-    ``k_scale``/``v_scale`` (f32 scalars for this (slot, head)) switch on the
-    int8 path: the K/V tiles arrive quantized and are dequantized HERE, on
-    the VMEM-resident chunk — the HBM stream is int8, so the cache read
+    The K/V tile holds all Hkv heads, ``(1, block_k, Hkv, D)``: Mosaic needs
+    a block's second-to-last dim to be a multiple of 8 or the whole array
+    dim, so a one-head tile ``(1, block_k, 1, D)`` does not compile.  The
+    head loop is unrolled here instead.
+
+    ``k_scales``/``v_scales`` (per-head f32 scalars for this slot) switch on
+    the int8 path: the K/V tiles arrive quantized and are dequantized HERE,
+    on the VMEM-resident chunk — the HBM stream is int8, so the cache read
     halves, and no bf16 pool copy ever exists.  The dequant arithmetic
     mirrors layers.kv_dequant (int8 -> f32 * scale -> bf16) so the kernel
     tracks the XLA serving path's numerics.
     """
-    j_blk = pl.program_id(2)
-    n_blk = pl.num_programs(2)
+    j_blk = pl.program_id(1)
+    n_blk = pl.num_programs(1)
 
     @pl.when(j_blk == 0)
     def _init():
@@ -90,49 +97,51 @@ def _attend_chunk(kv_valid, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0]  # (rows, D) rows = Sq*G
-    k = k_ref[0, :, 0, :]  # (block_k, D)
-    v = v_ref[0, :, 0, :]
-    if k_scale is not None:
-        k = (k.astype(jnp.float32) * k_scale).astype(jnp.bfloat16)
-        v = (v.astype(jnp.float32) * v_scale).astype(jnp.bfloat16)
-    rows = q.shape[0]
-
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # (rows, block_k)
-
+    hkv, rows = q_ref.shape[1], q_ref.shape[2]
     # packed row r -> query index i = r // G; abs position = kv_valid - Sq + i
     g = rows // sq
     i_vec = jax.lax.broadcasted_iota(jnp.int32, (rows, block_k), 0) // g
     j_vec = jax.lax.broadcasted_iota(jnp.int32, (rows, block_k), 1) + j_blk * block_k
     mask = (j_vec <= (kv_valid - sq + i_vec)) & (j_vec < skv)
-    s = jnp.where(mask, s, NEG_INF)
     # Partial tail chunk: lanes past Skv read unspecified data (NaN in
     # interpret mode).  Their weights are exactly 0, but 0 * NaN = NaN would
     # still poison acc — zero the out-of-range V rows explicitly.
     col = jax.lax.broadcasted_iota(jnp.int32, (block_k, 1), 0) + j_blk * block_k
-    v = jnp.where(col < skv, v, jnp.zeros((), v.dtype))
+    v_live = col < skv
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    m_ref[...] = m_new
+    for h in range(hkv):
+        q = q_ref[0, h]  # (rows, D) rows = Sq*G
+        k = k_ref[0, :, h, :]  # (block_k, D)
+        v = v_ref[0, :, h, :]
+        if k_scales is not None:
+            k = (k.astype(jnp.float32) * k_scales[h]).astype(jnp.bfloat16)
+            v = (v.astype(jnp.float32) * v_scales[h]).astype(jnp.bfloat16)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale  # (rows, block_k)
+        s = jnp.where(mask, s, NEG_INF)
+        v = jnp.where(v_live, v, jnp.zeros((), v.dtype))
+
+        m_prev = m_ref[h]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[h] = l_ref[h] * corr + p.sum(axis=-1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_ref[h] = m_new
 
     @pl.when(j_blk == n_blk - 1)
     def _finalize():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def _kernel(kv_valid_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
             *, block_k: int, sq: int, skv: int, scale: float):
-    _attend_chunk(kv_valid_ref[0], q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
+    b = pl.program_id(0)
+    _attend_chunk(kv_valid_ref[b], q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                   acc_ref, block_k=block_k, sq=sq, skv=skv, scale=scale)
 
 
@@ -154,11 +163,28 @@ def _paged_quant_kernel(slots_ref, kv_valid_ref, k_scale_ref, v_scale_ref,
     # next to slots/kv_valid — SMEM-resident before the body runs, looked up
     # here with the same slot map the index maps use for the K/V tiles.
     b = pl.program_id(0)
-    h = pl.program_id(1)
     row = slots_ref[b]
+    hkv = q_ref.shape[1]
     _attend_chunk(kv_valid_ref[b], q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                   acc_ref, block_k=block_k, sq=sq, skv=skv, scale=scale,
-                  k_scale=k_scale_ref[row, h], v_scale=v_scale_ref[row, h])
+                  k_scales=[k_scale_ref[row, h] for h in range(hkv)],
+                  v_scales=[v_scale_ref[row, h] for h in range(hkv)])
+
+
+def _scratch(hkv: int, rows: int, d: int):
+    """Online-softmax state for every kv head of one batch row.
+
+    VMEM per grid step at block_k=512, D=128: each K or V tile is
+    block_k x (Hkv rounded up to the sublane tile: 8 f32 / 16 bf16 /
+    32 int8 rows) x D, which is 2 MiB for Hkv <= that tile in every dtype;
+    K+V double-buffered is 8 MiB.  q and o blocks plus this scratch add
+    about 3 x Hkv x rows x D x 4 bytes (under 0.2 MiB at Hkv=2, rows=30).
+    """
+    return [
+        pltpu.VMEM((hkv, rows, 1), jnp.float32),   # m
+        pltpu.VMEM((hkv, rows, 1), jnp.float32),   # l
+        pltpu.VMEM((hkv, rows, d), jnp.float32),   # acc
+    ]
 
 
 def verify_attention_packed(
@@ -170,7 +196,7 @@ def verify_attention_packed(
     sq: int,
     scale: Optional[float] = None,
     block_k: int = 512,
-    interpret: bool = True,  # CPU container: interpret; flip off on TPU
+    interpret: bool = False,
 ) -> jax.Array:
     B, Hkv, rows, D = q.shape
     Skv = k.shape[1]
@@ -181,24 +207,23 @@ def verify_attention_packed(
 
     kernel = functools.partial(_kernel, block_k=block_k, sq=sq, skv=Skv,
                                scale=float(scale))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,  # kv_valid
+        grid=(B, n_blk),
+        in_specs=[
+            pl.BlockSpec((1, Hkv, rows, D), lambda b, j, kvv: (b, 0, 0, 0)),    # q
+            pl.BlockSpec((1, block_k, Hkv, D), lambda b, j, kvv: (b, j, 0, 0)),  # k
+            pl.BlockSpec((1, block_k, Hkv, D), lambda b, j, kvv: (b, j, 0, 0)),  # v
+        ],
+        out_specs=pl.BlockSpec((1, Hkv, rows, D), lambda b, j, kvv: (b, 0, 0, 0)),
+        scratch_shapes=_scratch(Hkv, rows, D),
+    )
     return pl.pallas_call(
         kernel,
-        grid=(B, Hkv, n_blk),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, h, j: (b,)),                 # kv_valid
-            pl.BlockSpec((1, 1, rows, D), lambda b, h, j: (b, h, 0, 0)),   # q
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, j: (b, j, h, 0)),  # k
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, j: (b, j, h, 0)),  # v
-        ],
-        out_specs=pl.BlockSpec((1, 1, rows, D), lambda b, h, j: (b, h, 0, 0)),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rows, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((rows, 1), jnp.float32),   # m
-            pltpu.VMEM((rows, 1), jnp.float32),   # l
-            pltpu.VMEM((rows, D), jnp.float32),   # acc
-        ],
         interpret=interpret,
-    )(kv_valid, q, k, v)
+    )(kv_valid.astype(jnp.int32), q, k, v)
 
 
 def verify_attention_paged(
@@ -211,15 +236,15 @@ def verify_attention_paged(
     sq: int,
     scale: Optional[float] = None,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
     k_scale: Optional[jax.Array] = None,  # (n_slots+1, Hkv) f32 dequant
     v_scale: Optional[jax.Array] = None,  # scales for an int8 pool
 ) -> jax.Array:
     """Slot-indexed verification attention over a shared cache-row pool.
 
     ``slots`` and ``kv_valid`` ride scalar prefetch: the index maps address
-    each (block_k, D) K/V tile as ``(slots[b], j, h, 0)`` directly in the
-    pool, so the chunk DMAs stream exactly the scheduled rows — no dense
+    each (block_k, Hkv, D) K/V tile as ``(slots[b], j, 0, 0)`` directly in
+    the pool, so the chunk DMAs stream exactly the scheduled rows — no dense
     gather ever exists (see module docstring).
 
     With an int8 pool, pass the PagedKVCache's per-(slot, head) dequant
@@ -239,58 +264,31 @@ def verify_attention_paged(
     if quant and (k_scale is None or v_scale is None):
         raise ValueError("int8 k_pool/v_pool require k_scale/v_scale operands")
 
-    scratch = [
-        pltpu.VMEM((rows, 1), jnp.float32),   # m
-        pltpu.VMEM((rows, 1), jnp.float32),   # l
-        pltpu.VMEM((rows, D), jnp.float32),   # acc
-    ]
-    if quant:
-        kernel = functools.partial(_paged_quant_kernel, block_k=block_k, sq=sq,
-                                   skv=Skv, scale=float(scale))
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,  # slots, kv_valid, k_scale, v_scale
-            grid=(B, Hkv, n_blk),
-            in_specs=[
-                pl.BlockSpec((1, 1, rows, D),
-                             lambda b, h, j, slots, kvv, ks, vs: (b, h, 0, 0)),
-                pl.BlockSpec((1, block_k, 1, D),
-                             lambda b, h, j, slots, kvv, ks, vs: (slots[b], j, h, 0)),
-                pl.BlockSpec((1, block_k, 1, D),
-                             lambda b, h, j, slots, kvv, ks, vs: (slots[b], j, h, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, rows, D),
-                                   lambda b, h, j, slots, kvv, ks, vs: (b, h, 0, 0)),
-            scratch_shapes=scratch,
-        )
-        return pl.pallas_call(
-            kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((B, Hkv, rows, D), q.dtype),
-            interpret=interpret,
-        )(slots.astype(jnp.int32), kv_valid.astype(jnp.int32),
-          k_scale.astype(jnp.float32), v_scale.astype(jnp.float32),
-          q, k_pool, v_pool)
+    # index maps see the grid indices, then every scalar-prefetch operand
+    def q_map(b, j, *prefetch):
+        return (b, 0, 0, 0)
 
-    kernel = functools.partial(_paged_kernel, block_k=block_k, sq=sq, skv=Skv,
-                               scale=float(scale))
+    def kv_map(b, j, slots, *prefetch):
+        return (slots[b], j, 0, 0)
+
+    body, n_prefetch = (_paged_quant_kernel, 4) if quant else (_paged_kernel, 2)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # slots, kv_valid
-        grid=(B, Hkv, n_blk),
+        num_scalar_prefetch=n_prefetch,  # slots, kv_valid (+ k_scale, v_scale)
+        grid=(B, n_blk),
         in_specs=[
-            pl.BlockSpec((1, 1, rows, D), lambda b, h, j, slots, kvv: (b, h, 0, 0)),
-            pl.BlockSpec(
-                (1, block_k, 1, D), lambda b, h, j, slots, kvv: (slots[b], j, h, 0)
-            ),
-            pl.BlockSpec(
-                (1, block_k, 1, D), lambda b, h, j, slots, kvv: (slots[b], j, h, 0)
-            ),
+            pl.BlockSpec((1, Hkv, rows, D), q_map),
+            pl.BlockSpec((1, block_k, Hkv, D), kv_map),
+            pl.BlockSpec((1, block_k, Hkv, D), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, rows, D), lambda b, h, j, slots, kvv: (b, h, 0, 0)),
-        scratch_shapes=scratch,
+        out_specs=pl.BlockSpec((1, Hkv, rows, D), q_map),
+        scratch_shapes=_scratch(Hkv, rows, D),
     )
+    prefetch = [slots.astype(jnp.int32), kv_valid.astype(jnp.int32)]
+    if quant:
+        prefetch += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
     return pl.pallas_call(
-        kernel,
+        functools.partial(body, block_k=block_k, sq=sq, skv=Skv, scale=float(scale)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rows, D), q.dtype),
         interpret=interpret,
-    )(slots.astype(jnp.int32), kv_valid.astype(jnp.int32), q, k_pool, v_pool)
+    )(*prefetch, q, k_pool, v_pool)

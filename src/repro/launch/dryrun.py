@@ -23,8 +23,8 @@ import json
 import time
 import traceback
 
+import jax
 
-from repro.compat import use_mesh
 from repro.configs.base import SHAPES, all_cells, get_config, shape_applicable
 from repro.launch.cells import build_cell
 from repro.launch.mesh import make_production_mesh
@@ -39,7 +39,7 @@ def run_cell(cfg, shape, mesh, mesh_name: str, *, verbose: bool = True,
              kv_bits: int = 16) -> dict:
     t0 = time.time()
     cell = build_cell(cfg, shape, mesh, attn_chunk=attn_chunk, kv_bits=kv_bits)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = cell.lower()
         t_lower = time.time() - t0
         compiled = lowered.compile()
@@ -47,8 +47,6 @@ def run_cell(cfg, shape, mesh, mesh_name: str, *, verbose: bool = True,
 
     ma = compiled.memory_analysis()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # jax 0.4.x returns [dict]
-        ca = ca[0] if ca else {}
     hlo = compiled.as_text()
     # trip-count-aware HLO walk: XLA's cost_analysis counts loop bodies ONCE
     # (scan-over-layers / grad-accum would be undercounted by 88x / 8x)

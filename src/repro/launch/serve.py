@@ -22,7 +22,9 @@ Backends (``--backend``, or inferred from the legacy ``--transport`` flag):
 
 On lossless links with fixed k every backend must be token-for-token
 identical to the reference loop; ``--check`` (default on) verifies it by
-running the reference backend on the same built models.
+running the reference backend on the same built models.  For a model at its
+published widths the check admits splits at greedy near-ties (``TIE_TOL``,
+see ``streams_match``).
 
     PYTHONPATH=src python -m repro.launch.serve --devices 6              # loopback
     PYTHONPATH=src python -m repro.launch.serve --transport sim --net wlan
@@ -33,7 +35,12 @@ running the reference backend on the same built models.
 
 import argparse
 import dataclasses
-from typing import Optional
+import json
+from typing import Dict, List, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
 
 from repro.api import ServeSpec, SpecError, System
 from repro.api.spec import (
@@ -90,6 +97,107 @@ def spec_from_args(args) -> ServeSpec:
         paged_attention=args.paged_attention,
         telemetry=args.telemetry,
     )
+
+
+# Greedy near-tie tolerance, in logits, for a target at its published widths.
+# With random weights at full width the top logits sit near 3.3, where one
+# bf16 step is 2**-6 = 0.0156, and the top two are often closer than that.
+# The engine and the lock-step reference verify in different batch shapes,
+# so they can round such a tie apart, and each then continues greedily from
+# its own prefix.  On a TPU v5e at qwen2-1.5b widths streams split at top-2
+# margins of 0.010 to 0.021, and the teacher-forced forward that scores them
+# (a third rounding of the same model) put served and reference tokens up
+# to 0.051 below its top logit, while a token shifted by one in the
+# vocabulary scored 1.25 below it at least (3.58 median).  At the smoke
+# preset the check stays exact.
+TIE_TOL = 0.1
+
+
+def greedy_gaps(system, outputs: Dict[int, List[int]]) -> Dict[int, tuple]:
+    """The target's teacher-forced view of committed streams: one forward
+    over prompt + stream per device.  Per token, at the position that chose
+    it: the gap from the top logit to the token's logit (0 where it is the
+    argmax), the top-2 margin, and the gap of the token shifted by one in
+    the vocabulary, a wrong token that shows what the tolerance refuses."""
+    spec, models = system.spec, system.models
+    target, plen = models.target, spec.prompt_len
+    length = plen + spec.max_new  # one padded shape: a single compile
+    prompts = system.prompts()
+
+    @jax.jit
+    def score(params, seq):
+        h, _ = target.forward(params, seq, attn_chunk=spec.attn_chunk)
+        logits = target.lm_head(params, h)[0, plen - 1:-1]  # row t picks token t
+        top = jax.lax.top_k(logits, 2)[0]
+        toks = seq[0, plen:, None]
+        chosen = jnp.take_along_axis(logits, toks, axis=1)[:, 0]
+        wrong = jnp.take_along_axis(logits, (toks + 1) % logits.shape[1], axis=1)[:, 0]
+        return top[:, 0] - chosen, top[:, 0] - top[:, 1], top[:, 0] - wrong
+
+    out = {}
+    for dev, toks in outputs.items():
+        seq = np.zeros((1, length), np.int32)
+        seq[0, :plen] = prompts[dev]
+        seq[0, plen:plen + len(toks)] = toks
+        got = jax.device_get(score(models.target_params, jnp.asarray(seq)))
+        out[dev] = tuple(g[: len(toks)] for g in got)
+    return out
+
+
+def streams_match(system, served: Dict[int, List[int]],
+                  reference: Dict[int, List[int]], label: str = "reference") -> bool:
+    """Whether the served streams (device id -> tokens) are the reference's.
+
+    Exact at the smoke preset.  At published widths a stream that splits
+    from the reference passes if it is as long and every token of both
+    streams lies within ``TIE_TOL`` of the teacher-forced top logit
+    (``greedy_gaps``): the two agree up to the split, split at a near-tie,
+    and each continues greedily from its own prefix.  At published widths
+    it prints what it found, with the shifted-token control."""
+    if sorted(served) != sorted(reference):
+        print(f"{label}: streams {sorted(served)} vs reference {sorted(reference)}")
+        return False
+    if system.spec.model.widths != "published":
+        return served == reference
+    split = {d: t for d, t in served.items() if t != reference[d]}
+    gaps = greedy_gaps(system, served)
+    ref_gaps = greedy_gaps(system, {d: reference[d] for d in split})
+    ok = True
+    for dev, toks in sorted(split.items()):
+        want = reference[dev]
+        p = next((i for i, (a, b) in enumerate(zip(toks, want)) if a != b),
+                 min(len(toks), len(want)))
+        (sg, sm, _), (rg, _, _) = gaps[dev], ref_gaps[dev]
+        s_max, r_max = float(sg.max(initial=0.0)), float(rg.max(initial=0.0))
+        good = len(toks) == len(want) and s_max <= TIE_TOL and r_max <= TIE_TOL
+        ok &= good
+        at = {"position": p, "served_token": toks[p] if p < len(toks) else None,
+              "reference_token": want[p] if p < len(want) else None}
+        if p < min(len(toks), len(want)):
+            at.update(top2_margin=float(sm[p]), served_gap=float(sg[p]),
+                      reference_gap=float(rg[p]))
+        print(f"{label}: stream {dev} splits from the reference: {json.dumps(at)}; "
+              f"max gap: served {s_max:.6f}, reference {r_max:.6f} "
+              f"(tolerance {TIE_TOL}) {'ok' if good else 'FAIL'}")
+    n = sum(len(t) for t in served.values())
+    off = sum(int((g > 0).sum()) for g, _, _ in gaps.values())
+    worst = max((float(g.max(initial=0.0)) for g, _, _ in gaps.values()), default=0.0)
+    wrong = np.concatenate([w for _, _, w in gaps.values()] or [np.zeros(0)])
+    if wrong.size:
+        print(f"{label}: control: each served token shifted by one scores "
+              f"{float(wrong.min()):.6f} (min) / {float(np.median(wrong)):.6f} "
+              f"(median) below the top")
+    print(f"{label}: {n} served tokens, {off} not the teacher-forced argmax, "
+          f"max gap {worst:.6f}; {len(split)} of {len(served)} streams split "
+          f"from the reference (tolerance {TIE_TOL}): {'PASS' if ok else 'FAIL'}")
+    return ok
+
+
+def reference_check(system, result, label: str = "reference") -> bool:
+    """Serve ``system``'s spec on the reference backend with the same built
+    models and compare the committed streams (``streams_match``)."""
+    ref = System.build(system.spec.with_backend("reference"), models=system.models).serve()
+    return streams_match(system, result.outputs, ref.outputs, label)
 
 
 def serve(spec: ServeSpec, *, check: bool = True) -> dict:
@@ -177,11 +285,11 @@ def serve(spec: ServeSpec, *, check: bool = True) -> dict:
                 ref = System.build(refspec).serve(prompts[lo:hi])
                 # the reference slice serves as devices 0..count-1; the
                 # fleet run served the same prompts as devices lo..hi-1
-                if any(
-                    ref.outputs[i] != result.outputs[lo + i]
-                    for i in range(hi - lo)
-                ):
-                    match = False
+                match &= streams_match(
+                    system,
+                    {lo + i: result.outputs[lo + i] for i in range(hi - lo)},
+                    {lo + i: ref.outputs[i] for i in range(hi - lo)},
+                )
             n = len(spec.fleet.classes)
             print(f"greedy per-class reference match ({n} classes): "
                   f"{'OK' if match else 'MISMATCH'}")
@@ -190,10 +298,7 @@ def serve(spec: ServeSpec, *, check: bool = True) -> dict:
                 "the per-class lock-step references"
             )
         else:
-            ref = System.build(
-                spec.with_backend("reference"), models=system.models
-            ).serve()
-            match = ref.outputs == result.outputs
+            match = reference_check(system, result)
             print(f"greedy lock-step reference match: {'OK' if match else 'MISMATCH'}")
             assert match, (
                 f"{spec.backend} serving must be output-identical to the "

@@ -10,7 +10,8 @@ per-position state checkpoints during verification instead (see mamba2.py).
 """
 from __future__ import annotations
 
-from typing import Any, Dict, List
+import contextlib
+from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -160,13 +161,18 @@ class PagedKVCache:
     model's own (``model.commit`` runs on the gathered dense sub-cache).
     """
 
-    def __init__(self, model: Any, n_slots: int, max_len: int, **cache_kw):
+    def __init__(self, model: Any, n_slots: int, max_len: int,
+                 device: Optional[jax.Device] = None, **cache_kw):
         self.model = model
         self.n_slots = n_slots
         self.scratch_slot = n_slots
         self.max_len = max_len
         self.cache_kw = dict(cache_kw)
-        self.cache = model.make_cache(n_slots + 1, max_len, **cache_kw)
+        # allocate on ``device`` directly, then commit the pool to it
+        with jax.default_device(device) if device is not None else contextlib.nullcontext():
+            self.cache = model.make_cache(n_slots + 1, max_len, **cache_kw)
+        if device is not None:
+            self.cache = jax.device_put(self.cache, device)
         self.allocator = SlotAllocator(n_slots)
 
     def pool_bytes(self) -> int:

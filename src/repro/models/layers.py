@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 
 Params = Dict[str, Any]
 
@@ -178,7 +177,7 @@ def embed_lookup(embed: jax.Array, tokens: jax.Array, ctx: "MeshContext") -> jax
         rows = jnp.where(ok[..., None], rows, jnp.zeros((), rows.dtype))
         return jax.lax.psum(rows, ax)
 
-    return shard_map(
+    return jax.shard_map(
         f,
         mesh=ctx.mesh,
         in_specs=(P(ax, None), P(bspec, None)),
@@ -716,7 +715,7 @@ def moe_block(x: jax.Array, p: Params, cfg, ctx: MeshContext) -> Tuple[jax.Array
         aux = jax.lax.psum(aux, ax)
         return out.reshape(tb, S, d), aux
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         shard_fn,
         mesh=ctx.mesh,
         in_specs=(P(ctx.batch_axes if ctx.batch_axes else None, None, None), w_specs),

@@ -676,6 +676,8 @@ def _encode_q(out: List[bytes], q: Optional[np.ndarray], qmode: str) -> None:
         out.append(q.astype("<f4").tobytes())
     elif qmode == "f16":
         out.append(q.astype("<f2").tobytes())
+    elif not q.size:  # int8 of an empty draft: no row to scale
+        out.append(struct.pack(">f", 0.0))
     else:  # int8: symmetric per-row scheme from quant/quantize.py
         qt = quantize(q[None, :], bits=8)
         out.append(struct.pack(">f", float(qt.scale[0, 0])))

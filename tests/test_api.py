@@ -163,13 +163,113 @@ def test_stats_to_json_uniform():
     assert json.dumps(c.to_json()) and c.to_json()["rounds"] == 4
 
 
-def test_cli_dump_spec(capsys):
+def test_cli_dump_spec(capsys, monkeypatch):
+    from repro import compile_cache
     from repro.cli import main
 
+    # the entry point turns the persistent cache on; keep this test process's
+    # later compiles out of it
+    monkeypatch.setattr(compile_cache, "configure_compile_cache", lambda: "")
     main(["serve", "--dump-spec", "--devices", "2", "--replicas", "2"])
     out = capsys.readouterr().out
     spec = ServeSpec.from_json(out[out.index("{"):])
     assert spec.backend == "transport" and spec.cluster.replicas == 2
+
+
+# ---------------------------------------------------------------------------
+# published widths (shapes only: nothing at full width is allocated here)
+# ---------------------------------------------------------------------------
+
+
+def _published(**kw) -> ModelSpec:
+    return ModelSpec(widths="published", vocab_size=None, draft_layers=2, **kw)
+
+
+@pytest.mark.parametrize(
+    "model",
+    [
+        dict(widths="full"),
+        dict(widths="published", vocab_size=256),  # published keeps its vocab
+        dict(widths="published"),  # vocab_size defaults to the smoke 256
+        dict(vocab_size=4),
+    ],
+)
+def test_model_widths_validation(model):
+    with pytest.raises(SpecError):
+        _spec(model=ModelSpec(**model))
+
+
+def test_published_widths_round_trip():
+    spec = _spec(backend="transport", model=_published(), prompt_len=128,
+                 max_new=64, max_len=256)
+    d = json.loads(spec.to_json_str())
+    assert d["model"]["widths"] == "published" and d["model"]["vocab_size"] is None
+    assert ServeSpec.from_json(d) == spec
+    chip = pathlib.Path(__file__).parent.parent / "examples" / "specs" / "chip_smoke.json"
+    assert ServeSpec.from_json(chip.read_text()).model == spec.model
+
+
+def test_published_widths_resolve_to_registered_config():
+    import jax
+
+    from repro.api.system import model_config
+    from repro.models.model_zoo import build_model
+
+    m = _published()
+    tcfg = model_config(m, m.arch, m.target_layers)
+    dcfg = model_config(m, m.draft_arch, m.draft_layers)
+    assert (tcfg.num_layers, tcfg.d_model, tcfg.num_heads, tcfg.num_kv_heads,
+            tcfg.d_ff, tcfg.vocab_size) == (28, 1536, 12, 2, 8960, 151936)
+    assert (dcfg.num_layers, dcfg.d_model, dcfg.vocab_size) == (2, 1536, 151936)
+    shapes = jax.eval_shape(build_model(tcfg).init_params, jax.random.key(0))
+    assert shapes["embed"].shape == (151936, 1536)
+    assert shapes["layers"]["attn"]["wq"].shape[:2] == (28, 1536)
+    n = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(shapes))
+    assert n == 1_543_714_304  # ~1.54 B: 3.1 GB in bf16
+    # depth cuts keep every width; the smoke preset stays the default
+    assert model_config(_published(target_layers=4), "qwen2-1.5b", 4).d_model == 1536
+    assert model_config(ModelSpec(), "qwen2-1.5b", None).d_model == 64
+
+
+def test_compile_cache_honours_env(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, JAX's own reading of it decides:
+    the entry point sets no other path, and compiles land there."""
+    import os
+    import subprocess
+    import sys
+
+    root = pathlib.Path(__file__).resolve().parent.parent
+    code = (
+        "import jax, jax.numpy as jnp\n"
+        "from repro.compile_cache import configure_compile_cache\n"
+        "print(configure_compile_cache(), jax.config.jax_compilation_cache_dir)\n"
+        "jax.jit(lambda x: x * 2 + 1)(jnp.ones(3)).block_until_ready()\n"
+    )
+    env = dict(os.environ, PYTHONPATH=str(root / "src"), JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+    out = subprocess.run([sys.executable, "-c", code], env=env, capture_output=True,
+                         text=True, timeout=120, check=True).stdout.split()
+    assert out[-2:] == [str(tmp_path)] * 2
+    assert any(tmp_path.iterdir()), "no cache entry written"
+
+
+def test_compile_cache_default_is_fixed_checkout_dir(monkeypatch):
+    """Without the variable the cache goes to one fixed, gitignored
+    directory in the checkout (no temp name, pid or time in the path)."""
+    import jax
+
+    from repro.compile_cache import CACHE_DIR, configure_compile_cache
+
+    root = pathlib.Path(__file__).resolve().parent.parent
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        assert configure_compile_cache() == str(root / ".jax_cache") == str(CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(CACHE_DIR)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+    assert ".jax_cache/" in (root / ".gitignore").read_text().split()
 
 
 # ---------------------------------------------------------------------------
@@ -219,6 +319,35 @@ def test_backend_equivalence(bundle, ref_outputs, backend, replicas):
     result = system.serve()
     assert result.outputs == ref_outputs, f"{backend} diverged from the reference"
     assert json.dumps(result.to_json())  # uniform record is an artifact
+
+
+def test_reference_check_tolerates_only_near_ties(bundle, ref_outputs, capsys):
+    """repro serve's reference check is exact at the smoke preset; at
+    published widths a split stream passes only if every token is within
+    TIE_TOL of the target's teacher-forced top logit, so a wrong token
+    fails it either way."""
+    import dataclasses
+    import types
+
+    from repro.launch.serve import TIE_TOL, greedy_gaps, streams_match
+
+    spec, models = bundle
+    system = System.build(spec.with_backend("reference"), models=models)
+    wrong = {d: list(t) for d, t in ref_outputs.items()}
+    wrong[0][3] = (wrong[0][3] + 1) % V
+    assert streams_match(system, ref_outputs, ref_outputs)
+    assert not streams_match(system, wrong, ref_outputs)
+    assert capsys.readouterr().out == ""  # the exact check prints nothing
+
+    wide = types.SimpleNamespace(  # the same models, checked as at published widths
+        spec=dataclasses.replace(spec, model=_published()), models=models,
+        prompts=system.prompts)
+    gap, _, control = greedy_gaps(wide, {0: wrong[0]})[0]
+    assert gap[3] > TIE_TOL and gap[:3].max() <= TIE_TOL and control.min() >= 0
+    assert streams_match(wide, ref_outputs, ref_outputs)
+    assert not streams_match(wide, wrong, ref_outputs)
+    out = capsys.readouterr().out
+    assert "stream 0 splits from the reference" in out and "FAIL" in out
 
 
 def test_session_stream_consistency(bundle, ref_outputs):

@@ -465,6 +465,7 @@ def test_tcp_endpoint_codec_roundtrip_matches_loopback():
             frame = await asyncio.wait_for(client.recv(), 5.0)
             back.append(codec.decode_frame(frame)[0])
         client.close()
+        server_ep.close()  # Python 3.12's wait_closed waits for every connection
         server.close()
         await server.wait_closed()
         assert client.stats.frames_tx == len(msgs)
@@ -499,6 +500,7 @@ def test_tcp_endpoint_recv_none_on_close():
         assert isinstance(codec.decode_frame(frame)[0], codec.Close)
         client.close()
         assert await asyncio.wait_for(server_ep.recv(), 5.0) is None
+        server_ep.close()  # Python 3.12's wait_closed waits for every connection
         server.close()
         await server.wait_closed()
 
@@ -523,3 +525,51 @@ def test_ssm_decode_forward_with_slots_raises_cleanly():
     toks = jax.numpy.zeros((2, 3), jax.numpy.int32)
     with pytest.raises(NotImplementedError, match="gather/scatter fallback"):
         mm.decode_forward(mp, cache, toks, slots=jax.numpy.asarray([0, 1]))
+
+
+def test_router_places_replicas_on_distinct_devices():
+    """On a host with several devices each in-process replica commits its
+    params and pool to its own device, verifies there, and accepts a KV row
+    exported from the other device bit-exactly.  Runs in a child process
+    with two virtual CPU devices (this process has one)."""
+    import os
+    import subprocess
+    import sys
+    import textwrap
+
+    code = textwrap.dedent("""
+        import dataclasses, jax, numpy as np
+        from repro.cluster import Router
+        from repro.configs.base import get_config
+        from repro.models.model_zoo import build_model
+        cfg = dataclasses.replace(get_config("qwen2-1.5b").reduced(), vocab_size=64)
+        model = build_model(cfg)
+        params = jax.jit(model.init_params)(jax.random.key(0))
+        router = Router.build(model, params, replicas=2, n_slots=1, max_len=32, k_max=2)
+        engines = [r.engine for r in router.replicas]
+        assert [e.device for e in engines] == jax.devices()[:2]
+        router.warmup()
+        prompt = np.arange(6, dtype=np.int32)
+        for dev in range(2):
+            assert router.admit(dev, prompt, 0.0) is not None
+            router.submit(dev, np.asarray([1, 2], np.int32), 0.0)
+        verdicts = router.step(1.0)
+        assert sorted(v.device_id for v in verdicts) == [0, 1]
+        for e in engines:
+            assert len(e.round_log) == 1
+            for leaf in jax.tree.leaves((e.params, e.pool.cache)):
+                assert leaf.devices() == {e.device}
+        row = engines[1].core.export_row(0)
+        engines[0].core.import_row(0, row)
+        back = engines[0].core.export_row(0)
+        for a, b in zip(jax.tree.leaves(row), jax.tree.leaves(back)):
+            assert np.array_equal(np.asarray(a), np.asarray(b))
+        print("ok")
+    """)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"), JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=2")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True,
+                         env=env, timeout=300)
+    assert out.returncode == 0, out.stderr[-4000:]
+    assert out.stdout.split()[-1] == "ok"

@@ -77,7 +77,6 @@ def test_debug_mesh_lower_compile(arch, shape):
     (the full 512-device x 40-cell sweep is launch/dryrun.py)."""
     code = textwrap.dedent(f"""
         import jax, dataclasses
-        from repro.compat import use_mesh
         from repro.configs.base import get_config, SHAPES
         from repro.launch.cells import build_cell
         from repro.launch.mesh import make_debug_mesh
@@ -88,7 +87,7 @@ def test_debug_mesh_lower_compile(arch, shape):
         shape = dataclasses.replace(SHAPES["{shape}"],
                                     seq_len=2048, global_batch=8)
         cell = build_cell(cfg, shape, mesh, attn_chunk=256)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             compiled = cell.lower().compile()
         ma = compiled.memory_analysis()
         print("ok", ma.temp_size_in_bytes)
@@ -101,7 +100,6 @@ def test_sp_attention_numerics_under_mesh():
     """Sequence-parallel flash-decoding == single-device reference."""
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
-        from repro.compat import use_mesh
         from repro.models.layers import MeshContext, flash_attention
         from repro.distributed.collectives import sp_append_attend
         mesh = jax.make_mesh((2, 4), ("data", "model"))
@@ -116,7 +114,7 @@ def test_sp_attention_numerics_under_mesh():
         vn = jax.random.normal(ks[4], (B, Sq, Hkv, D))
         clen = jnp.full((B,), 30, jnp.int32)
         start = jnp.int32(30)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             out, kc2, vc2 = jax.jit(lambda *a: sp_append_attend(*a, ctx, chunk=16))(
                 q, kc, vc, kn, vn, clen, start)
         kref = kc.at[:, 30:33].set(kn)
@@ -134,7 +132,6 @@ def test_moe_shard_map_matches_single_device():
     """EP/f-TP moe_block under a mesh == single-device moe math."""
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np, dataclasses
-        from repro.compat import use_mesh
         from repro.configs.base import get_config
         from repro.models.layers import MeshContext, init_moe, moe_block, NO_MESH
         mesh = jax.make_mesh((2, 4), ("data", "model"))
@@ -145,7 +142,7 @@ def test_moe_shard_map_matches_single_device():
             x = jax.random.normal(jax.random.key(1), (4, 8, cfg.d_model), jnp.bfloat16)
             ref, _ = moe_block(x, p, cfg, NO_MESH)
             ctx = MeshContext(mesh=mesh, batch_axes=("data",), model_axis="model")
-            with use_mesh(mesh):
+            with jax.set_mesh(mesh):
                 out, _ = jax.jit(lambda x, p: moe_block(x, p, cfg, ctx))(x, p)
             np.testing.assert_allclose(np.asarray(out, np.float32),
                                        np.asarray(ref, np.float32), rtol=6e-2, atol=6e-2)

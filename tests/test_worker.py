@@ -496,6 +496,31 @@ def test_build_engine_from_spec_forces_engine_backend():
     assert engine.pool.n_slots == spec.slots_per_replica
 
 
+def test_spawn_worker_refuses_when_parent_holds_accelerator(monkeypatch):
+    """A chip belongs to one process: once this process holds a TPU, a
+    spawned worker could never reach it, so spawn_worker refuses at once
+    instead of waiting out its startup probe."""
+    import subprocess
+
+    import jax
+    from jax._src import xla_bridge
+
+    from repro.cluster import remote
+
+    monkeypatch.setattr(xla_bridge, "backends_are_initialized", lambda: True)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def no_spawn(*a, **kw):
+        raise AssertionError("spawn_worker started a process")
+
+    monkeypatch.setattr(subprocess, "Popen", no_spawn)
+    assert remote.held_accelerator() == "tpu"
+    with pytest.raises(RuntimeError, match="holds the tpu backend"):
+        remote.spawn_worker()
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert remote.held_accelerator() is None
+
+
 # ---------------------------------------------------------------------------
 # real worker processes (slow tier)
 # ---------------------------------------------------------------------------
