@@ -503,7 +503,7 @@ class Router:
         if not p.recover_streams or not lost:
             return set()
         recovered: Set[int] = set()
-        with telemetry.span("router_recovery_seconds"):
+        with telemetry.span("router_recovery", "router_recovery_seconds"):
             for dev in lost:
                 if self._readmit(dev):
                     recovered.add(dev)
@@ -573,7 +573,7 @@ class Router:
             time.sleep(bo.attempt())
         self._respawn_count[idx] = n + 1
         try:
-            with telemetry.span("router_respawn_seconds"):
+            with telemetry.span("router_respawn", "router_respawn_seconds"):
                 replica.revive()
         except Exception as e:
             log.warning(
@@ -656,7 +656,7 @@ class Router:
             if idx is None:
                 return None
             try:
-                with telemetry.span("router_place_seconds"):
+                with telemetry.span("router_place", "router_place_seconds"):
                     stream = self.replicas[idx].admit(device_id, prompt, now)
             except ConnectionError:
                 self._evict(idx)
@@ -712,7 +712,7 @@ class Router:
                 f"replica fingerprints differ ({src_r.fingerprint} vs "
                 f"{dst_r.fingerprint}); migration would change the stream's tokens"
             )
-        with telemetry.span("router_migrate_seconds"):
+        with telemetry.span("router_migrate", "router_migrate_seconds"):
             with self._guard(src):
                 stream, row = src_r.export_stream(device_id)
             try:
@@ -851,7 +851,7 @@ class Router:
             if not r.dead and r.flavor == "remote"
         ]
         futures = {}
-        with telemetry.span("router_step_seconds"):
+        with telemetry.span("router_step", "router_step_seconds"):
             if len(remote_idx) > 1:
                 if self._pool is None:
                     self._pool = ThreadPoolExecutor(
@@ -1057,7 +1057,6 @@ class _HeartbeatMonitor(threading.Thread):
                 self.misses[i] = 0
                 continue
             self.misses[i] = self.misses.get(i, 0) + 1
-            telemetry.count("router_heartbeat_misses_total")
             if self.misses[i] >= self.policy.heartbeat_misses:
                 log.warning(
                     "replica %d missed %d consecutive heartbeat(s); marking suspect",

@@ -181,7 +181,6 @@ class AdmissionControl:
         if batch is not None and telemetry.enabled():
             for req in batch.requests:
                 telemetry.observe("admission_queue_wait_seconds", now - req.arrival)
-            telemetry.registry().gauge("admission_queue_depth").set(self.queue_depth)
         if self.planner.dropped:
             for req in self.planner.dropped:
                 if req.device_id in self.streams:

@@ -71,7 +71,7 @@ class Verdict:
     # long this round waited in the admission queue and how long its verify
     # step took, so receivers can attribute latency to queue vs verify vs wire
     queue_s: float = 0.0
-    verify_s: float = 0.0
+    verify_s: float = 0.0  # includes the device time: ends when results reach the host
 
 
 @dataclasses.dataclass
@@ -81,7 +81,7 @@ class RoundStats:
     bucket: int  # padded jit batch size
     queue_depth: int  # planner queue after dispatch
     n_commit: int  # tokens committed this round
-    step_seconds: float  # wall time of the verify call
+    step_seconds: float  # verify call through its results on the host (device time included)
 
 
 @dataclasses.dataclass
@@ -370,11 +370,12 @@ class EngineCore:
     def prefill_slot(self, slot: int, prompt: jax.Array) -> int:
         """Prefill ``prompt`` into pool row ``slot``; returns the last prompt
         token (the stream's first ``prev_token``)."""
-        with telemetry.span("engine_prefill_seconds"):
+        with telemetry.span("prefill", "engine_prefill_seconds"):
             row = self.pool.make_row_cache()
             prompt = jnp.asarray(prompt, jnp.int32)
             _, row, prev = self.steps.prefill(self.params, row, prompt[None, :])
-            self.pool.write_slot(slot, row)
+            with telemetry.span("pool_write"):
+                self.pool.write_slot(slot, row)
             return int(prev[0])
 
     def export_row(self, slot: int) -> Dict[str, jax.Array]:
@@ -442,40 +443,41 @@ class EngineCore:
         toks: np.ndarray,
         qs: Optional[np.ndarray],
         lens: np.ndarray,
-    ) -> Tuple[Any, int, float]:
-        """One bucketed verify pass over pool rows ``slots``.
+    ) -> Tuple[Any, int, int]:
+        """Launch one bucketed verify pass over pool rows ``slots``.
 
         Inputs are the un-padded per-request arrays; the core pads them to
         the enclosing bucket (scratch-slot rows for the fill) and commits
-        the accepted prefixes into the pool.  Returns
-        ``(VerifyResult, bucket, step_seconds)``.
+        the accepted prefixes into the pool.  Returns ``(VerifyResult,
+        bucket, fill)``, fill being the real requests before padding.  The
+        result's arrays are still on the device: the caller's first read of
+        them waits for the device, so it times the verify step, this call
+        only its launch.
         """
-        t_wall = time.perf_counter()
-        bucket = self.bucket_for(slots.shape[0])
-        slots_p = _pad_to(np.asarray(slots, np.int32), bucket, fill=self.pool.scratch_slot)
-        vb = verification.make_verify_batch(
-            jnp.asarray(_pad_to(prev, bucket)),
-            jnp.asarray(_pad_to(toks, bucket)),
-            jnp.asarray(_pad_to(lens, bucket)),
-            draft_q=jnp.asarray(_pad_to(qs, bucket)) if qs is not None else None,
-            seed=np.uint32(self._seed),
-        )
-        res, self.pool.cache = self.steps.verify(
-            self.params, self.pool.cache, jnp.asarray(slots_p), vb
-        )
+        with telemetry.span("pack"):
+            bucket = self.bucket_for(slots.shape[0])
+            slots_p = _pad_to(np.asarray(slots, np.int32), bucket, fill=self.pool.scratch_slot)
+            vb = verification.make_verify_batch(
+                jnp.asarray(_pad_to(prev, bucket)),
+                jnp.asarray(_pad_to(toks, bucket)),
+                jnp.asarray(_pad_to(lens, bucket)),
+                draft_q=jnp.asarray(_pad_to(qs, bucket)) if qs is not None else None,
+                seed=np.uint32(self._seed),
+            )
+            slots_d = jnp.asarray(slots_p)
+        with telemetry.span("launch"):
+            res, self.pool.cache = self.steps.verify(self.params, self.pool.cache, slots_d, vb)
         self._seed += 1
-        step_seconds = time.perf_counter() - t_wall
         if telemetry.enabled():
-            telemetry.observe("engine_verify_seconds", step_seconds)
             telemetry.observe(
                 "engine_verify_fill", slots.shape[0], buckets=telemetry.K_BUCKETS
             )
-        return res, bucket, step_seconds
+        return res, bucket, int(slots.shape[0])
 
     def force_extend(self, slot: int, feed: np.ndarray) -> None:
         """Append ``feed`` (already shifted to satisfy the KV invariant) to
         pool row ``slot`` without verification (§III-A fallback resync)."""
-        with telemetry.span("engine_commit_seconds"):
+        with telemetry.span("force_extend", "engine_commit_seconds"):
             self._force_extend(slot, feed)
 
     def _force_extend(self, slot: int, feed: np.ndarray) -> None:

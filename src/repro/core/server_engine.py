@@ -335,90 +335,98 @@ class ServerEngine:
     def step(self, now: float) -> Optional[List[Verdict]]:
         """Ask the planner for a batch; if the policy fires, verify that row
         subset and commit.  Returns per-request verdicts, or None."""
-        batch = self.admission.next_batch(now)
-        if batch is None:
-            return None
-        prev, toks, qs, lens = batch.padded_arrays()
-        slots = np.asarray(
-            [self.streams[r.device_id].slot for r in batch.requests], np.int32
-        )
-        res, bucket, step_seconds = self.core.verify(
-            slots,
-            prev,
-            toks,
-            qs if any(r.draft_q is not None for r in batch.requests) else None,
-            lens,
-        )
-
-        out_tokens = np.asarray(res.out_tokens)
-        n_accepted = np.asarray(res.n_accepted)
-        n_commit = np.asarray(res.n_commit)
-        extra = np.asarray(res.extra_token)
-        depth_after = self.queue_depth
-        verdicts = []
-        committed_round = 0
-        traced = telemetry.enabled()
-        for i, req in enumerate(batch.requests):
-            stream = self.streams[req.device_id]
-            self.admission.resolve(req.device_id)
-            self._drafted += int(lens[i])
-            self._accepted += int(n_accepted[i])
-            stream.drafted += int(lens[i])
-            stream.accepted += int(n_accepted[i])
-            n = int(n_commit[i])
-            toks_i = out_tokens[i, :n]
-            stream.committed.extend(int(t) for t in toks_i)
-            stream.prev_token = int(extra[i])
-            stream.rounds += 1
-            committed_round += n
-            queue_s = now - req.arrival
-            self._latencies.append(queue_s)
-            verdicts.append(
-                Verdict(
-                    device_id=req.device_id,
-                    # per-ROUND acceptance, not the lifetime ratio: a lifetime
-                    # average takes O(rounds) to register a regime shift, so
-                    # the device-side controller would keep burning k_max
-                    # verify tokens long after drafts stopped landing (the
-                    # client's EWMA does the smoothing)
-                    n_accepted=int(n_accepted[i]),
-                    tokens=toks_i,
-                    next_prev=int(extra[i]),
-                    accept_rate=int(n_accepted[i]) / max(int(lens[i]), 1),
+        with telemetry.span("plan"):
+            batch = self.admission.next_batch(now)
+            if batch is None:
+                return None
+            prev, toks, qs, lens = batch.padded_arrays()
+            slots = np.asarray(
+                [self.streams[r.device_id].slot for r in batch.requests], np.int32
+            )
+        # step_seconds runs until the results are on the host, so it holds
+        # the device's time as well as the dispatch's
+        t0 = time.perf_counter()
+        with telemetry.span("verify"):
+            res, bucket, _ = self.core.verify(
+                slots,
+                prev,
+                toks,
+                qs if any(r.draft_q is not None for r in batch.requests) else None,
+                lens,
+            )
+            with telemetry.span("sync"):
+                out_tokens = np.asarray(res.out_tokens)
+                n_accepted = np.asarray(res.n_accepted)
+                n_commit = np.asarray(res.n_commit)
+                extra = np.asarray(res.extra_token)
+        step_seconds = time.perf_counter() - t0
+        telemetry.observe("engine_verify_seconds", step_seconds)
+        with telemetry.span("commit"):
+            depth_after = self.queue_depth
+            verdicts = []
+            committed_round = 0
+            traced = telemetry.enabled()
+            for i, req in enumerate(batch.requests):
+                stream = self.streams[req.device_id]
+                self.admission.resolve(req.device_id)
+                self._drafted += int(lens[i])
+                self._accepted += int(n_accepted[i])
+                stream.drafted += int(lens[i])
+                stream.accepted += int(n_accepted[i])
+                n = int(n_commit[i])
+                toks_i = out_tokens[i, :n]
+                stream.committed.extend(int(t) for t in toks_i)
+                stream.prev_token = int(extra[i])
+                stream.rounds += 1
+                committed_round += n
+                queue_s = now - req.arrival
+                self._latencies.append(queue_s)
+                verdicts.append(
+                    Verdict(
+                        device_id=req.device_id,
+                        # per-ROUND acceptance, not the lifetime ratio: a lifetime
+                        # average takes O(rounds) to register a regime shift, so
+                        # the device-side controller would keep burning k_max
+                        # verify tokens long after drafts stopped landing (the
+                        # client's EWMA does the smoothing)
+                        n_accepted=int(n_accepted[i]),
+                        tokens=toks_i,
+                        next_prev=int(extra[i]),
+                        accept_rate=int(n_accepted[i]) / max(int(lens[i]), 1),
+                        queue_depth=depth_after,
+                        # server-timing breakdown: populated unconditionally (two
+                        # host floats per request) so the client-side attribution
+                        # works whether or not this process collects telemetry
+                        queue_s=queue_s,
+                        verify_s=step_seconds,
+                    )
+                )
+                if traced:
+                    seq = self._round_seq.get(req.device_id, 0)
+                    self._round_seq[req.device_id] = seq + 1
+                    ev = telemetry.TraceEvent(
+                        device_id=req.device_id, round=seq, t=now,
+                        k=int(lens[i]), n_accepted=int(n_accepted[i]), n_commit=n,
+                        queue_s=queue_s, verify_s=step_seconds,
+                    )
+                    self.trace.append(ev)
+                    self.flight.record(ev)
+                    telemetry.observe("engine_round_latency_seconds", queue_s + step_seconds)
+                    telemetry.observe("engine_k", int(lens[i]), buckets=telemetry.K_BUCKETS)
+            self._busy_seconds += step_seconds
+            self._committed_total += committed_round
+            self._t_last = max(self._t_last, now)
+            self.round_log.append(
+                RoundStats(
+                    time=now,
+                    size=batch.size,
+                    bucket=bucket,
                     queue_depth=depth_after,
-                    # server-timing breakdown: populated unconditionally (two
-                    # host floats per request) so the client-side attribution
-                    # works whether or not this process collects telemetry
-                    queue_s=queue_s,
-                    verify_s=step_seconds,
+                    n_commit=committed_round,
+                    step_seconds=step_seconds,
                 )
             )
-            if traced:
-                seq = self._round_seq.get(req.device_id, 0)
-                self._round_seq[req.device_id] = seq + 1
-                ev = telemetry.TraceEvent(
-                    device_id=req.device_id, round=seq, t=now,
-                    k=int(lens[i]), n_accepted=int(n_accepted[i]), n_commit=n,
-                    queue_s=queue_s, verify_s=step_seconds,
-                )
-                self.trace.append(ev)
-                self.flight.record(ev)
-                telemetry.observe("engine_round_latency_seconds", queue_s + step_seconds)
-                telemetry.observe("engine_k", int(lens[i]), buckets=telemetry.K_BUCKETS)
-        self._busy_seconds += step_seconds
-        self._committed_total += committed_round
-        self._t_last = max(self._t_last, now)
-        self.round_log.append(
-            RoundStats(
-                time=now,
-                size=batch.size,
-                bucket=bucket,
-                queue_depth=depth_after,
-                n_commit=committed_round,
-                step_seconds=step_seconds,
-            )
-        )
-        return verdicts
+            return verdicts
 
     # -- stats ---------------------------------------------------------------
 

@@ -1,15 +1,15 @@
 """Process-local metrics registry: counters, gauges, fixed-bucket histograms.
 
-No external dependencies — the registry renders both a Prometheus-style text
-exposition (``exposition()``) and a JSON snapshot (``snapshot()``), which is
-what crosses process boundaries inside codec v3 ``ReplicaStats`` telemetry
-payloads and lands in BENCH artifacts.
+The registry renders both a Prometheus-style text exposition
+(``exposition()``) and a JSON snapshot (``snapshot()``), which is what
+crosses process boundaries inside codec v3 ``ReplicaStats`` telemetry
+payloads.
 
 Everything here is observation-only and cheap: a metric update is a dict hit
 plus a locked float add, and the :func:`span` context manager short-circuits
-to a shared no-op object while telemetry is disabled, so instrumenting a
-host-side boundary costs one global-flag check per round when off.  Nothing
-in this module ever runs inside a jitted computation.
+to a shared no-op object while telemetry is disabled and no profiler is
+recording, so instrumenting a host-side boundary then costs two flag checks.
+Nothing in this module ever runs inside a jitted computation.
 """
 
 from __future__ import annotations
@@ -17,6 +17,8 @@ from __future__ import annotations
 import threading
 import time
 from typing import Dict, Optional, Sequence, Tuple, Union
+
+from jax.profiler import TraceAnnotation
 
 # default span buckets: sub-millisecond device hops up through multi-second
 # straggler rounds (seconds, ascending; +Inf is implicit)
@@ -308,36 +310,54 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def annotate(self, **args) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("name", "labels", "t0", "seconds")
+    __slots__ = ("histogram", "labels", "trace", "t0")
 
-    def __init__(self, name: str, labels: _LabelArg):
-        self.name = name
+    def __init__(self, phase: str, histogram: Optional[str], labels: _LabelArg):
+        self.histogram = histogram if _ENABLED else None
         self.labels = labels
+        self.trace = TraceAnnotation(f"sled.{phase}")
         self.t0 = 0.0
-        self.seconds = 0.0
 
     def __enter__(self):
+        self.trace.__enter__()
         self.t0 = time.perf_counter()
         return self
 
+    def annotate(self, **args) -> None:
+        """Attach arguments to the span (a decoded frame's ids); they become
+        the profiler event's stats."""
+        self.trace.set_metadata(**args)
+
     def __exit__(self, *exc):
-        self.seconds = time.perf_counter() - self.t0
-        _REGISTRY.histogram(self.name, labels=self.labels).observe(self.seconds)
+        seconds = time.perf_counter() - self.t0
+        self.trace.__exit__(*exc)
+        if self.histogram is not None:
+            _REGISTRY.histogram(self.histogram, labels=self.labels).observe(seconds)
         return False
 
 
-def span(name: str, labels: _LabelArg = None):
-    """Monotonic-clock span → histogram ``name``; a shared no-op when
-    telemetry is disabled.  Host-side boundaries only — never wrap jitted
-    code with this (the span would time dispatch, not compute)."""
-    if not _ENABLED:
+def span(phase: str, histogram: Optional[str] = None, labels: _LabelArg = None):
+    """A host-side phase of the serving loop.
+
+    Entered, it opens a ``jax.profiler.TraceAnnotation`` named
+    ``sled.<phase>``, so a ``jax.profiler.trace`` around a serve shows the
+    program's phases on the device trace's clock whether or not telemetry
+    is on; ``annotate`` adds stats to the event.  While telemetry
+    is on it also feeds the duration to the histogram ``histogram``, when
+    one is named.  With neither a profiler recording nor telemetry on it is
+    a shared no-op.  A span around jitted code times its dispatch only,
+    unless the span also reads the result back to the host."""
+    if not _ENABLED and not TraceAnnotation.is_enabled():
         return _NULL_SPAN
-    return _Span(name, labels)
+    return _Span(phase, histogram, labels)
 
 
 def observe(name: str, value: float, buckets: Sequence[float] = LATENCY_BUCKETS_S,

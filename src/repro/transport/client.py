@@ -220,7 +220,6 @@ class EdgeClient:
                 ) from cause
             await asyncio.sleep(self._backoff.attempt())
             self.stats.reconnects += 1
-            telemetry.count("client_reconnects_total")
             try:
                 fresh = await self.reconnect_cb()
                 old = self.ep

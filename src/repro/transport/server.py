@@ -38,10 +38,13 @@ from typing import Deque, Dict, List, Optional, Tuple, Union
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.cluster.router import Router
 from repro.core.server_engine import EngineStats, ServerEngine
 from repro.transport import codec
 from repro.transport.links import Endpoint
+
+Replies = List[Tuple[int, bytes]]  # (device_id, encoded frame) to send, in order
 
 
 class TransportServer:
@@ -96,9 +99,12 @@ class TransportServer:
             frame = await ep.recv()
             if frame is None:
                 break
-            msg, _ = codec.decode_frame(frame)
-            device_id = msg.device_id
-            await self._dispatch(msg, ep)
+            with telemetry.span("recv") as sp:
+                msg, _ = codec.decode_frame(frame)
+                device_id = msg.device_id
+                sp.annotate(device_id=device_id, seq=getattr(msg, "seq", -1))  # -1: no round
+                replies = self._dispatch(msg, ep)
+            await self._send_all(replies)
         # peer vanished without a Close: reclaim the slot — unless the
         # device already redialed on a fresh endpoint (EdgeClient reconnect
         # maps the new conn via Hello before closing the dead one), in
@@ -108,7 +114,7 @@ class TransportServer:
             and device_id in self.engine.streams
             and self._conns.get(device_id) is ep
         ):
-            await self._retire(device_id)
+            await self._send_all(self._retire(device_id))
 
     def _record(self, device_id: int, frame: bytes, seq: int) -> None:
         """No-await bookkeeping: must happen before the frame hits the wire."""
@@ -128,53 +134,54 @@ class TransportServer:
             # after it redials.
             pass
 
-    async def _dispatch(self, msg, ep: Endpoint) -> None:
+    async def _send_all(self, replies: Replies) -> None:
+        for dev, frame in replies:
+            await self._send(dev, frame)
+
+    # The handlers below run with no await: they change the engine's state
+    # and return the frames to send once they are done.
+
+    def _dispatch(self, msg, ep: Endpoint) -> Replies:
         dev = msg.device_id
         if isinstance(msg, codec.Hello):
             self._conns[dev] = ep
             if dev in self.engine.streams:
                 # duplicate Hello: the Admit was lost — resend, don't re-admit
                 slot = self.engine.streams[dev].slot
-                await self._send(dev, codec.encode_frame(codec.Admit(dev, ok=True, slot=slot)))
-                return
+                return [(dev, codec.encode_frame(codec.Admit(dev, ok=True, slot=slot)))]
             if any(d == dev for d, _ in self._pending_admits):
-                return  # already queued for a slot
+                return []  # already queued for a slot
             stream = self.engine.admit(dev, jnp.asarray(msg.prompt, jnp.int32), self.now())
             if stream is None:
                 self._pending_admits.append((dev, np.asarray(msg.prompt, np.int32)))
-                await self._send(dev, codec.encode_frame(codec.Admit(dev, ok=False)))
-            else:
-                await self._send(
-                    dev, codec.encode_frame(codec.Admit(dev, ok=True, slot=stream.slot))
-                )
-        elif isinstance(msg, codec.DraftPacket):
+                return [(dev, codec.encode_frame(codec.Admit(dev, ok=False)))]
+            return [(dev, codec.encode_frame(codec.Admit(dev, ok=True, slot=stream.slot)))]
+        if isinstance(msg, codec.DraftPacket):
             if dev not in self.engine.streams:
-                return  # raced a retirement; the client is closing
+                return []  # raced a retirement; the client is closing
             if self.engine.has_inflight(dev):
-                return  # duplicate frame for the round already queued
+                return []  # duplicate frame for the round already queued
             if self._last_reply_seq.get(dev, -1) >= msg.seq:
-                return  # stale resend of a round that already resolved
+                return []  # stale resend of a round that already resolved
             self._req_seq[dev] = msg.seq
             self.engine.submit(dev, msg.tokens, self.now(), draft_q=msg.draft_q)
             self._wake.set()
-        elif isinstance(msg, codec.Fallback):
-            await self._handle_fallback(msg)
-        elif isinstance(msg, codec.Close):
-            if dev in self.engine.streams:
-                await self._retire(dev)
-        else:
-            raise codec.CodecError(f"server cannot handle {type(msg).__name__}")
+            return []
+        if isinstance(msg, codec.Fallback):
+            return self._handle_fallback(msg)
+        if isinstance(msg, codec.Close):
+            return self._retire(dev) if dev in self.engine.streams else []
+        raise codec.CodecError(f"server cannot handle {type(msg).__name__}")
 
-    async def _handle_fallback(self, msg: codec.Fallback) -> None:
+    def _handle_fallback(self, msg: codec.Fallback) -> Replies:
         dev = msg.device_id
         if dev not in self.engine.streams:
-            return
+            return []
         if self._last_reply_seq.get(dev, -1) >= msg.seq:
             # this round already resolved (verdict or earlier ack) — the
             # stored reply is authoritative; resend it, the device reconciles
             self.late_verdicts_resent += 1
-            await self._send(dev, self._last_reply[dev])
-            return
+            return [(dev, self._last_reply[dev])]
         # request still queued (cancel it) or lost on the wire (nothing to
         # cancel): either way the stream resyncs with the released tokens
         self.engine.cancel_request(dev)
@@ -182,9 +189,9 @@ class TransportServer:
         self.fallback_acks += 1
         ack = codec.encode_frame(codec.FallbackAck(dev, msg.seq, next_prev))
         self._record(dev, ack, msg.seq)
-        await self._send(dev, ack)
+        return [(dev, ack)]
 
-    async def _retire(self, device_id: int) -> None:
+    def _retire(self, device_id: int) -> Replies:
         self.engine.retire(device_id)
         self._req_seq.pop(device_id, None)
         self._last_reply.pop(device_id, None)
@@ -196,9 +203,8 @@ class TransportServer:
             if stream is None:  # still full (another admit raced us)
                 self._pending_admits.appendleft((dev, prompt))
             else:
-                await self._send(
-                    dev, codec.encode_frame(codec.Admit(dev, ok=True, slot=stream.slot))
-                )
+                return [(dev, codec.encode_frame(codec.Admit(dev, ok=True, slot=stream.slot)))]
+        return []
 
     # -- the serving loop ----------------------------------------------------
 
@@ -207,41 +213,44 @@ class TransportServer:
             now = self.now()
             verdicts = self.engine.step(now)
             if verdicts:
-                # encode + record with NO awaits in between: once anything
-                # else runs, every verdict of this round must be authoritative
-                outgoing = []
-                for v in verdicts:
-                    seq = self._req_seq.get(v.device_id, 0)
-                    frame = codec.encode_frame(
-                        codec.Verdict(
-                            device_id=v.device_id,
-                            seq=seq,
-                            n_accepted=v.n_accepted,
-                            tokens=np.asarray(v.tokens, np.int32),
-                            next_prev=v.next_prev,
-                            accept_rate=v.accept_rate,
-                            queue_depth=v.queue_depth,
-                            queue_s=v.queue_s,
-                            verify_s=v.verify_s,
+                with telemetry.span("send"):
+                    # encode + record with NO awaits in between: once anything
+                    # else runs, every verdict of this round must be authoritative
+                    outgoing = []
+                    for v in verdicts:
+                        seq = self._req_seq.get(v.device_id, 0)
+                        frame = codec.encode_frame(
+                            codec.Verdict(
+                                device_id=v.device_id,
+                                seq=seq,
+                                n_accepted=v.n_accepted,
+                                tokens=np.asarray(v.tokens, np.int32),
+                                next_prev=v.next_prev,
+                                accept_rate=v.accept_rate,
+                                queue_depth=v.queue_depth,
+                                queue_s=v.queue_s,
+                                verify_s=v.verify_s,
+                            )
                         )
-                    )
-                    self._record(v.device_id, frame, seq)
-                    outgoing.append((v.device_id, frame))
-                for dev, frame in outgoing:
-                    await self._send(dev, frame)
+                        self._record(v.device_id, frame, seq)
+                        outgoing.append((v.device_id, frame))
+                    await self._send_all(outgoing)
                 await asyncio.sleep(0)  # let replies land before re-stepping
                 continue
             hint = self.engine.next_event_hint(now)
             timeout = self.idle_tick
-            if self.engine.queue_depth:
+            queued = self.engine.queue_depth
+            if queued:
                 # work is queued but the policy hasn't fired: wake at the
                 # planner's next deadline/straggler event (or quickly, for
                 # policies that fire on arrival)
                 timeout = max(hint - now, 0.0) + 1e-4 if hint is not None else 1e-3
-            try:
-                await asyncio.wait_for(self._wake.wait(), timeout)
-            except asyncio.TimeoutError:
-                pass
+            # demand-bound (nothing queued) or held by the batching policy
+            with telemetry.span("hold" if queued else "await_work"):
+                try:
+                    await asyncio.wait_for(self._wake.wait(), timeout)
+                except asyncio.TimeoutError:
+                    pass
             self._wake.clear()
 
     # -- stats ---------------------------------------------------------------

@@ -136,7 +136,6 @@ class WorkerCore:
         key = self._replay_key(msg)
         if key is not None and key in self._replay:
             self.replay_hits += 1
-            telemetry.count("worker_replay_hits_total")
             return self._replay[key]
         try:
             reply = self._dispatch(msg)
@@ -304,7 +303,7 @@ class ReplicaWorker:
             self._transport = TransportServer(self.core.engine)
         srv = self._transport
         srv._endpoints.append(ep)  # wire stats: this conn counts in stats()
-        await srv._dispatch(first, ep)
+        await srv._send_all(srv._dispatch(first, ep))
         if srv._stepper is None:
             srv._stepper = asyncio.get_running_loop().create_task(srv._step_loop())
         device_id = getattr(first, "device_id", None)
@@ -314,9 +313,9 @@ class ReplicaWorker:
                 break
             msg, _ = codec.decode_frame(frame)
             device_id = msg.device_id
-            await srv._dispatch(msg, ep)
+            await srv._send_all(srv._dispatch(msg, ep))
         if device_id is not None and device_id in srv.engine.streams:
-            await srv._retire(device_id)
+            await srv._send_all(srv._retire(device_id))
 
 
 def main(argv: Optional[List[str]] = None) -> None:

@@ -117,7 +117,7 @@ def test_span_is_noop_when_disabled():
 
 def test_span_records_when_enabled():
     telemetry.enable(True)
-    with telemetry.span("engine_verify_seconds"):
+    with telemetry.span("verify", "engine_verify_seconds"):
         pass
     h = telemetry.registry().histogram("engine_verify_seconds")
     assert h.count == 1
