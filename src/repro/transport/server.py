@@ -26,8 +26,17 @@ the same no-await stretch as ``engine.step``, so a Fallback frame processed
 later can never force-extend a stream whose round was already verified.
 
 Single-process, single event loop: engine steps and device drafting
-interleave at await points rather than truly overlapping (documented limit;
-real sockets across hosts are a ROADMAP item).
+interleave at await points rather than truly overlapping.  ``engine.step``
+blocks the loop, and so does an admission's prefill inside a reader's
+``_dispatch``; frames that land meanwhile wait in their sockets.  So after
+sending a call's verdicts the stepper *drains* before it plans the next
+call: it yields in passes of ``_PASS_TURNS`` loop turns, each long enough
+for a readable socket's bytes to reach ``_dispatch``, and stops at the
+first pass that dispatched no frame.  Every frame that landed during the
+call, or during an admission the drain itself let run, makes the next
+call.  The drain takes at most one pass per connected device, so a peer
+that writes on every turn holds the stepper for that many passes, not for
+ever.
 """
 from __future__ import annotations
 
@@ -46,6 +55,11 @@ from repro.transport.links import Endpoint
 
 Replies = List[Tuple[int, bytes]]  # (device_id, encoded frame) to send, in order
 
+# Loop turns from a socket turning readable to its frame in ``_dispatch``
+# over a StreamEndpoint: the selector's ``_read_ready`` feeds the reader,
+# the reader task wakes, its ``recv`` returns and dispatches.
+_PASS_TURNS = 3
+
 
 class TransportServer:
     def __init__(self, engine: Union[ServerEngine, Router], *, idle_tick: float = 0.05):
@@ -61,6 +75,7 @@ class TransportServer:
         self._tasks: List[asyncio.Task] = []
         self._stepper: Optional[asyncio.Task] = None
         self._t0: Optional[float] = None
+        self._dispatched = 0  # frames handled by _dispatch, ever
         self.late_verdicts_resent = 0
         self.fallback_acks = 0
 
@@ -142,6 +157,7 @@ class TransportServer:
     # and return the frames to send once they are done.
 
     def _dispatch(self, msg, ep: Endpoint) -> Replies:
+        self._dispatched += 1
         dev = msg.device_id
         if isinstance(msg, codec.Hello):
             self._conns[dev] = ep
@@ -235,7 +251,7 @@ class TransportServer:
                         self._record(v.device_id, frame, seq)
                         outgoing.append((v.device_id, frame))
                     await self._send_all(outgoing)
-                await asyncio.sleep(0)  # let replies land before re-stepping
+                await self._drain()
                 continue
             hint = self.engine.next_event_hint(now)
             timeout = self.idle_tick
@@ -252,6 +268,23 @@ class TransportServer:
                 except asyncio.TimeoutError:
                     pass
             self._wake.clear()
+
+    async def _drain(self) -> None:
+        """Yield until a whole pass dispatches no frame (module docstring).
+
+        Bounded by one pass per connected device: each pass that goes on
+        dispatched a frame, so that many passes read a frame from every
+        device even if each landed behind an admission the pass before ran.
+        A wall-clock bound would cut the drain at the first prefill."""
+        with telemetry.span("drain"):
+            start = self._dispatched
+            for _ in range(max(1, len(self._conns))):
+                before = self._dispatched
+                for _ in range(_PASS_TURNS):
+                    await asyncio.sleep(0)
+                if self._dispatched == before:
+                    break
+            telemetry.count("transport_frames_drained_total", self._dispatched - start)
 
     # -- stats ---------------------------------------------------------------
 

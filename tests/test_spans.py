@@ -3,9 +3,12 @@
 A tiny ServerEngine behind the TransportServer, over in-process links,
 served under ``jax.profiler`` on the CPU: every phase of the loop shows up as a host
 event on the profiler's clock, the verify call's parts nest inside it, a
-frame's read carries its device and round, and the profiler changes no
-served token.  And ``verify_s`` times the verify step through its results
-on the host, not the dispatch alone.
+frame's read carries its device and round, the drain after each call's
+verdicts holds the reads it made, and the profiler changes no served
+token; all with telemetry off, as the benchmark serves.  A second serve
+with telemetry on checks that the drain counts the frames it read.  And
+``verify_s`` times the verify step through its results on the host, not
+the dispatch alone.
 """
 
 import asyncio
@@ -29,8 +32,8 @@ from repro.transport.server import TransportServer
 V = 128
 LAG = NetProfile("lag", rtt_mean=0.02, rtt_jitter=0.0, bandwidth_bps=1e8)
 PHASES = (
-    "recv", "await_work", "hold", "send", "plan", "verify", "pack", "launch", "sync",
-    "commit", "prefill", "pool_write",
+    "recv", "await_work", "hold", "send", "drain", "plan", "verify", "pack", "launch",
+    "sync", "commit", "prefill", "pool_write",
 )
 
 
@@ -89,8 +92,26 @@ def _host_spans(profile_dir):
 
 @pytest.fixture(scope="module")
 def profiled(models, tmp_path_factory):
+    """The served tokens and the host spans, with telemetry off: the spans
+    come from the profiler alone, as the benchmark records them."""
     d = tmp_path_factory.mktemp("profile")
     return _serve(models, d), _host_spans(d)
+
+
+@pytest.fixture(scope="module")
+def counted(models, tmp_path_factory):
+    """The host spans and the drain's frame counter of a second profiled
+    serve, with telemetry on so that counters count."""
+    d = tmp_path_factory.mktemp("counted")
+    telemetry.registry().reset()
+    telemetry.enable(True)
+    try:
+        _serve(models, d)
+        drained = telemetry.registry().counter("transport_frames_drained_total").value
+    finally:
+        telemetry.enable(False)
+        telemetry.registry().reset()
+    return _host_spans(d), drained
 
 
 def test_every_phase_is_a_profiler_span(profiled):
@@ -114,6 +135,35 @@ def test_recv_carries_device_and_round(profiled):
     assert recv and all(st["device_id"] in (0, 1) and "seq" in st for st in recv)
     rounds = {(st["device_id"], st["seq"]) for st in recv if st["seq"] >= 0}
     assert {0, 1} == {d for d, _ in rounds} and len(rounds) > 2
+
+
+def _drained_recvs(spans):
+    """The ``sled.recv`` spans inside a ``sled.drain``, after checking that
+    each drain follows a ``sled.send`` and that no read straddles a drain."""
+    drains = sorted((a, b) for name, a, b, _ in spans if name == "sled.drain")
+    sends = sorted(b for name, _, b, _ in spans if name == "sled.send")
+    assert drains and len(drains) == len(sends)
+    assert all(s <= a for s, (a, _) in zip(sends, drains))
+    recv = [(a, b) for name, a, b, _ in spans if name == "sled.recv"]
+    inside = [r for r in recv if any(a <= r[0] and r[1] <= b for a, b in drains)]
+    assert not any(
+        a < r[1] and r[0] < b for r in recv if r not in inside for a, b in drains
+    )
+    return inside
+
+
+def test_drain_holds_its_reads(profiled):
+    """A ``sled.recv`` lies wholly inside a drain or wholly outside every
+    drain, with telemetry off."""
+    _, spans = profiled
+    assert _drained_recvs(spans)
+
+
+def test_drain_holds_its_reads_and_counts_them(counted):
+    """With telemetry on, the counter counts the frames read inside drains."""
+    spans, drained = counted
+    inside = _drained_recvs(spans)
+    assert inside and drained == len(inside)
 
 
 def test_profiler_changes_no_served_token(models, profiled):

@@ -12,6 +12,11 @@ agree token-for-token with each other.
 
 import asyncio
 import dataclasses
+import select
+import socket
+import threading
+import time
+from types import SimpleNamespace
 
 import jax
 import numpy as np
@@ -24,8 +29,8 @@ from repro.models.model_zoo import build_model, perturb_params
 from repro.serving.devices import NETS, NetProfile
 from repro.transport import codec
 from repro.transport.client import EdgeClient
-from repro.transport.links import LoopbackLink, SimulatedLink, make_link
-from repro.transport.server import TransportServer
+from repro.transport.links import LoopbackLink, SimulatedLink, make_link, tcp_connect, tcp_listen
+from repro.transport.server import _PASS_TURNS, TransportServer
 
 V = 128
 
@@ -463,6 +468,259 @@ def test_transport_client_without_hook_still_raises():
             await client._redial(ConnectionError("boom"))
 
     asyncio.run(inner())
+
+
+# ---------------------------------------------------------------------------
+# the stepper's read order, over localhost TCP
+# ---------------------------------------------------------------------------
+
+
+class _FakeEngine:
+    """The surface TransportServer drives, with no model.  ``step`` verifies
+    every queued request and records which devices each call carried;
+    ``on_step(devices)`` and ``on_admit(device)``, when set, run inside the
+    call and block the event loop the way a device call and a prefill do."""
+
+    def __init__(self):
+        self.streams = {}
+        self.queue = {}
+        self.calls = []
+        self.forced = []
+        self.on_step = self.on_admit = None
+
+    @property
+    def queue_depth(self):
+        return len(self.queue)
+
+    def admit(self, dev, prompt, now=0.0):
+        if self.on_admit is not None:
+            self.on_admit(dev)
+        self.streams[dev] = SimpleNamespace(slot=len(self.streams))
+        return self.streams[dev]
+
+    def has_inflight(self, dev):
+        return dev in self.queue
+
+    def submit(self, dev, tokens, now, draft_q=None):
+        self.queue[dev] = np.asarray(tokens, np.int32)
+
+    def next_event_hint(self, now):
+        return None
+
+    def step(self, now):
+        batch, self.queue = self.queue, {}
+        self.calls.append(set(batch))
+        if batch and self.on_step is not None:
+            self.on_step(set(batch))
+        return [
+            SimpleNamespace(device_id=d, n_accepted=len(t), tokens=t, next_prev=int(t[-1]),
+                            accept_rate=1.0, queue_depth=0, queue_s=0.0, verify_s=0.0)
+            for d, t in batch.items()
+        ]
+
+    def cancel_request(self, dev):
+        return self.queue.pop(dev, None) is not None
+
+    def force_extend(self, dev, tokens):
+        self.forced.append(dev)
+        return int(tokens[-1])
+
+    def retire(self, dev):
+        self.streams.pop(dev, None)
+        self.queue.pop(dev, None)
+
+
+class _Device:
+    """A device's blocking TCP socket, driven from the test's thread."""
+
+    def __init__(self, port):
+        self.sock = socket.create_connection(("127.0.0.1", port), timeout=10.0)
+        self.decoder = codec.FrameDecoder()
+
+    def send(self, msg):
+        self.sock.sendall(codec.encode_frame(msg))
+
+    def recv(self):
+        while (raw := self.decoder.next_raw()) is None:
+            data = self.sock.recv(65536)
+            assert data, "the server closed the connection"
+            self.decoder.feed(data)
+        return codec.decode_frame(raw)[0]
+
+
+_TOKS = np.asarray([3, 1, 4], np.int32)
+
+
+def _hello(dial, dev):
+    d = dial()
+    d.send(codec.Hello(dev, np.arange(4, dtype=np.int32)))
+    assert d.recv().ok
+    return d
+
+
+def _draft(dev, seq=1):
+    return codec.DraftPacket(dev, seq, _TOKS)
+
+
+def _wait_readable(*eps):
+    """Block (the event loop, when called from the engine) until bytes wait
+    unread in each server endpoint's socket."""
+    pending = {ep._writer.get_extra_info("socket") for ep in eps}
+    while pending:
+        ready, _, _ = select.select(list(pending), [], [], 10.0)
+        assert ready, "the frames never reached the server's sockets"
+        pending -= set(ready)
+
+
+def _serve_tcp(engine, client):
+    """Serve ``engine`` over localhost TCP while ``client(dial, server)`` runs
+    in a thread (``dial()`` opens a _Device); returns its result and the
+    server."""
+    devices = []
+
+    async def inner():
+        server = TransportServer(engine)
+        listener, port = await tcp_listen(server.attach)
+
+        def dial():
+            devices.append(_Device(port))
+            return devices[-1]
+
+        try:
+            return await asyncio.to_thread(client, dial, server), server
+        finally:
+            for d in devices:
+                d.sock.close()
+            await server.stop()
+            for ep in server._endpoints:
+                ep.close()
+            listener.close()
+
+    return asyncio.run(inner())
+
+
+def test_frames_that_land_during_a_call_make_the_next_call():
+    """Frames for A and B land while the call carrying C blocks the loop:
+    the very next call carries them; the one after is not the first."""
+    engine, in_call = _FakeEngine(), threading.Event()
+    A, B, C = 0, 1, 2
+
+    def client(dial, server):
+        devs = {d: _hello(dial, d) for d in (A, B, C)}
+
+        def gate(batch):
+            if batch == {C}:
+                in_call.set()
+                _wait_readable(server._conns[A], server._conns[B])
+
+        engine.on_step = gate
+        devs[C].send(_draft(C))
+        assert in_call.wait(10.0)
+        devs[A].send(_draft(A))
+        devs[B].send(_draft(B))
+        return {d: devs[d].recv() for d in (C, A, B)}
+
+    verdicts, _ = _serve_tcp(engine, client)
+    assert all(isinstance(v, codec.Verdict) and v.seq == 1 for v in verdicts.values())
+    i = engine.calls.index({C})
+    assert engine.calls[i + 1] == {A, B}, engine.calls[i:]
+
+
+def test_a_frame_that_lands_during_an_admission_makes_the_next_call():
+    """E's Hello lands during the call carrying C; its admission blocks like
+    a prefill while A's frame lands.  The drain reads A before the next call."""
+    engine = _FakeEngine()
+    in_call, admitting = threading.Event(), threading.Event()
+    A, C, E = 0, 2, 3
+
+    def client(dial, server):
+        devs = {d: _hello(dial, d) for d in (A, C)}
+        e = dial()
+        deadline = time.monotonic() + 10.0
+        while len(server._endpoints) < 3 and time.monotonic() < deadline:
+            time.sleep(0.001)
+        (e_ep,) = [ep for ep in server._endpoints if ep not in server._conns.values()]
+
+        def step_gate(batch):
+            if batch == {C}:
+                in_call.set()
+                _wait_readable(e_ep)
+
+        def admit_gate(dev):
+            if dev == E:
+                admitting.set()
+                _wait_readable(server._conns[A])
+
+        engine.on_step, engine.on_admit = step_gate, admit_gate
+        devs[C].send(_draft(C))
+        assert in_call.wait(10.0)
+        e.send(codec.Hello(E, np.arange(4, dtype=np.int32)))
+        assert admitting.wait(10.0)
+        devs[A].send(_draft(A))
+        return e.recv(), devs[C].recv(), devs[A].recv()
+
+    (admit, vc, va), _ = _serve_tcp(engine, client)
+    assert admit.ok and (vc.device_id, va.device_id) == (C, A)
+    i = engine.calls.index({C})
+    assert engine.calls[i + 1] == {A}, engine.calls[i:]
+
+
+def test_a_flooding_peer_cannot_hold_the_stepper_past_the_bound():
+    """A device that writes a fresh round on every loop turn keeps every
+    drain pass busy; the stepper still plans a call at least once per
+    bound (one pass per connected device: here one) and a pass."""
+    engine, writes, at_call = _FakeEngine(), [0], []
+    n_writes = 1000
+    engine.on_step = lambda batch: at_call.append(writes[0])
+
+    async def inner():
+        server = TransportServer(engine)
+        listener, port = await tcp_listen(server.attach)
+        ep = await tcp_connect("127.0.0.1", port)
+        await ep.send(codec.encode_frame(codec.Hello(0, np.arange(4, dtype=np.int32))))
+        assert codec.decode_frame(await ep.recv())[0].ok
+        for seq in range(1, n_writes + 1):
+            await ep.send(codec.encode_frame(_draft(0, seq)))
+            writes[0] = seq
+            await asyncio.sleep(0)
+        await server.stop()
+        for e in (ep, *server._endpoints):
+            e.close()
+        listener.close()
+        return server
+
+    server = asyncio.run(inner())
+    during = [w for w in at_call if w < n_writes]
+    assert len(during) >= 100, len(during)
+    assert max(np.diff(during)) <= 2 * _PASS_TURNS
+    assert server._dispatched > len(at_call)
+
+
+def test_a_fallback_that_lands_during_its_verify_call_gets_the_verdict():
+    """Race discipline: the call verifying A's round records its verdict
+    before any await, so a Fallback for that round that landed during the
+    call, and is read by the drain, gets the stored Verdict resent."""
+    engine, in_call = _FakeEngine(), threading.Event()
+
+    def client(dial, server):
+        dev = _hello(dial, 0)
+
+        def gate(batch):
+            if batch == {0}:
+                in_call.set()
+                _wait_readable(server._conns[0])
+
+        engine.on_step = gate
+        dev.send(_draft(0))
+        assert in_call.wait(10.0)
+        dev.send(codec.Fallback(0, 1, _TOKS))
+        return dev.recv(), dev.recv()
+
+    (first, second), server = _serve_tcp(engine, client)
+    assert isinstance(first, codec.Verdict) and isinstance(second, codec.Verdict)
+    assert first.seq == second.seq == 1
+    np.testing.assert_array_equal(first.tokens, second.tokens)
+    assert (server.late_verdicts_resent, server.fallback_acks, engine.forced) == (1, 0, [])
 
 
 # ---------------------------------------------------------------------------
