@@ -35,13 +35,29 @@ class ModelConfig:
     rope_theta: float = 10_000.0
     tie_embeddings: bool = False
     use_rope: bool = True
+    norm_eps: float = 1e-6  # RMSNorm epsilon
 
-    # MoE
+    # latent attention (MLA, DeepSeek-V2/V3): 0 = per-head K/V.  The cache
+    # holds one (kv_lora_rank + qk_rope_head_dim)-wide row per position and
+    # layer: the normed latent and the roped key part shared by all heads.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+
+    # MoE.  Routing drops no tokens.  ``held_experts`` is (first, count) of
+    # the routed experts this device holds (count 0: all of them); the
+    # router still scores all ``num_experts``.
     num_experts: int = 0
     experts_per_token: int = 0
     moe_d_ff: int = 0
     router_aux_coef: float = 0.001
-    capacity_factor: float = 1.25
+    first_k_dense: int = 0  # leading dense layers (DeepSeek's first_k_dense_replace)
+    num_shared_experts: int = 0  # one shared SwiGLU of width num_shared_experts * moe_d_ff
+    router_scoring: str = "softmax"  # softmax | sigmoid
+    router_bias: bool = False  # selection bias: top-k by score + bias, weighted by score
+    routed_scale: float = 1.0  # factor on the normalised top-k weights
+    held_experts: Tuple[int, int] = (0, 0)
 
     # SSM (Mamba2 / SSD)
     ssm_state: int = 0
@@ -72,6 +88,12 @@ class ModelConfig:
         return self.d_model // self.num_heads if self.num_heads else 0
 
     @property
+    def held(self) -> Tuple[int, int]:
+        """(first, count) of the routed experts held here."""
+        first, count = self.held_experts
+        return first, count or self.num_experts
+
+    @property
     def ssm_heads(self) -> int:
         return (self.ssm_expand * self.d_model) // self.ssm_head_dim
 
@@ -90,17 +112,22 @@ class ModelConfig:
         if self.family in ("dense", "moe", "vlm", "hybrid_attn"):
             pass
         if self.family in ("dense", "moe", "vlm"):
-            qkv = d * hd * (self.num_heads + 2 * self.num_kv_heads) + hd * self.num_heads * d
+            if self.kv_lora_rank:
+                h, r, rope = self.num_heads, self.kv_lora_rank, self.qk_rope_head_dim
+                qkv = (d * h * (self.qk_nope_head_dim + rope) + d * (r + rope) + r
+                       + r * h * (self.qk_nope_head_dim + self.v_head_dim) + h * self.v_head_dim * d)
+            else:
+                qkv = d * hd * (self.num_heads + 2 * self.num_kv_heads) + hd * self.num_heads * d
             if self.qkv_bias:
                 qkv += hd * (self.num_heads + 2 * self.num_kv_heads)
-            per_layer += qkv
+            per_layer += qkv + 2 * d  # attention, norms
             if self.family == "moe":
-                per_layer += d * self.num_experts  # router
-                per_layer += self.num_experts * (3 * d * self.moe_d_ff)
+                moe = d * self.num_experts + self.held[1] * 3 * d * self.moe_d_ff
+                moe += 3 * d * self.num_shared_experts * self.moe_d_ff
+                n += self.first_k_dense * (per_layer + 3 * d * self.d_ff)
+                n += (L - self.first_k_dense) * (per_layer + moe)
             else:
-                per_layer += 3 * d * self.d_ff
-            per_layer += 2 * d  # norms
-            n += L * per_layer
+                n += L * (per_layer + 3 * d * self.d_ff)
         elif self.family == "ssm":
             n += L * self._ssd_layer_params()
         elif self.family == "hybrid":
@@ -129,8 +156,9 @@ class ModelConfig:
         """Active params per token (MoE: only routed experts count)."""
         if self.family != "moe":
             return self.param_count()
-        dense_share = self.param_count() - self.num_layers * self.num_experts * 3 * self.d_model * self.moe_d_ff
-        active_moe = self.num_layers * self.experts_per_token * 3 * self.d_model * self.moe_d_ff
+        n_moe = self.num_layers - self.first_k_dense
+        dense_share = self.param_count() - n_moe * self.held[1] * 3 * self.d_model * self.moe_d_ff
+        active_moe = n_moe * self.experts_per_token * 3 * self.d_model * self.moe_d_ff
         return dense_share + active_moe
 
     def reduced(self) -> "ModelConfig":
@@ -146,7 +174,11 @@ class ModelConfig:
             vocab_size=256,
         )
         if self.family == "moe":
-            kw.update(num_experts=4, experts_per_token=2, moe_d_ff=32)
+            kw.update(num_experts=4, experts_per_token=2, moe_d_ff=32, held_experts=(0, 0))
+        if self.kv_lora_rank:
+            kw.update(kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16)
+        if self.first_k_dense:
+            kw.update(first_k_dense=1, num_layers=3)
         if self.family in ("ssm", "hybrid"):
             kw.update(ssm_state=16, ssm_head_dim=16, ssm_chunk=16)
         if self.family == "hybrid":
@@ -226,6 +258,7 @@ _CONFIG_MODULES = [
     "mamba2_370m",
     "qwen3_moe_30b_a3b",
     "granite_moe_3b_a800m",
+    "moonlight_16b_a3b",
     "llava_next_mistral_7b",
     "sled_paper",
 ]

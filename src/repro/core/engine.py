@@ -212,6 +212,9 @@ class VerifySteps:
         kv_dtype: Any = "bf16",
     ):
         self.model = model
+        # MoE steps report the rows each held expert computed
+        # (engine_moe_expert_rows_total), only while telemetry is on
+        self.expert_rows = telemetry.enabled() and model.cfg.family == "moe"
         self.greedy = greedy
         self.temperature = temperature
         self.scratch_slot = scratch_slot
@@ -234,6 +237,7 @@ class VerifySteps:
                 temperature=temperature,
                 attn_chunk=attn_chunk,
                 paged_attention=self.paged_attention,
+                expert_rows=self.expert_rows,
             )
         )
         self.prefill = jax.jit(

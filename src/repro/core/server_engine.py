@@ -359,6 +359,10 @@ class ServerEngine:
                 n_accepted = np.asarray(res.n_accepted)
                 n_commit = np.asarray(res.n_commit)
                 extra = np.asarray(res.extra_token)
+        if res.expert_rows is not None:
+            first = self.core.model.cfg.held[0]
+            for e, n in enumerate(np.asarray(res.expert_rows).tolist()):
+                telemetry.count("engine_moe_expert_rows_total", n, labels={"expert": first + e})
         step_seconds = time.perf_counter() - t0
         telemetry.observe("engine_verify_seconds", step_seconds)
         with telemetry.span("commit"):

@@ -40,12 +40,15 @@ class VerifyResult:
     extra_token: jax.Array  # (B,) correction (rejected) or bonus (all accepted)
     accepted_mask: jax.Array  # (B, K)
     rejected: jax.Array    # (B,) True if a draft was rejected (m < length)
+    # (count,) rows each held expert computed this call, summed over layers;
+    # only from a verify step built with expert_rows=True (telemetry on)
+    expert_rows: Optional[jax.Array] = None
 
 
 jax.tree_util.register_dataclass(
     VerifyResult,
     data_fields=["n_accepted", "n_commit", "out_tokens", "extra_token",
-                 "accepted_mask", "rejected"],
+                 "accepted_mask", "rejected", "expert_rows"],
     meta_fields=[],
 )
 

@@ -15,6 +15,7 @@ SLED's entire server-side hot loop.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Tuple
 
 import jax
@@ -95,6 +96,7 @@ def make_paged_verify_step(
     temperature: float = 1.0,
     attn_chunk: int = 1024,
     paged_attention: bool = True,
+    expert_rows: bool = False,
 ):
     """Slot-indexed verify step for continuous batching over a row pool.
 
@@ -121,9 +123,22 @@ def make_paged_verify_step(
     Padding convention (both modes): unused entries point at
     ``scratch_slot`` with ``lengths = 0``; the step resets the scratch row's
     committed length so repeated padding can never walk scratch state off
-    the end of the buffer.
+    the end of the buffer.  MoE models route only the real tokens (a real
+    row's first ``lengths + 1`` positions) through their experts; with
+    ``expert_rows`` the result carries the rows each held expert computed
+    (``VerifyResult.expert_rows``).
     """
     use_paged = paged_attention and supports_paged_attention(model.cfg)
+    moe = model.cfg.family == "moe"
+
+    def _routed(slots, batch):
+        if not moe:
+            return {}
+        pos = jnp.arange(batch["tokens_in"].shape[1], dtype=jnp.int32)
+        return {"valid": (slots != scratch_slot)[:, None] & (pos[None] <= batch["lengths"][:, None])}
+
+    def _with_rows(res, stats):
+        return dataclasses.replace(res, expert_rows=stats.rows) if expert_rows else res
 
     def _verify_logits(params, h, batch) -> VerifyResult:
         logits = model.lm_head(params, h)  # (B_bucket, K+1, V) fp32
@@ -141,10 +156,11 @@ def make_paged_verify_step(
 
     def paged_verify_step(params, pool, slots, batch) -> Tuple[VerifyResult, Any]:
         base_len = jnp.take(pool["length"], slots, axis=0)
-        h, new_pool, _ = model.decode_forward(
-            params, pool, batch["tokens_in"], ctx, attn_chunk=attn_chunk, slots=slots
+        h, new_pool, stats = model.decode_forward(
+            params, pool, batch["tokens_in"], ctx, attn_chunk=attn_chunk, slots=slots,
+            **_routed(slots, batch),
         )
-        res = _verify_logits(params, h, batch)
+        res = _with_rows(_verify_logits(params, h, batch), stats)
         # commit = per-slot length bump; duplicate scratch entries race, but
         # the scratch row is reset right after (and never read as committed)
         length = new_pool["length"].at[slots].set(
@@ -155,10 +171,10 @@ def make_paged_verify_step(
 
     def gather_verify_step(params, pool, slots, batch) -> Tuple[VerifyResult, Any]:
         sub = gather_slots(pool, slots)
-        h, ck_sub, _ = model.decode_forward(
-            params, sub, batch["tokens_in"], ctx, attn_chunk=attn_chunk
+        h, ck_sub, stats = model.decode_forward(
+            params, sub, batch["tokens_in"], ctx, attn_chunk=attn_chunk, **_routed(slots, batch)
         )
-        res = _verify_logits(params, h, batch)
+        res = _with_rows(_verify_logits(params, h, batch), stats)
         new_sub = model.commit(ck_sub, res.n_commit)
         new_pool = scatter_slots(pool, slots, new_sub)
         new_pool["length"] = new_pool["length"].at[scratch_slot].set(0)

@@ -101,7 +101,7 @@ def forward(cfg, params, tokens, ctx: MeshContext = NO_MESH, *, remat=False,
     x, _ = jax.lax.scan(group_body, x, groups)
     if cfg.num_layers % cfg.attn_every:
         x, _ = jax.lax.scan(ssm_body, x, tail)
-    return L.apply_norm(x, params["final_norm"], cfg.norm), jnp.zeros((), jnp.float32)
+    return L.apply_norm(x, params["final_norm"], cfg), jnp.zeros((), jnp.float32)
 
 
 def _run_cached(cfg, params, cache, tokens, ctx, attn_chunk, decode: bool):
@@ -144,7 +144,7 @@ def _run_cached(cfg, params, cache, tokens, ctx, attn_chunk, decode: bool):
     ssm_s, conv_s = jax.tree.map(merge, g_states[0], t_states[0]), jax.tree.map(
         merge, g_states[1], t_states[1]
     )
-    x = L.apply_norm(x, params["final_norm"], cfg.norm)
+    x = L.apply_norm(x, params["final_norm"], cfg)
     return x, ssm_s, conv_s, new_kv
 
 
@@ -172,7 +172,7 @@ def decode_forward(cfg, params, cache, tokens, ctx: MeshContext = NO_MESH, *,
     x, ssm_ck, conv_ck, new_kv = _run_cached(cfg, params, cache, tokens, ctx, attn_chunk, True)
     ckpt_cache = {**cache, "k": new_kv[0], "v": new_kv[1],
                   "ssm_ckpt": ssm_ck, "conv_ckpt": conv_ck}
-    return x, ckpt_cache, jnp.zeros((), jnp.float32)
+    return x, ckpt_cache, L.MoEStats(jnp.zeros((), jnp.float32), None)
 
 
 def select_checkpoint(cache: Dict[str, jax.Array], n_commit: jax.Array) -> Dict[str, jax.Array]:

@@ -80,8 +80,8 @@ def rollback(cache: Dict[str, jax.Array], new_length: jax.Array) -> Dict[str, ja
 
 def supports_paged_attention(cfg) -> bool:
     """True when every cache leaf the verify forward touches is attention-
-    shaped (k/v/cross buffers + length), so the slot-indexed fast path can
-    run against the pool.  SSM and hybrid caches carry recurrent state
+    shaped (k/v/cross buffers, or latent attention's ckv/kpe, + length), so
+    the slot-indexed fast path can run against the pool.  SSM and hybrid caches carry recurrent state
     leaves that must still ride the gather/scatter fallback."""
     return getattr(cfg, "family", None) not in ("ssm", "hybrid")
 

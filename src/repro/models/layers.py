@@ -1,4 +1,5 @@
-"""Core pure-JAX layers: norms, RoPE, flash-style attention, MLP, MoE.
+"""Core pure-JAX layers: norms, RoPE, flash-style attention, latent
+attention (MLA), MLP, MoE.
 
 Everything is a pure function over parameter pytrees (no flax).  Attention is
 implemented as an online-softmax scan over KV chunks ("xla_flash") so that
@@ -10,7 +11,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -136,9 +137,9 @@ def layernorm(x: jax.Array, scale: jax.Array, bias: jax.Array, eps: float = 1e-5
     return (y * scale.astype(jnp.float32) + bias.astype(jnp.float32)).astype(dtype)
 
 
-def apply_norm(x: jax.Array, p: Params, kind: str) -> jax.Array:
-    if kind == "rmsnorm":
-        return rmsnorm(x, p["scale"])
+def apply_norm(x: jax.Array, p: Params, cfg) -> jax.Array:
+    if cfg.norm == "rmsnorm":
+        return rmsnorm(x, p["scale"], cfg.norm_eps)
     return layernorm(x, p["scale"], p["bias"])
 
 
@@ -555,6 +556,128 @@ def attention_block(
 
 
 # ---------------------------------------------------------------------------
+# Latent attention (MLA, DeepSeek-V2/V3), served in the absorbed form
+# ---------------------------------------------------------------------------
+
+
+def init_mla(key, cfg) -> Params:
+    d, h, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    nope, rope_d, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    k1, k2, k3, k4 = jax.random.split(key, 4)
+    std = 0.02
+    out_std = std / math.sqrt(2 * max(cfg.num_layers, 1))
+    n = lambda k, shp, sd=std: (jax.random.normal(k, shp) * sd).astype(jnp.bfloat16)
+    return {
+        "wq": n(k1, (d, h * (nope + rope_d))),
+        "wkv_a": n(k2, (d, r + rope_d)),
+        "kv_norm": {"scale": jnp.ones((r,), jnp.float32)},
+        "wkv_b": n(k3, (r, h * (nope + vd))),
+        "wo": n(k4, (h * vd, d), out_std),
+    }
+
+
+def latent_attention(
+    q_lat: jax.Array,  # (B, Sq, H, R): absorbed queries
+    q_pe: jax.Array,  # (B, Sq, H, rope): roped query parts
+    ckv: jax.Array,  # (L, n_rows, S, R): normed latent cache stack
+    kpe: jax.Array,  # (L, n_rows, S, rope): roped key cache stack
+    *,
+    layer: jax.Array,
+    rows: Optional[jax.Array],  # (B,) pool rows; None -> row b for batch b
+    kv_valid: jax.Array,  # (B,)
+    q_pos: jax.Array,  # (B, Sq)
+    scale: float,
+    chunk: int,
+) -> jax.Array:
+    """Chunked online-softmax attention of every head against one shared
+    latent row per position (multi-query): score ``q_lat . c~ + q_pe .
+    k_pe``, value ``c~``.  Returns (B, Sq, H, R)."""
+    B, Sq, H, R = q_lat.shape
+    n_rows, Skv, rope_d = ckv.shape[1], ckv.shape[2], kpe.shape[-1]
+    chunk = min(chunk, Skv)
+    if Skv % chunk:
+        raise ValueError(f"latent cache length {Skv} is not a multiple of chunk {chunk}")
+
+    def load(buf, idx, width):
+        blk = jax.lax.dynamic_slice(buf, (layer, 0, idx * chunk, 0), (1, n_rows, chunk, width))[0]
+        return blk if rows is None else jnp.take(blk, rows, axis=0)  # (B, chunk, width)
+
+    def body(carry, idx):
+        m, l, acc = carry
+        cb, pb = load(ckv, idx, R), load(kpe, idx, rope_d)
+        s = (jnp.einsum("bshr,bcr->bshc", q_lat, cb, preferred_element_type=jnp.float32)
+             + jnp.einsum("bshr,bcr->bshc", q_pe, pb, preferred_element_type=jnp.float32)) * scale
+        j = idx * chunk + jnp.arange(chunk, dtype=jnp.int32)
+        mask = (j[None, None, :] < kv_valid[:, None, None]) & (j[None, None, :] <= q_pos[:, :, None])
+        s = jnp.where(mask[:, :, None, :], s, NEG_INF)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        p = jnp.exp(s - m_new[..., None])
+        corr = jnp.exp(m - m_new)
+        l = l * corr + p.sum(axis=-1)
+        acc = acc * corr[..., None] + jnp.einsum(
+            "bshc,bcr->bshr", p.astype(cb.dtype), cb, preferred_element_type=jnp.float32
+        )
+        return (m_new, l, acc), None
+
+    init = (jnp.full((B, Sq, H), NEG_INF, jnp.float32), jnp.zeros((B, Sq, H), jnp.float32),
+            jnp.zeros((B, Sq, H, R), jnp.float32))
+    (m, l, acc), _ = jax.lax.scan(body, init, jnp.arange(Skv // chunk, dtype=jnp.int32))
+    return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q_lat.dtype)
+
+
+def mla_block(
+    x: jax.Array,  # (B, S, d)
+    p: Params,
+    cfg,
+    *,
+    positions: jax.Array,  # (B, S)
+    pool: Tuple[jax.Array, jax.Array],  # (ckv (L, n_rows, Smax, R), kpe (L, n_rows, Smax, rope))
+    cache_len: jax.Array,  # (B,)
+    layer: jax.Array,
+    rows: Optional[jax.Array] = None,  # (B,) pool rows (slot pool); None -> row b
+    chunk: int = 1024,
+) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
+    """Latent attention with its cache append; returns (out, pool').
+
+    Per token: ``[q_nope | q_pe] = x W_q`` per head, ``[c | k_pe] = x
+    W_kva``, ``c~ = RMSNorm(c)``, RoPE on q_pe and on k_pe (one head shared
+    by all heads).  The cache keeps ``c~`` and ``k_pe`` per position, in two
+    leaves: one 576-wide row would pad to 640 lanes on the TPU and take a
+    layout copy of the whole pool in and out of the step.  Scores use the
+    absorbed query ``q^_h = q_nope,h W_kvb^{K,h}T`` against c~ plus ``q_pe
+    . k_pe`` over sqrt(nope + rope); the head output is ``(sum p c~)
+    W_kvb^{V,h}`` — the plain form's k_nope and v never materialise.  Fresh
+    rows go into pool rows ``rows`` at ``cache_len`` with one
+    dynamic_update_slice per batch row and leaf (the slot pool's only
+    write; see attention_block).
+    """
+    B, S, _ = x.shape
+    h, r = cfg.num_heads, cfg.kv_lora_rank
+    nope, rope_d, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    q = (x @ p["wq"]).reshape(B, S, h, nope + rope_d)
+    q_pe = rope(q[..., nope:], positions, cfg.rope_theta)
+    kva = x @ p["wkv_a"]
+    c = rmsnorm(kva[..., :r], p["kv_norm"]["scale"], cfg.norm_eps)
+    k_pe = rope(kva[..., None, r:], positions, cfg.rope_theta)[:, :, 0]
+    ckv, kpe = pool
+    c, k_pe = c.astype(ckv.dtype), k_pe.astype(kpe.dtype)
+    for b in range(B):
+        row = jnp.int32(b) if rows is None else rows[b].astype(jnp.int32)
+        start = (layer, row, cache_len[b].astype(jnp.int32), jnp.int32(0))
+        ckv = jax.lax.dynamic_update_slice(ckv, c[b][None, None], start)
+        kpe = jax.lax.dynamic_update_slice(kpe, k_pe[b][None, None], start)
+    wkv_b = p["wkv_b"].reshape(r, h, nope + vd)
+    q_lat = jnp.einsum("bshn,rhn->bshr", q[..., :nope], wkv_b[..., :nope])
+    o = latent_attention(
+        q_lat, q_pe.astype(q_lat.dtype), ckv, kpe, layer=layer, rows=rows,
+        kv_valid=cache_len + S, q_pos=positions,
+        scale=1.0 / math.sqrt(nope + rope_d), chunk=chunk,
+    )
+    o = jnp.einsum("bshr,rhv->bshv", o, wkv_b[..., nope:])
+    return o.reshape(B, S, h * vd) @ p["wo"], (ckv, kpe)
+
+
+# ---------------------------------------------------------------------------
 # MLP
 # ---------------------------------------------------------------------------
 
@@ -584,104 +707,127 @@ def mlp_block(x: jax.Array, p: Params, cfg) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# MoE (top-k, capacity-based dispatch; EP via shard_map when a mesh is given)
+# MoE (top-k, dropless grouped matmul over the experts held here; EP via
+# shard_map when a mesh is given)
 # ---------------------------------------------------------------------------
+
+
+class MoEStats(NamedTuple):
+    """What MoE layers report beside their output (summed over layers by a
+    stack)."""
+    aux: jax.Array  # () Switch-style load-balance loss over all experts
+    rows: Optional[jax.Array]  # (held,) int32 (token, choice) pairs each
+    # held expert computed; None where no layer routed
 
 
 def init_moe(key, cfg) -> Params:
     d, f, e = cfg.d_model, cfg.moe_d_ff, cfg.num_experts
+    first, count = cfg.held
     k1, k2, k3, k4 = jax.random.split(key, 4)
     std = 0.02
     out_std = std / math.sqrt(2 * max(cfg.num_layers, 1))
-    return {
+    p = {
         "router": (jax.random.normal(k1, (d, e)) * std).astype(jnp.float32),
-        "wg": (jax.random.normal(k2, (e, d, f)) * std).astype(jnp.bfloat16),
-        "wu": (jax.random.normal(k3, (e, d, f)) * std).astype(jnp.bfloat16),
-        "wd": (jax.random.normal(k4, (e, f, d)) * out_std).astype(jnp.bfloat16),
+        "wg": (jax.random.normal(k2, (e, d, f)) * std)[first:first + count].astype(jnp.bfloat16),
+        "wu": (jax.random.normal(k3, (e, d, f)) * std)[first:first + count].astype(jnp.bfloat16),
+        "wd": (jax.random.normal(k4, (e, f, d)) * out_std)[first:first + count].astype(jnp.bfloat16),
     }
+    if cfg.router_bias:
+        p["router_bias"] = jnp.zeros((e,), jnp.float32)
+    return p
 
 
-def _moe_local(x_flat: jax.Array, p: Params, cfg, e_start: int, e_local: int,
-               capacity: int) -> Tuple[jax.Array, jax.Array]:
-    """MoE math over a contiguous slice of experts [e_start, e_start+e_local).
+def route(x_flat: jax.Array, p: Params, cfg) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Router over ALL ``cfg.num_experts`` experts, in float32.
 
-    x_flat: (T, d) local tokens. Router runs over ALL experts (replicated,
-    cheap); only assignments landing in the local expert slice are dispatched.
-    Returns (out (T, d) partial sum over local experts, aux loss scalar).
+    Returns (scores (T, E), top-k expert ids (T, K), their weights (T, K)).
+    softmax scoring: weights are the top-k probabilities renormalised.
+    sigmoid scoring (DeepSeek-V3): the top k are chosen by score + the
+    selection bias, weighted by score, normalised and scaled by
+    ``cfg.routed_scale``.
+    """
+    K = cfg.experts_per_token
+    logits = x_flat.astype(jnp.float32) @ p["router"]  # (T, E)
+    if cfg.router_scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        sel = scores + p["router_bias"] if "router_bias" in p else scores
+        _, top_idx = jax.lax.top_k(sel, K)
+        top_vals = jnp.take_along_axis(scores, top_idx, axis=-1)
+        top_vals = top_vals / (top_vals.sum(-1, keepdims=True) + 1e-20)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+        top_vals, top_idx = jax.lax.top_k(scores, K)
+        top_vals = top_vals / jnp.maximum(top_vals.sum(-1, keepdims=True), 1e-9)
+    return scores, top_idx, top_vals * cfg.routed_scale
+
+
+def moe_routed(x_flat: jax.Array, p: Params, cfg, first, count: int,
+               valid: Optional[jax.Array] = None) -> Tuple[jax.Array, MoEStats]:
+    """The routed experts' part of an MoE layer, for the experts
+    ``[first, first + count)`` that ``p`` holds (``first`` may be traced).
+
+    x_flat: (T, d) tokens; ``valid`` (T,) bool marks real tokens (bucket
+    padding is routed nowhere).  The router scores all experts; each
+    (token, choice) that lands on a held expert is computed, none is
+    dropped: assignments are sorted by expert and run as one grouped
+    matmul per projection (``lax.ragged_dot``), so a token's result never
+    depends on which other tokens share its batch.  Returns (out (T, d)
+    partial sum over the held experts, MoEStats: the aux loss over all
+    experts, rows (count,) computed per held expert).
     """
     T, d = x_flat.shape
     E, K = cfg.num_experts, cfg.experts_per_token
-
-    logits = (x_flat.astype(jnp.float32) @ p["router"])  # (T, E)
-    gates = jax.nn.softmax(logits, axis=-1)
-    top_vals, top_idx = jax.lax.top_k(gates, K)  # (T, K)
-    top_vals = top_vals / jnp.maximum(top_vals.sum(-1, keepdims=True), 1e-9)
-
-    # aux load-balancing loss (Switch-style), computed over all experts
-    me = jnp.mean(gates, axis=0)  # (E,)
+    scores, top_idx, top_w = route(x_flat, p, cfg)
+    me = jnp.mean(scores, axis=0)  # (E,)
     ce = jnp.zeros((E,), jnp.float32).at[top_idx.reshape(-1)].add(1.0) / (T * K)
     aux = E * jnp.sum(me * ce)
 
-    # local assignment mask + position within each local expert
-    local = (top_idx >= e_start) & (top_idx < e_start + e_local)  # (T, K)
-    e_rel = jnp.where(local, top_idx - e_start, 0)  # (T, K)
-    flat_onehot = (
-        jax.nn.one_hot(e_rel, e_local, dtype=jnp.int32)
-        * local[..., None].astype(jnp.int32)
-    ).reshape(T * K, e_local)
-    pos = (jnp.cumsum(flat_onehot, axis=0) - flat_onehot)  # position per assignment
-    pos = (pos * flat_onehot).sum(-1).reshape(T, K)
-    keep = local & (pos < capacity)
-
-    # scatter tokens into (e_local, capacity, d)
-    disp = jnp.zeros((e_local, capacity, d), x_flat.dtype)
-    t_rep = jnp.broadcast_to(jnp.arange(T)[:, None], (T, K))
-    e_flat = jnp.where(keep, e_rel, e_local)  # drop -> OOB row
-    disp = disp.at[e_flat.reshape(-1), pos.reshape(-1)].add(
-        jnp.where(keep.reshape(-1, 1), x_flat[t_rep.reshape(-1)], 0),
-        mode="drop",
-    )
-
-    h = jnp.einsum("ecd,edf->ecf", disp, p["wg"], preferred_element_type=jnp.float32)
-    u = jnp.einsum("ecd,edf->ecf", disp, p["wu"], preferred_element_type=jnp.float32)
-    h = (jax.nn.silu(h) * u).astype(x_flat.dtype)
-    y = jnp.einsum("ecf,efd->ecd", h, p["wd"], preferred_element_type=jnp.float32)
-
-    # gather back: out[t] += gate * y[e, pos]
-    vals = y[e_flat.reshape(-1), pos.reshape(-1)]  # (T*K, d)
-    vals = vals * (top_vals.reshape(-1, 1) * keep.reshape(-1, 1))
-    out = jnp.zeros((T, d), jnp.float32).at[t_rep.reshape(-1)].add(vals)
-    return out.astype(x_flat.dtype), aux
+    held = (top_idx >= first) & (top_idx < first + count)  # (T, K)
+    if valid is not None:
+        held = held & valid[:, None]
+    group = jnp.where(held, top_idx - first, count).reshape(-1)  # absent -> group `count`
+    order = jnp.argsort(group, stable=True)
+    rows = jnp.zeros((count + 1,), jnp.int32).at[group].add(1)[:count]
+    xs = x_flat[order // K]  # (T*K, d), grouped by expert
+    h = jax.lax.ragged_dot(xs, p["wg"], rows, preferred_element_type=jnp.float32)
+    u = jax.lax.ragged_dot(xs, p["wu"], rows, preferred_element_type=jnp.float32)
+    a = (jax.nn.silu(h) * u).astype(x_flat.dtype)
+    y = jax.lax.ragged_dot(a, p["wd"], rows, preferred_element_type=jnp.float32)
+    # assignments past the held groups belong to no group: zero them
+    y = jnp.where((jnp.arange(T * K) < rows.sum())[:, None], y, 0.0)
+    y = jnp.zeros_like(y).at[order].set(y).reshape(T, K, d)  # back to (token, choice)
+    w = jnp.where(held, top_w, 0.0)
+    out = jnp.einsum("tkd,tk->td", y, w)
+    return out.astype(x_flat.dtype), MoEStats(aux, rows)
 
 
-def moe_block(x: jax.Array, p: Params, cfg, ctx: MeshContext) -> Tuple[jax.Array, jax.Array]:
-    """x: (B, S, d) -> (B, S, d), aux-loss.
+def moe_block(x: jax.Array, p: Params, cfg, ctx: MeshContext,
+              valid: Optional[jax.Array] = None) -> Tuple[jax.Array, MoEStats]:
+    """x: (B, S, d) -> (B, S, d), MoEStats; ``valid`` (B, S) marks the real
+    tokens (padding is routed nowhere; None: all).
 
-    Under a mesh: tokens stay sharded over the batch axes; experts are
-    sharded over the model axis when E % tp == 0 (EP), otherwise expert-
-    internal d_ff is sharded (f-TP).  Either way the partial outputs are
-    psum'd over the model axis — same collective volume as a dense TP MLP.
+    Without a mesh: the experts ``cfg.held`` names.  Under a mesh: tokens
+    stay sharded over the batch axes; experts are sharded over the model
+    axis when E % tp == 0 (EP: each rank is :func:`moe_routed` over its
+    own slice), otherwise expert-internal d_ff is sharded (f-TP).  Either
+    way the partial outputs are psum'd over the model axis — same
+    collective volume as a dense TP MLP — and the rows cover all E experts.
     """
     B, S, d = x.shape
-    E, K = cfg.num_experts, cfg.experts_per_token
-
-    def cap(tokens: int) -> int:
-        c = int(math.ceil(tokens * K / E * cfg.capacity_factor))
-        return max(8, -(-c // 8) * 8)  # round up to 8
+    E = cfg.num_experts
+    flat = lambda v, t: None if v is None else v.reshape(t)
 
     if ctx.mesh is None:
-        out, aux = _moe_local(x.reshape(B * S, d), p, cfg, 0, E, cap(B * S))
-        return out.reshape(B, S, d), aux
+        first, count = cfg.held
+        out, st = moe_routed(x.reshape(B * S, d), p, cfg, first, count, flat(valid, B * S))
+        return out.reshape(B, S, d), st
 
     tp = ctx.tp
     ep = E % tp == 0
     ax = ctx.model_axis
-    batch_spec = P(ctx.batch_axes) if ctx.batch_axes else P()
     n_batch_shards = 1
     for a in ctx.batch_axes:
         n_batch_shards *= ctx.mesh.shape[a]
-    t_local = (B // n_batch_shards) * S
-    capacity = cap(t_local)
 
     if ep:
         w_specs = {
@@ -697,29 +843,38 @@ def moe_block(x: jax.Array, p: Params, cfg, ctx: MeshContext) -> Tuple[jax.Array
             "wu": P(None, None, ax),
             "wd": P(None, ax, None),
         }
+    if "router_bias" in p:
+        w_specs["router_bias"] = P(None)
 
-    def shard_fn(xb, pw):
+    def shard_fn(xb, pw, vb=None):
         tb, _, _ = xb.shape
-        xf = xb.reshape(tb * S, d)
+        xf, vf = xb.reshape(tb * S, d), flat(vb, tb * S)
         if ep:
-            idx = jax.lax.axis_index(ax)
             e_local = E // tp
-            out, aux = _moe_local(xf, pw, cfg, idx * e_local, e_local, capacity)
+            out, (aux, rows) = moe_routed(xf, pw, cfg, jax.lax.axis_index(ax) * e_local,
+                                          e_local, vf)
+            rows = jax.lax.all_gather(rows, ax, tiled=True)  # (E,)
         else:
             # f-TP: all experts, partial d_ff -> swiglu is elementwise in f,
             # wd contracts the local f slice; psum completes both f and E sums.
-            out, aux = _moe_local(xf, pw, cfg, 0, E, capacity)
+            out, (aux, rows) = moe_routed(xf, pw, cfg, 0, E, vf)
         # aux is computed from the full router on every model rank; de-dup.
         aux = aux / tp
         out = jax.lax.psum(out, ax)
         aux = jax.lax.psum(aux, ax)
-        return out.reshape(tb, S, d), aux
+        if ctx.batch_axes:
+            rows = jax.lax.psum(rows, ctx.batch_axes)
+        return out.reshape(tb, S, d), aux, rows
 
-    out, aux = jax.shard_map(
+    batch = ctx.batch_axes if ctx.batch_axes else None
+    args, specs = (x, {k: p[k] for k in w_specs}), (P(batch, None, None), w_specs)
+    if valid is not None:
+        args, specs = args + (valid,), specs + (P(batch, None),)
+    out, aux, rows = jax.shard_map(
         shard_fn,
         mesh=ctx.mesh,
-        in_specs=(P(ctx.batch_axes if ctx.batch_axes else None, None, None), w_specs),
-        out_specs=(P(ctx.batch_axes if ctx.batch_axes else None, None, None), P()),
+        in_specs=specs,
+        out_specs=(P(batch, None, None), P(), P()),
         check_vma=False,
-    )(x, {k: p[k] for k in ("router", "wg", "wu", "wd")})
-    return out, aux / n_batch_shards
+    )(*args)
+    return out, MoEStats(aux / n_batch_shards, rows)

@@ -182,7 +182,7 @@ def ssd_layer_forward(
     B, S, d = x.shape
     H, N, Pd = cfg.ssm_heads, cfg.ssm_state, cfg.ssm_head_dim
     cw = cfg.conv_width
-    zxbcdt = L.apply_norm(x, lp["norm"], cfg.norm) @ lp["in_proj"]
+    zxbcdt = L.apply_norm(x, lp["norm"], cfg) @ lp["in_proj"]
     z, xBC, dt = _split_in_proj(cfg, zxbcdt)
     if conv0 is not None:
         full = jnp.concatenate([conv0.astype(xBC.dtype), xBC], axis=1)
@@ -222,7 +222,7 @@ def ssd_layer_decode(
     B, K, d = x.shape
     H, N, Pd = cfg.ssm_heads, cfg.ssm_state, cfg.ssm_head_dim
     cw = cfg.conv_width
-    zxbcdt = L.apply_norm(x, lp["norm"], cfg.norm) @ lp["in_proj"]
+    zxbcdt = L.apply_norm(x, lp["norm"], cfg) @ lp["in_proj"]
     z, xBC, dt = _split_in_proj(cfg, zxbcdt)
     full = jnp.concatenate([conv0.astype(xBC.dtype), xBC], axis=1)  # (B, cw-1+K, C)
     conv_out = _causal_conv(full, lp["conv_w"], lp["conv_b"])[:, cw - 1 :]
@@ -303,7 +303,7 @@ def forward(cfg, params, tokens, ctx: MeshContext = NO_MESH, *, remat=False, **_
     if remat:
         body = jax.checkpoint(body, prevent_cse=False)
     x, _ = jax.lax.scan(body, x, params["layers"])
-    return L.apply_norm(x, params["final_norm"], cfg.norm), jnp.zeros((), jnp.float32)
+    return L.apply_norm(x, params["final_norm"], cfg), jnp.zeros((), jnp.float32)
 
 
 def prefill(cfg, params, tokens, cache, ctx: MeshContext = NO_MESH, **_):
@@ -316,7 +316,7 @@ def prefill(cfg, params, tokens, cache, ctx: MeshContext = NO_MESH, **_):
         return out, (hf, cf.astype(jnp.bfloat16))
 
     x, (hs, convs) = jax.lax.scan(body, x, (params["layers"], cache["ssm"], cache["conv"]))
-    x = L.apply_norm(x, params["final_norm"], cfg.norm)
+    x = L.apply_norm(x, params["final_norm"], cfg)
     new_cache = {"ssm": hs, "conv": convs, "length": cache["length"] + tokens.shape[1]}
     return lm_head(cfg, params, x[:, -1:, :])[:, 0], new_cache
 
@@ -344,9 +344,9 @@ def decode_forward(cfg, params, cache, tokens, ctx: MeshContext = NO_MESH, *,
         return out, (h_ck, c_ck)
 
     x, (h_cks, c_cks) = jax.lax.scan(body, x, (params["layers"], cache["ssm"], cache["conv"]))
-    x = L.apply_norm(x, params["final_norm"], cfg.norm)
+    x = L.apply_norm(x, params["final_norm"], cfg)
     ckpt_cache = {**cache, "ssm_ckpt": h_cks, "conv_ckpt": c_cks}
-    return x, ckpt_cache, jnp.zeros((), jnp.float32)
+    return x, ckpt_cache, L.MoEStats(jnp.zeros((), jnp.float32), None)
 
 
 def select_checkpoint(cache: Dict[str, jax.Array], n_commit: jax.Array) -> Dict[str, jax.Array]:

@@ -6,7 +6,7 @@
   lm_head(params, hidden)              -> logits fp32
   make_cache(batch, max_len, ...)      -> cache pytree (or specs)
   prefill(params, tokens, cache, ctx)  -> (last logits, cache)
-  decode_forward(params, cache, toks)  -> (hidden, ckpt_cache, aux)  [verify]
+  decode_forward(params, cache, toks)  -> (hidden, ckpt_cache, layers.MoEStats)  [verify]
   commit(cache, n_commit)              -> cache  [speculative rollback]
 
 The stub frontends ([audio]/[vlm]) enter via forward/prefill kwargs

@@ -5,6 +5,13 @@ Pure functions over parameter pytrees.  Layers are stacked along a leading
 compile time) is independent of depth.  The same ``decode_forward`` serves
 prefill (S = prompt length, cache_len = 0) and SLED verification
 (S = K draft tokens + 1, cache_len = committed length).
+
+Latent-attention models (``cfg.kv_lora_rank``, DeepSeek-V3 blocks) keep
+``cfg.first_k_dense`` leading dense layers in a stack of their own
+(``dense_layers``) ahead of the MoE stack, shared experts beside the routed
+ones (``shared``), and two cache leaves per position and layer: ``ckv``
+(L, B, S, kv_lora_rank), the normed latent, and ``kpe`` (L, B, S,
+qk_rope_head_dim), the roped key part shared by all heads.
 """
 from __future__ import annotations
 
@@ -25,15 +32,19 @@ Params = Dict[str, Any]
 # ---------------------------------------------------------------------------
 
 
-def _init_layer(cfg, key) -> Params:
+def _init_layer(cfg, key, moe: Optional[bool] = None) -> Params:
+    if moe is None:
+        moe = cfg.family == "moe"
     ks = jax.random.split(key, 4)
     p = {
         "ln1": L.init_norm(cfg.d_model, cfg.norm),
-        "attn": L.init_attention(ks[0], cfg),
+        "attn": (L.init_mla if cfg.kv_lora_rank else L.init_attention)(ks[0], cfg),
         "ln2": L.init_norm(cfg.d_model, cfg.norm),
     }
-    if cfg.family == "moe":
+    if moe:
         p["moe"] = L.init_moe(ks[1], cfg)
+        if cfg.num_shared_experts:
+            p["shared"] = L.init_mlp(ks[2], cfg, cfg.num_shared_experts * cfg.moe_d_ff)
     else:
         p["mlp"] = L.init_mlp(ks[1], cfg)
     return p
@@ -54,6 +65,8 @@ def _stack_init(init_fn, cfg, key, n) -> Params:
 
 def init_params(cfg, key, *, max_pos: int = 0) -> Params:
     """``max_pos`` sizes the learned position table (non-RoPE archs only)."""
+    if cfg.first_k_dense and not cfg.kv_lora_rank:
+        raise ValueError("leading dense layers are served on the latent-attention stack only")
     k_emb, k_layers, k_head, k_enc = jax.random.split(key, 4)
     p: Params = {
         "embed": (jax.random.normal(k_emb, (cfg.vocab_size, cfg.d_model)) * 0.02).astype(
@@ -62,7 +75,10 @@ def init_params(cfg, key, *, max_pos: int = 0) -> Params:
         "final_norm": L.init_norm(cfg.d_model, cfg.norm),
     }
     layer_init = _init_cross_layer if cfg.is_encdec else _init_layer
-    p["layers"] = _stack_init(layer_init, cfg, k_layers, cfg.num_layers)
+    p["layers"] = _stack_init(layer_init, cfg, k_layers, cfg.num_layers - cfg.first_k_dense)
+    if cfg.first_k_dense:
+        p["dense_layers"] = _stack_init(lambda c, k: _init_layer(c, k, moe=False), cfg,
+                                        jax.random.fold_in(k_layers, 1), cfg.first_k_dense)
     if not cfg.tie_embeddings:
         p["lm_head"] = (
             jax.random.normal(k_head, (cfg.d_model, cfg.vocab_size)) * 0.02
@@ -112,31 +128,43 @@ def _block(
     flash_remat: bool = False,
     slots: Optional[jax.Array] = None,
     kv_scales: Optional[Tuple[jax.Array, jax.Array]] = None,
-) -> Tuple[jax.Array, Optional[Tuple[jax.Array, ...]], jax.Array]:
-    h = L.apply_norm(x, lp["ln1"], cfg.norm)
-    a, new_kv = L.attention_block(
-        h, lp["attn"], cfg,
-        positions=positions, kv_cache=kv, cache_len=cache_len,
-        cache_layer=cache_layer, uniform_start=uniform_start,
-        causal=causal, chunk=attn_chunk, ctx=ctx, flash_remat=flash_remat,
-        slots=slots, kv_scales=kv_scales,
-    )
+    valid: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, Optional[Tuple[jax.Array, ...]], L.MoEStats]:
+    """One layer; returns (x', the cache leaves it wrote, MoEStats).  Latent
+    attention (``cfg.kv_lora_rank``) takes ``kv`` = the whole (ckv, kpe)
+    stacks and ``cache_layer``; ``valid`` (B, S) marks the tokens MoE routes."""
+    h = L.apply_norm(x, lp["ln1"], cfg)
+    if cfg.kv_lora_rank:
+        a, new_kv = L.mla_block(
+            h, lp["attn"], cfg, positions=positions, pool=kv, cache_len=cache_len,
+            layer=cache_layer, rows=slots, chunk=attn_chunk,
+        )
+    else:
+        a, new_kv = L.attention_block(
+            h, lp["attn"], cfg,
+            positions=positions, kv_cache=kv, cache_len=cache_len,
+            cache_layer=cache_layer, uniform_start=uniform_start,
+            causal=causal, chunk=attn_chunk, ctx=ctx, flash_remat=flash_remat,
+            slots=slots, kv_scales=kv_scales,
+        )
     x = x + a
     if cross is not None:
-        h = L.apply_norm(x, lp["ln_x"], cfg.norm)
+        h = L.apply_norm(x, lp["ln_x"], cfg)
         a, _ = L.attention_block(
             h, lp["xattn"], cfg,
             positions=positions, cross_kv=cross, cross_len=cross_len,
             cross_layer=cross_layer, chunk=attn_chunk, slots=slots,
         )
         x = x + a
-    h = L.apply_norm(x, lp["ln2"], cfg.norm)
-    aux = jnp.zeros((), jnp.float32)
+    h = L.apply_norm(x, lp["ln2"], cfg)
+    stats = L.MoEStats(jnp.zeros((), jnp.float32), None)
     if "moe" in lp:
-        m, aux = L.moe_block(h, lp["moe"], cfg, ctx)
+        m, stats = L.moe_block(h, lp["moe"], cfg, ctx, valid)
+        if "shared" in lp:
+            m = m + L.mlp_block(h, lp["shared"], cfg)
     else:
         m = L.mlp_block(h, lp["mlp"], cfg)
-    return x + m, new_kv, aux
+    return x + m, new_kv, stats
 
 
 # ---------------------------------------------------------------------------
@@ -156,6 +184,14 @@ def forward(
     attn_chunk: int = 1024,
 ) -> Tuple[jax.Array, jax.Array]:
     """Returns (hidden (B, S_total, d), aux_loss). Use lm_head() for logits."""
+    if cfg.kv_lora_rank:
+        # latent attention runs against its cache layout; a fresh cache of
+        # the sequence's length makes the full forward
+        B, S = tokens.shape
+        cache = make_cache(cfg, B, S, attn_chunk=min(attn_chunk, S))
+        x, _, stats = decode_forward(cfg, params, cache, tokens, ctx,
+                                     attn_chunk=min(attn_chunk, S))
+        return x, stats.aux
     x = L.embed_lookup(params["embed"], tokens, ctx)
     if embeds_prefix is not None:
         x = jnp.concatenate([embeds_prefix.astype(x.dtype), x], axis=1)
@@ -177,20 +213,20 @@ def forward(
             # cross K/V are layer-specific projections of the shared enc_out
             ck = (enc_out @ lp["xattn"]["wk"]).reshape(B, -1, cfg.num_kv_heads, cfg.head_dim)
             cv = (enc_out @ lp["xattn"]["wv"]).reshape(B, -1, cfg.num_kv_heads, cfg.head_dim)
-            h, _, a = _block(
+            h, _, st = _block(
                 h, lp, cfg, ctx, positions=positions,
                 cross=(ck, cv), cross_len=cross_len, attn_chunk=attn_chunk,
                 flash_remat=remat,
             )
         else:
-            h, _, a = _block(h, lp, cfg, ctx, positions=positions,
-                             attn_chunk=attn_chunk, flash_remat=remat)
-        return (h, aux + a), None
+            h, _, st = _block(h, lp, cfg, ctx, positions=positions,
+                              attn_chunk=attn_chunk, flash_remat=remat)
+        return (h, aux + st.aux), None
 
     if remat:
         body = jax.checkpoint(body, prevent_cse=False)
     (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), params["layers"])
-    x = L.apply_norm(x, params["final_norm"], cfg.norm)
+    x = L.apply_norm(x, params["final_norm"], cfg)
     return x, aux
 
 
@@ -207,7 +243,7 @@ def encode(cfg, params, frames: jax.Array, ctx: MeshContext = NO_MESH, *, attn_c
         return h, None
 
     x, _ = jax.lax.scan(body, x, enc["layers"])
-    return L.apply_norm(x, enc["final_norm"], cfg.norm)
+    return L.apply_norm(x, enc["final_norm"], cfg)
 
 
 def lm_head(cfg, params: Params, h: jax.Array) -> jax.Array:
@@ -227,6 +263,15 @@ def make_cache(cfg, batch: int, max_len: int, *, spec_only: bool = False,
     ``kv_dtype=jnp.int8`` halves the cache stream/footprint (layers.kv_quant).
     """
     max_len = -(-max_len // attn_chunk) * attn_chunk
+    if cfg.kv_lora_rank:
+        if kv_dtype != jnp.bfloat16:
+            raise ValueError("kv_dtype int8 is not supported for latent attention (MLA): "
+                             "its pool has no quantized layout; serve it with kv_dtype='bf16'")
+        lead = (cfg.num_layers, batch, max_len)
+        make = jax.ShapeDtypeStruct if spec_only else jnp.zeros
+        return {"ckv": make(lead + (cfg.kv_lora_rank,), jnp.bfloat16),
+                "kpe": make(lead + (cfg.qk_rope_head_dim,), jnp.bfloat16),
+                "length": make((batch,), jnp.int32)}
     fn = kv_cache_spec if spec_only else init_kv_cache
     cache = fn(cfg.num_layers, batch, max_len, cfg.num_kv_heads, cfg.head_dim,
                dtype=kv_dtype)
@@ -262,12 +307,16 @@ def decode_forward(
     uniform: bool = False,  # all rows share one insert position (padded static batch)
     slots: Optional[jax.Array] = None,  # (B,) cache is a PagedKVCache pool;
     # batch row b runs against pool row slots[b] (continuous batching)
-) -> Tuple[jax.Array, Dict[str, jax.Array], jax.Array]:
+    valid: Optional[jax.Array] = None,  # (B, S_new) real tokens: MoE routes
+    # only these (None: all)
+) -> Tuple[jax.Array, Dict[str, jax.Array], L.MoEStats]:
     """Run S_new tokens against the cache starting at ``cache['length']``.
 
-    Returns (hidden (B, S_new, d), cache', aux).  ``cache'`` has the new K/V
+    Returns (hidden (B, S_new, d), cache', stats).  ``cache'`` has the new K/V
     written but ``length`` unchanged — callers commit via kvcache.rollback
-    (for SLED: after the acceptance count is known).
+    (for SLED: after the acceptance count is known).  ``stats`` sums the MoE
+    layers' MoEStats: the aux loss, and the rows each held expert computed
+    (None for a stack without MoE layers).
 
     With ``slots``, cache leaves keep their pool shape (L, n_pool, S, H, D)
     end to end: per-row lengths come from ``length[slots]``, the K+1 fresh
@@ -275,6 +324,10 @@ def decode_forward(
     streams slot-indexed chunks out of the stacked pool — no dense gathered
     sub-cache and no per-layer write-back ever exist.  This is the XLA
     mirror of the Pallas ``verify_attention_paged`` kernel's addressing.
+    Latent attention's ``ckv``/``kpe`` leaves always take this path (row b
+    for batch row b without ``slots``).  ``cfg.first_k_dense`` leading dense
+    layers run unrolled ahead of the loop: a loop of their own would hand
+    the cache to the main loop through a second cache-sized buffer.
     """
     x = L.embed_lookup(params["embed"], tokens, ctx) if embeds is None else embeds.astype(jnp.bfloat16)
     B, S, _ = x.shape
@@ -296,63 +349,57 @@ def decode_forward(
     def idx(a, l):
         return jax.lax.dynamic_index_in_dim(a, l, 0, keepdims=False)
 
-    quant = "k_scale" in cache  # int8 cache: thread the scale leaves too
+    if cfg.kv_lora_rank:
+        names = ("ckv", "kpe")
+    elif "k_scale" in cache:  # int8 cache: thread the scale leaves too
+        names = ("k", "v", "k_scale", "v_scale")
+    else:
+        names = ("k", "v")
+    resident = slots is not None or bool(cfg.kv_lora_rank)
 
-    def body(l, carry):
+    def body(l, carry, layers=params["layers"], offset=0):
         # slice the layer's cache out, append + attend, write back in place.
         # (Streaming chunks straight from the stacked buffer inside the
         # flash scan re-materialises the stack as a while-loop operand on
         # some backends — the per-layer slice is the portable fast path;
         # the "split" cache layout below removes even this copy.)
-        if quant:
-            h, k_all, v_all, ksc, vsc, aux = carry
-        else:
-            h, k_all, v_all, aux = carry
-            ksc = vsc = None
-        lp = jax.tree.map(lambda a: idx(a, l), params["layers"])
+        h, kv, aux = carry[:3]
+        lp = jax.tree.map(lambda a: idx(a, l), layers)
         cross = (idx(cache["cross_k"], l), idx(cache["cross_v"], l)) if cfg.is_encdec else None
-        if slots is not None:
+        kw = dict(positions=positions, cache_len=cache_len, cross=cross, cross_len=cross_len,
+                  attn_chunk=attn_chunk, valid=valid)
+        if resident:
             # pool-resident path: hand the whole stacked pool to the block
             # (cache_layer addressing); fresh rows scatter into slot rows,
             # attention slot-indexes its chunks, nothing is written back
             # wholesale — the carry is updated only at the fresh rows.
-            h, new_kv, a = _block(
-                h, lp, cfg, ctx, positions=positions, kv=(k_all, v_all),
-                cache_len=cache_len, cache_layer=l, slots=slots,
-                cross=cross, cross_len=cross_len, attn_chunk=attn_chunk,
-                kv_scales=(ksc, vsc) if quant else None,
+            h, kv, st = _block(h, lp, cfg, ctx, kv=kv[:2], kv_scales=kv[2:] or None,
+                               cache_layer=l + offset if offset else l, slots=slots, **kw)
+        else:
+            h, new_kv, st = _block(
+                h, lp, cfg, ctx, kv=(idx(kv[0], l), idx(kv[1], l)),
+                kv_scales=(idx(kv[2], l), idx(kv[3], l)) if len(kv) > 2 else None,
+                uniform_start=uniform_start, **kw,
             )
-            if quant:
-                return (h, new_kv[0], new_kv[1], new_kv[2], new_kv[3], aux + a)
-            return (h, new_kv[0], new_kv[1], aux + a)
-        h, new_kv, a = _block(
-            h, lp, cfg, ctx, positions=positions, kv=(idx(k_all, l), idx(v_all, l)),
-            cache_len=cache_len, uniform_start=uniform_start,
-            cross=cross, cross_len=cross_len, attn_chunk=attn_chunk,
-            kv_scales=(idx(ksc, l), idx(vsc, l)) if quant else None,
-        )
-        k_all = jax.lax.dynamic_update_index_in_dim(k_all, new_kv[0], l, 0)
-        v_all = jax.lax.dynamic_update_index_in_dim(v_all, new_kv[1], l, 0)
-        if quant:
-            ksc = jax.lax.dynamic_update_index_in_dim(ksc, new_kv[2], l, 0)
-            vsc = jax.lax.dynamic_update_index_in_dim(vsc, new_kv[3], l, 0)
-            return (h, k_all, v_all, ksc, vsc, aux + a)
-        return (h, k_all, v_all, aux + a)
+            kv = tuple(jax.lax.dynamic_update_index_in_dim(a, n, l, 0) for a, n in zip(kv, new_kv))
+        out = (h, tuple(kv), aux + st.aux)
+        if len(carry) > 3:  # rows per held expert, summed over MoE layers
+            out += (carry[3] if st.rows is None else carry[3] + st.rows,)
+        return out
 
-    aux0 = jnp.zeros((), jnp.float32)
-    if quant:
-        init = (x, cache["k"], cache["v"], cache["k_scale"], cache["v_scale"], aux0)
-    else:
-        init = (x, cache["k"], cache["v"], aux0)
-    out = jax.lax.fori_loop(0, cfg.num_layers, body, init)
-    if quant:
-        x, k_all, v_all, ksc, vsc, aux = out
-        new_cache = {**cache, "k": k_all, "v": v_all, "k_scale": ksc, "v_scale": vsc}
-    else:
-        x, k_all, v_all, aux = out
-        new_cache = {**cache, "k": k_all, "v": v_all}
-    x = L.apply_norm(x, params["final_norm"], cfg.norm)
-    return x, new_cache, aux
+    carry = (x, tuple(cache[n] for n in names), jnp.zeros((), jnp.float32))
+    if cfg.family == "moe":
+        carry += (jnp.zeros((cfg.held[1],), jnp.int32),)
+    for l in range(cfg.first_k_dense):
+        carry = body(l, carry, params["dense_layers"])
+    loop = body
+    if cfg.first_k_dense:
+        loop = lambda l, c: body(l, c, params["layers"], cfg.first_k_dense)
+    carry = jax.lax.fori_loop(0, cfg.num_layers - cfg.first_k_dense, loop, carry)
+    x, kv, aux = carry[:3]
+    x = L.apply_norm(x, params["final_norm"], cfg)
+    stats = L.MoEStats(aux, carry[3] if len(carry) > 3 else None)
+    return x, {**cache, **dict(zip(names, kv))}, stats
 
 
 def prefill(
