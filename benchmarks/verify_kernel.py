@@ -48,8 +48,8 @@ def run(quick: bool = False, kv_dtype: str = "bf16") -> list:
     ] if not quick else [(4, 5, 8, 1, 1024, 64)]
     for (B, Sq, Hq, Hkv, Skv, D) in shapes:
         q = jax.ShapeDtypeStruct((B, Sq, Hq, D), jnp.bfloat16)
-        k = jax.ShapeDtypeStruct((B, Skv, Hkv, D), kdt)
-        v = jax.ShapeDtypeStruct((B, Skv, Hkv, D), kdt)
+        k = jax.ShapeDtypeStruct((B, Hkv, Skv, D), kdt)  # the model's head-major K/V
+        v = jax.ShapeDtypeStruct((B, Hkv, Skv, D), kdt)
         kv_valid = jax.ShapeDtypeStruct((B,), jnp.int32)
 
         def xla_path(q, k, v, kv_valid):

@@ -19,8 +19,9 @@ from __future__ import annotations
 
 import dataclasses
 import logging
+import re
 import time
-from typing import Any, Dict, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -28,7 +29,7 @@ import numpy as np
 
 from repro import telemetry
 from repro.core import verification
-from repro.models.kvcache import PagedKVCache, gather_slots, supports_paged_attention
+from repro.models.kvcache import PagedKVCache, gather_slots, supports_paged_attention, write_row
 from repro.models.layers import NO_MESH, MeshContext
 
 log = logging.getLogger(__name__)
@@ -182,6 +183,22 @@ class EngineStats:
         )
 
 
+# an HLO instruction line: its name, its result shape(s), its opcode
+_HLO_OP = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*?)\s+([\w\-]+)\(", re.M)
+
+
+def pool_copies(hlo: str, pool: Dict[str, Any]) -> List[str]:
+    """Names of the instructions in a compiled program's HLO
+    (``compiled.as_text()``) that copy a whole pool leaf: ``copy`` and
+    ``copy-start`` whose result has a K/V (or latent) leaf's shape.  Empty
+    when the program updates the pool where it lies."""
+    dims = {"[" + ",".join(map(str, a.shape)) + "]" for a in jax.tree.leaves(pool) if a.ndim >= 3}
+    return [
+        name for name, result, op in _HLO_OP.findall(hlo)
+        if op in ("copy", "copy-start") and any(d in result for d in dims)
+    ]
+
+
 def _pad_to(a: np.ndarray, n: int, fill=0) -> np.ndarray:
     if a.shape[0] == n:
         return a
@@ -197,6 +214,9 @@ class VerifySteps:
     model — jax.jit caches on the wrapped closure, so replicas sharing a
     bundle share compiled executables (same shapes, same functions) instead
     of each paying the full warmup.
+
+    ``verify`` and ``extend`` take the pool donated (argument 1): the new
+    pool aliases the old one, which the caller must not read again.
     """
 
     def __init__(
@@ -238,7 +258,8 @@ class VerifySteps:
                 attn_chunk=attn_chunk,
                 paged_attention=self.paged_attention,
                 expert_rows=self.expert_rows,
-            )
+            ),
+            donate_argnums=(1,),
         )
         self.prefill = jax.jit(
             verification.make_prefill_step(model, ctx=ctx, attn_chunk=attn_chunk)
@@ -249,7 +270,8 @@ class VerifySteps:
                 ctx=ctx,
                 attn_chunk=attn_chunk,
                 paged_attention=self.paged_attention,
-            )
+            ),
+            donate_argnums=(1,),
         )
 
 
@@ -412,7 +434,8 @@ class EngineCore:
         lazily on first dispatch).  Returns ``{bucket: compile_seconds}``
         for this call — also accumulated in ``self.compile_log`` and logged
         at INFO so startup budgets are observable (ROADMAP "bucket
-        compilation budget")."""
+        compilation budget").  With telemetry on it also records
+        ``engine_pool_copies`` (:meth:`_record_pool_copies`)."""
         if buckets is None:
             selected = list(self.buckets)
         else:
@@ -425,20 +448,49 @@ class EngineCore:
         times: Dict[int, float] = {}
         for b in selected:
             t0 = time.perf_counter()
-            vb = verification.make_verify_batch(
-                jnp.zeros((b,), jnp.int32),
-                jnp.zeros((b, self.k_max), jnp.int32),
-                jnp.zeros((b,), jnp.int32),
-                draft_q=None if self.greedy else jnp.zeros((b, self.k_max), jnp.float32),
-                seed=np.uint32(0),
-            )
             slots = jnp.full((b,), self.pool.scratch_slot, jnp.int32)
-            _, self.pool.cache = self.steps.verify(self.params, self.pool.cache, slots, vb)
+            _, self.pool.cache = self.steps.verify(
+                self.params, self.pool.cache, slots, self._scratch_batch(b)
+            )
             jax.block_until_ready(self.pool.cache["length"])
             times[b] = time.perf_counter() - t0
             log.info("warmup: bucket %d verify step ready in %.2fs", b, times[b])
         self.compile_log.update(times)
+        if telemetry.enabled():
+            self._record_pool_copies(selected)
         return times
+
+    def _scratch_batch(self, b: int) -> Dict[str, jax.Array]:
+        return verification.make_verify_batch(
+            jnp.zeros((b,), jnp.int32),
+            jnp.zeros((b, self.k_max), jnp.int32),
+            jnp.zeros((b,), jnp.int32),
+            draft_q=None if self.greedy else jnp.zeros((b, self.k_max), jnp.float32),
+            seed=np.uint32(0),
+        )
+
+    def _record_pool_copies(self, buckets: Sequence[int]) -> None:
+        """Gauge ``engine_pool_copies{program,bucket}``: whole-pool copies
+        in the compiled verify (per bucket), extend and admission-write
+        programs, which should update the pool in place (0).  Each program
+        is compiled once more to read its HLO, so only with telemetry on."""
+        pool = self.pool.cache
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+        programs = [
+            ("verify", b, self.steps.verify.lower(self.params, pool, i32(b), self._scratch_batch(b)))
+            for b in buckets
+        ]
+        programs.append(("extend", 1, self.steps.extend.lower(
+            self.params, pool, i32(1), i32(1, self.k_max + 1), i32(1))))
+        programs.append(("write", 1, write_row.lower(
+            pool, i32(), jax.eval_shape(self.pool.make_row_cache))))
+        reg = telemetry.registry()
+        for program, bucket, lowered in programs:
+            n = len(pool_copies(lowered.compile().as_text(), pool))
+            reg.gauge("engine_pool_copies", labels={"program": program, "bucket": bucket}).set(n)
+            if n:
+                log.warning("%s program (bucket %d) copies a whole pool leaf %d times: "
+                            "the pool is not updated in place", program, bucket, n)
 
     def verify(
         self,

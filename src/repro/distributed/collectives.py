@@ -37,9 +37,9 @@ COMBINE_DTYPE = None  # None -> fp32
 
 def sp_append_attend(
     q: jax.Array,       # (B, Sq, Hq, D) — replicated over model axis
-    k_cache: jax.Array,  # (B, S, Hkv, D) — S sharded over model axis
+    k_cache: jax.Array,  # (B, Hkv, S, D) — S sharded over model axis
     v_cache: jax.Array,
-    k_new: jax.Array,   # (B, Sq, Hkv, D) fresh rows (replicated)
+    k_new: jax.Array,   # (B, Hkv, Sq, D) fresh rows (replicated)
     v_new: jax.Array,
     cache_len: jax.Array,   # (B,) committed lengths
     start: jax.Array,       # scalar: uniform insert position (padded batch)
@@ -52,7 +52,7 @@ def sp_append_attend(
     ax = ctx.model_axis
     tp = ctx.tp
     B, Sq, Hq, D = q.shape
-    S = k_cache.shape[1]
+    S = k_cache.shape[2]
     S_loc = S // tp
     bspec = ctx.batch_axes if ctx.batch_axes else None
     chunk = min(chunk, S_loc)
@@ -66,8 +66,8 @@ def sp_append_attend(
         pos = st + jnp.arange(Sq, dtype=jnp.int32) - base  # local positions
         pos = jnp.where((pos >= 0) & (pos < S_loc), pos, S_loc)
         from repro.models.layers import kv_quant
-        kc = kc.at[:, pos].set(kv_quant(kn, kc.dtype), mode="drop")
-        vc = vc.at[:, pos].set(kv_quant(vn, vc.dtype), mode="drop")
+        kc = kc.at[:, :, pos].set(kv_quant(kn, kc.dtype), mode="drop")
+        vc = vc.at[:, :, pos].set(kv_quant(vn, vc.dtype), mode="drop")
         # local flash with global position masking
         q_pos = clen[:, None] + jnp.arange(Sq, dtype=jnp.int32)[None]
         kv_valid = clen + Sq
@@ -91,8 +91,8 @@ def sp_append_attend(
         mesh=ctx.mesh,
         in_specs=(
             P(bspec, None, None, None),      # q
-            P(bspec, ax, None, None),        # k_cache (S sharded)
-            P(bspec, ax, None, None),        # v_cache
+            P(bspec, None, ax, None),        # k_cache (S sharded)
+            P(bspec, None, ax, None),        # v_cache
             P(bspec, None, None, None),      # k_new
             P(bspec, None, None, None),      # v_new
             P(bspec),                        # cache_len
@@ -100,8 +100,8 @@ def sp_append_attend(
         ),
         out_specs=(
             P(bspec, None, None, None),
-            P(bspec, ax, None, None),
-            P(bspec, ax, None, None),
+            P(bspec, None, ax, None),
+            P(bspec, None, ax, None),
         ),
         check_vma=False,
     )(q, k_cache, v_cache, k_new, v_new, cache_len, start)

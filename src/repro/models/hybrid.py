@@ -68,7 +68,7 @@ def make_cache(cfg, batch: int, max_len: int, *, spec_only: bool = False,
     max_len = -(-max_len // attn_chunk) * attn_chunk
     ssm = M2.make_cache(cfg, batch, spec_only=spec_only)
     na = n_apps(cfg)
-    kv_shape = (na, batch, max_len, cfg.num_kv_heads, cfg.head_dim)
+    kv_shape = (na, batch, cfg.num_kv_heads, max_len, cfg.head_dim)
     if spec_only:
         kv = {
             "k": jax.ShapeDtypeStruct(kv_shape, jnp.bfloat16),

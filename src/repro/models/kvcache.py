@@ -11,6 +11,7 @@ per-position state checkpoints during verification instead (see mamba2.py).
 from __future__ import annotations
 
 import contextlib
+import functools
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -25,18 +26,23 @@ def init_kv_cache(
     head_dim: int,
     dtype=jnp.bfloat16,
 ) -> Dict[str, jax.Array]:
+    """K/V leaves head-major, (L, B, Hkv, S, D): one head's positions of a
+    row are contiguous, the order the verify step's layer loop reads and
+    writes, so the step updates the pool where it lies (models/layers.py)."""
+    shape = (num_layers, batch, num_kv_heads, max_len, head_dim)
     return {
-        "k": jnp.zeros((num_layers, batch, max_len, num_kv_heads, head_dim), dtype),
-        "v": jnp.zeros((num_layers, batch, max_len, num_kv_heads, head_dim), dtype),
+        "k": jnp.zeros(shape, dtype),
+        "v": jnp.zeros(shape, dtype),
         "length": jnp.zeros((batch,), jnp.int32),
     }
 
 
 def kv_cache_spec(num_layers, batch, max_len, num_kv_heads, head_dim, dtype=jnp.bfloat16):
     """ShapeDtypeStruct stand-in (dry-run: no allocation)."""
+    shape = (num_layers, batch, num_kv_heads, max_len, head_dim)
     return {
-        "k": jax.ShapeDtypeStruct((num_layers, batch, max_len, num_kv_heads, head_dim), dtype),
-        "v": jax.ShapeDtypeStruct((num_layers, batch, max_len, num_kv_heads, head_dim), dtype),
+        "k": jax.ShapeDtypeStruct(shape, dtype),
+        "v": jax.ShapeDtypeStruct(shape, dtype),
         "length": jax.ShapeDtypeStruct((batch,), jnp.int32),
     }
 
@@ -51,8 +57,8 @@ def rollback(cache: Dict[str, jax.Array], new_length: jax.Array) -> Dict[str, ja
 # ---------------------------------------------------------------------------
 #
 # Every cache family in this repo shares one layout convention: ``length`` is
-# (B,) and every other leaf carries the batch on axis 1 (k/v: (L, B, S, H, D),
-# ssm: (L, B, H, P, N), conv: (L, B, cw-1, C), cross_k/v: (L, B, F, H, D),
+# (B,) and every other leaf carries the batch on axis 1 (k/v: (L, B, H, S, D),
+# ssm: (L, B, H, P, N), conv: (L, B, cw-1, C), cross_k/v: (L, B, H, F, D),
 # int8 dequant scales k_scale/v_scale: (L, B, Hkv)).  Because the convention
 # covers the scale leaves too, a quantized pool needs no special casing here:
 # gather_slots / scatter_slots / write_slot / ExportStream move the int8 rows
@@ -93,6 +99,22 @@ def _batch_axis(leaf: jax.Array) -> int:
 def gather_slots(cache: Dict[str, jax.Array], slots: jax.Array) -> Dict[str, jax.Array]:
     """Dense sub-cache holding pool rows ``slots`` (jit-traceable)."""
     return jax.tree.map(lambda a: jnp.take(a, slots, axis=_batch_axis(a)), cache)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def write_row(
+    pool: Dict[str, jax.Array], slot: jax.Array, row: Dict[str, jax.Array]
+) -> Dict[str, jax.Array]:
+    """``pool`` with the batch-1 cache ``row`` in pool row ``slot``: one
+    dynamic_update_slice per leaf.  The pool is donated, so the program
+    writes the one row where the pool lies and copies nothing else."""
+
+    def put(p, r):
+        start = [0] * p.ndim
+        start[_batch_axis(p)] = slot
+        return jax.lax.dynamic_update_slice(p, r, start)
+
+    return jax.tree.map(put, pool, row)
 
 
 def scatter_slots(
@@ -204,8 +226,9 @@ class PagedKVCache:
         return self.model.make_cache(1, self.max_len, **self.cache_kw)
 
     def write_slot(self, slot: int, row_cache: Dict[str, jax.Array]) -> None:
-        """Install a prefilled batch-1 cache into pool row ``slot``."""
-        self.cache = scatter_slots(self.cache, jnp.asarray([slot], jnp.int32), row_cache)
+        """Install a prefilled batch-1 cache into pool row ``slot`` (in
+        place: the old pool buffers are donated to the write)."""
+        self.cache = write_row(self.cache, jnp.int32(slot), row_cache)
 
     def lengths(self) -> jax.Array:
         return self.cache["length"][: self.n_slots]

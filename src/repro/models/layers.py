@@ -51,8 +51,8 @@ KV_SCALE_EPS = 1e-6  # floor for amax/127 so all-zero rows stay invertible
 
 
 def _bc(scale: jax.Array) -> jax.Array:
-    """(B, Hkv) scale broadcast against a (B, S, Hkv, D) K/V tile."""
-    return scale[:, None, :, None]
+    """(B, Hkv) scale broadcast against a (B, Hkv, S, D) K/V tile."""
+    return scale[:, :, None, None]
 
 
 def kv_quant(x: jax.Array, dtype, scale: Optional[jax.Array] = None) -> jax.Array:
@@ -70,8 +70,8 @@ def kv_dequant(x: jax.Array, scale: Optional[jax.Array] = None) -> jax.Array:
 
 
 def kv_fresh_scale(x: jax.Array) -> jax.Array:
-    """Per-(row, kv-head) symmetric scale for a fresh (B, S, Hkv, D) tile."""
-    amax = jnp.abs(x.astype(jnp.float32)).max(axis=(1, 3))
+    """Per-(row, kv-head) symmetric scale for a fresh (B, Hkv, S, D) tile."""
+    amax = jnp.abs(x.astype(jnp.float32)).max(axis=(2, 3))
     return jnp.maximum(amax / 127.0, KV_SCALE_EPS)
 
 
@@ -214,7 +214,7 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 
 def flash_attention(
     q: jax.Array,  # (B, Sq, Hq, D)
-    k: jax.Array,  # (B, Skv, Hkv, D) — or (L, B, Skv, Hkv, D) with ``layer``
+    k: jax.Array,  # (B, Hkv, Skv, D) head-major — or (L, B, Hkv, Skv, D) with ``layer``
     v: jax.Array,
     *,
     q_pos: Optional[jax.Array] = None,  # (B, Sq) absolute positions; None -> arange
@@ -223,10 +223,10 @@ def flash_attention(
     chunk: int = 1024,
     scale: Optional[float] = None,
     layer: Optional[jax.Array] = None,  # stream chunks straight from a
-    # stacked (L, B, S, H, D) cache buffer — avoids materialising a per-layer
+    # stacked (L, B, H, S, D) cache buffer — avoids materialising a per-layer
     # slice copy of the cache inside the layer loop (§Perf memory fix)
     slots: Optional[jax.Array] = None,  # (B,) pool-row indices: k/v are a
-    # PagedKVCache pool ((n_pool, S, H, D), or (L, n_pool, S, H, D) with
+    # PagedKVCache pool ((n_pool, H, S, D), or (L, n_pool, H, S, D) with
     # ``layer``) and batch entry b attends against pool row slots[b].  Each
     # kv chunk is sliced from the pool FIRST and row-indexed second, so only
     # chunk-sized slot-indexed tiles ever materialise — the XLA mirror of
@@ -251,8 +251,7 @@ def flash_attention(
     """
     B, Sq, Hq, D = q.shape
     stacked = layer is not None
-    Skv, Hkv = (k.shape[2], k.shape[3]) if stacked else (k.shape[1], k.shape[2])
-    Bk = k.shape[1] if stacked else k.shape[0]  # pool rows when slots given
+    Bk, Hkv, Skv = k.shape[-4:-1]  # Bk: pool rows when slots given
     G = Hq // Hkv
     if scale is None:
         scale = 1.0 / math.sqrt(D)
@@ -264,9 +263,8 @@ def flash_attention(
         if slots is not None:
             k = jnp.take(k, slots, axis=0)
             v = jnp.take(v, slots, axis=0)
-        seq_ax = 1
-        kv = (k.astype(jnp.float32).mean(axis=seq_ax)
-              + v.astype(jnp.float32).mean(axis=seq_ax))  # one pass over K+V
+        kv = (k.astype(jnp.float32).mean(axis=2)
+              + v.astype(jnp.float32).mean(axis=2))  # one pass over K+V
         kv = jnp.repeat(kv, G, axis=1)  # (B, Hq, D)
         out = (q.astype(jnp.float32) * kv[:, None] * scale).astype(q.dtype)
         if return_stats:
@@ -286,22 +284,22 @@ def flash_attention(
         # rare path: callers size KV buffers to a chunk multiple (make_cache
         # rounds up), so only short fresh K/V (e.g. whisper's 1500-frame
         # encoder) ever pays this copy.
-        padw = ((0, 0),) * (k.ndim - 3) + ((0, pad), (0, 0), (0, 0))
+        padw = ((0, 0),) * (k.ndim - 2) + ((0, pad), (0, 0))
         k = jnp.pad(k, padw)
         v = jnp.pad(v, padw)
 
     if stacked:
         def chunk_at(a, idx):
             sl = jax.lax.dynamic_slice(
-                a, (layer, 0, idx * chunk, 0, 0), (1, Bk, chunk, Hkv, D)
+                a, (layer, 0, 0, idx * chunk, 0), (1, Bk, Hkv, chunk, D)
             )[0]
             # pool layout: slice the chunk first, row-index second — only a
-            # (B, chunk, H, D) slot-indexed tile materialises, never a dense
+            # (B, H, chunk, D) slot-indexed tile materialises, never a dense
             # gathered copy of the cache rows
             return jnp.take(sl, slots, axis=0) if slots is not None else sl
     else:
         def chunk_at(a, idx):
-            sl = jax.lax.dynamic_slice_in_dim(a, idx * chunk, chunk, axis=1)
+            sl = jax.lax.dynamic_slice_in_dim(a, idx * chunk, chunk, axis=2)
             return jnp.take(sl, slots, axis=0) if slots is not None else sl
 
     qg = q.reshape(B, Sq, Hkv, G, D)
@@ -318,7 +316,7 @@ def flash_attention(
         vb = kv_dequant(chunk_at(v, idx), v_scale)
         # scores: (B, Sq, Hkv, G, chunk)
         s = jnp.einsum(
-            "bshgd,bchd->bshgc", qg, kb, preferred_element_type=jnp.float32
+            "bshgd,bhcd->bshgc", qg, kb, preferred_element_type=jnp.float32
         ) * scale
         j = idx * chunk + jnp.arange(chunk, dtype=jnp.int32)  # (chunk,)
         if pos_offset is not None:
@@ -331,8 +329,10 @@ def flash_attention(
         p = jnp.exp(s - m_new[..., None])
         corr = jnp.exp(m - m_new)
         l = l * corr + p.sum(axis=-1)
+        # V first: the dot then contracts the chunk axis of its right
+        # operand, a form the CPU backend runs in bf16 (the TPU takes either)
         acc = acc * corr[..., None] + jnp.einsum(
-            "bshgc,bchd->bshgd", p.astype(vb.dtype), vb,
+            "bhcd,bshgc->bshgd", vb, p.astype(vb.dtype),
             preferred_element_type=jnp.float32,
         )
         return (m_new, l, acc), None
@@ -378,7 +378,7 @@ def attention_block(
     cfg,
     *,
     positions: jax.Array,  # (B, S)
-    kv_cache: Optional[Tuple[jax.Array, jax.Array]] = None,  # (B, Smax, Hkv, D) x2
+    kv_cache: Optional[Tuple[jax.Array, jax.Array]] = None,  # (B, Hkv, Smax, D) x2
     cache_len: Optional[jax.Array] = None,  # (B,)
     cache_layer: Optional[jax.Array] = None,  # kv_cache is the full (L, ...) stack
     uniform_start: Optional[jax.Array] = None,  # scalar: all rows share the
@@ -403,7 +403,10 @@ def attention_block(
     With a kv_cache, new K/V rows are scattered into the buffer at
     ``cache_len + arange(S)`` per row, and attention runs over the whole
     buffer with ``kv_valid = cache_len + S``; returns the updated cache.
-    With ``cache_layer``, the cache is the stacked (L, B, S, H, D) buffer:
+    Cache and cross K/V are head-major, (B, Hkv, S, D), so a row's
+    positions of one head are contiguous: an append is a (Hkv, S, D) window
+    and the step runs its layer loop over the pool as stored.
+    With ``cache_layer``, the cache is the stacked (L, B, H, S, D) buffer:
     only the S new rows are written (tiny scatter) and attention streams
     chunks directly from the stack — the layer loop never copies the cache.
     With ``slots``, the cache batch axis is a PagedKVCache row pool: the
@@ -439,6 +442,7 @@ def attention_block(
     if cfg.use_rope:
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
+    k, v = k.swapaxes(1, 2), v.swapaxes(1, 2)  # (B, Hkv, S, D), the cache's layout
 
     new_cache = None
     if slots is not None and ctx.seq_shard_kv:
@@ -480,19 +484,19 @@ def attention_block(
             row_vs = jnp.where(cache_len[:, None] == 0, kv_fresh_scale(v), _rows(vsc))
         kq, vq = kv_quant(k, ck.dtype, row_ks), kv_quant(v, cv.dtype, row_vs)
         if uniform_start is not None and cache_layer is not None:
-            start = (cache_layer, jnp.int32(0), uniform_start.astype(jnp.int32),
-                     jnp.int32(0), jnp.int32(0))
+            start = (cache_layer, jnp.int32(0), jnp.int32(0),
+                     uniform_start.astype(jnp.int32), jnp.int32(0))
             ck = jax.lax.dynamic_update_slice(ck, kq[None], start)
             cv = jax.lax.dynamic_update_slice(cv, vq[None], start)
         elif uniform_start is not None:
-            start = (jnp.int32(0), uniform_start.astype(jnp.int32), jnp.int32(0),
+            start = (jnp.int32(0), jnp.int32(0), uniform_start.astype(jnp.int32),
                      jnp.int32(0))
             ck = jax.lax.dynamic_update_slice(ck, kq, start)
             cv = jax.lax.dynamic_update_slice(cv, vq, start)
         elif slots is not None:
             # slot pool: batch row b appends its S fresh rows into pool row
             # slots[b].  A static unroll of per-row dynamic_update_slice
-            # (one contiguous (1, S, H, D) window each) is the ONLY pool
+            # (one (1, H, S, D) window each) is the ONLY pool
             # write of the step — a scatter here would be rewritten by XLA's
             # scatter expander into a B*S-trip select loop over the whole
             # pool buffer.  Duplicate scratch-slot rows overwrite in order
@@ -504,22 +508,25 @@ def attention_block(
                 row = slots[b].astype(jnp.int32)
                 pos = cache_len[b].astype(jnp.int32)
                 if cache_layer is not None:
-                    start = (cache_layer, row, pos, jnp.int32(0), jnp.int32(0))
+                    start = (cache_layer, row, jnp.int32(0), pos, jnp.int32(0))
                     ck = jax.lax.dynamic_update_slice(ck, kq[b][None, None], start)
                     cv = jax.lax.dynamic_update_slice(cv, vq[b][None, None], start)
                 else:
-                    start = (row, pos, jnp.int32(0), jnp.int32(0))
+                    start = (row, jnp.int32(0), pos, jnp.int32(0))
                     ck = jax.lax.dynamic_update_slice(ck, kq[b][None], start)
                     cv = jax.lax.dynamic_update_slice(cv, vq[b][None], start)
         else:
+            # one (Hkv, D) window per (row, position): the index arrays sit
+            # around the head axis, so the update is indexed (B, S, Hkv, D)
             b_idx = jnp.arange(B, dtype=jnp.int32)[:, None]  # (B,1)
             s_idx = cache_len[:, None] + jnp.arange(S, dtype=jnp.int32)[None]  # (B,S)
+            kq, vq = kq.swapaxes(1, 2), vq.swapaxes(1, 2)
             if cache_layer is not None:
-                ck = ck.at[cache_layer, b_idx, s_idx].set(kq, mode="drop")
-                cv = cv.at[cache_layer, b_idx, s_idx].set(vq, mode="drop")
+                ck = ck.at[cache_layer, b_idx, :, s_idx].set(kq, mode="drop")
+                cv = cv.at[cache_layer, b_idx, :, s_idx].set(vq, mode="drop")
             else:
-                ck = ck.at[b_idx, s_idx].set(kq, mode="drop")
-                cv = cv.at[b_idx, s_idx].set(vq, mode="drop")
+                ck = ck.at[b_idx, :, s_idx].set(kq, mode="drop")
+                cv = cv.at[b_idx, :, s_idx].set(vq, mode="drop")
         if row_ks is not None:
             # persist the selected scales along the same addressing as the
             # K/V append (a no-op rewrite for rows whose scale was already

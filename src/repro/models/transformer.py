@@ -211,8 +211,7 @@ def forward(
         h, aux = carry
         if cfg.is_encdec:
             # cross K/V are layer-specific projections of the shared enc_out
-            ck = (enc_out @ lp["xattn"]["wk"]).reshape(B, -1, cfg.num_kv_heads, cfg.head_dim)
-            cv = (enc_out @ lp["xattn"]["wv"]).reshape(B, -1, cfg.num_kv_heads, cfg.head_dim)
+            ck, cv = _cross_kv(lp, enc_out, cfg)
             h, _, st = _block(
                 h, lp, cfg, ctx, positions=positions,
                 cross=(ck, cv), cross_len=cross_len, attn_chunk=attn_chunk,
@@ -285,7 +284,7 @@ def make_cache(cfg, batch: int, max_len: int, *, spec_only: bool = False,
         cache["k_scale"] = mk()
         cache["v_scale"] = mk()
     if cfg.is_encdec:
-        shp = (cfg.num_layers, batch, enc_len or cfg.encoder_seq, cfg.num_kv_heads, cfg.head_dim)
+        shp = (cfg.num_layers, batch, cfg.num_kv_heads, enc_len or cfg.encoder_seq, cfg.head_dim)
         if spec_only:
             cache["cross_k"] = jax.ShapeDtypeStruct(shp, jnp.bfloat16)
             cache["cross_v"] = jax.ShapeDtypeStruct(shp, jnp.bfloat16)
@@ -318,7 +317,7 @@ def decode_forward(
     layers' MoEStats: the aux loss, and the rows each held expert computed
     (None for a stack without MoE layers).
 
-    With ``slots``, cache leaves keep their pool shape (L, n_pool, S, H, D)
+    With ``slots``, cache leaves keep their pool shape (L, n_pool, H, S, D)
     end to end: per-row lengths come from ``length[slots]``, the K+1 fresh
     K/V rows are scattered straight into pool rows ``slots``, and attention
     streams slot-indexed chunks out of the stacked pool — no dense gathered
@@ -337,7 +336,7 @@ def decode_forward(
         x = x + params["pos_embed"][positions]
     cross_len = None
     if cfg.is_encdec:
-        cross_len = jnp.full((B,), cache["cross_k"].shape[2], jnp.int32)
+        cross_len = jnp.full((B,), cache["cross_k"].shape[3], jnp.int32)
     uniform_start = cache_len[0] if uniform else None
 
     # fori_loop carrying the FULL cache buffers, updated in place: a scan
@@ -415,10 +414,9 @@ def prefill(
     uniform: bool = False,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Fill the cache from a prompt; returns (last-position logits, cache)."""
-    B = tokens.shape[0]
     if cfg.is_encdec:
         enc_out = encode(cfg, params, enc_frames, ctx, attn_chunk=attn_chunk)
-        cks, cvs = _map_layers_xkv(params["layers"], enc_out, cfg, B)
+        cks, cvs = jax.lax.map(lambda lp: _cross_kv(lp, enc_out, cfg), params["layers"])
         cache = {**cache, "cross_k": cks, "cross_v": cvs}
     embeds = None
     if embeds_prefix is not None:
@@ -432,10 +430,9 @@ def prefill(
     return logits[:, 0], cache
 
 
-def _map_layers_xkv(layers, enc_out, cfg, B):
-    def one(lp):
-        ck = (enc_out @ lp["xattn"]["wk"]).reshape(B, -1, cfg.num_kv_heads, cfg.head_dim)
-        cv = (enc_out @ lp["xattn"]["wv"]).reshape(B, -1, cfg.num_kv_heads, cfg.head_dim)
-        return ck, cv
-
-    return jax.lax.map(one, layers)
+def _cross_kv(lp, enc_out, cfg):
+    """A layer's cross-attention K/V of the encoder output, head-major
+    (B, Hkv, F, D) like every cache leaf."""
+    B = enc_out.shape[0]
+    return tuple((enc_out @ lp["xattn"][w]).reshape(B, -1, cfg.num_kv_heads, cfg.head_dim)
+                 .swapaxes(1, 2) for w in ("wk", "wv"))

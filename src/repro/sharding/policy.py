@@ -201,14 +201,14 @@ class Policy:
         cfg = self.cfg
         if key == "length":
             return P(self._bspec(shape[0]))
-        if key in ("cross_k", "cross_v"):  # (L, B, F, Hkv, hd) — encoder side
+        if key in ("cross_k", "cross_v"):  # (L, B, Hkv, F, hd) — encoder side
             h = "model" if self.shard_attn_kv() else None
-            return P(None, self._bspec(shape[1]), None, h, None)
-        if key in ("k", "v"):  # (L|n, B, S, Hkv, hd)
-            if self.seq_shard_kv() and shape[2] % self.tp == 0:
-                return P(None, self._bspec(shape[1]), "model", None, None)
+            return P(None, self._bspec(shape[1]), h, None, None)
+        if key in ("k", "v"):  # (L|n, B, Hkv, S, hd)
+            if self.seq_shard_kv() and shape[3] % self.tp == 0:
+                return P(None, self._bspec(shape[1]), None, "model", None)
             h = "model" if self.shard_attn_kv() else None
-            return P(None, self._bspec(shape[1]), None, h, None)
+            return P(None, self._bspec(shape[1]), h, None, None)
         if key.startswith("ssm"):  # (L, B, H, P, N)
             h = "model" if cfg.ssm_heads % self.tp == 0 else None
             return P(None, self._bspec(shape[1]), h, None, None)

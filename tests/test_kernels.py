@@ -47,7 +47,8 @@ def test_verify_attention_matches_model_flash():
     v = jax.random.normal(ks[2], (B, Skv, Hkv, D))
     kv_valid = jnp.array([40, 90], jnp.int32)
     q_pos = kv_valid[:, None] - Sq + jnp.arange(Sq)[None]
-    a = flash_attention(q, k, v, q_pos=q_pos, kv_valid=kv_valid, chunk=32)
+    a = flash_attention(q, k.swapaxes(1, 2), v.swapaxes(1, 2), q_pos=q_pos,
+                        kv_valid=kv_valid, chunk=32)  # the model's K/V are head-major
     b = ops.verify_attention(q, k, v, kv_valid, block_k=32, interpret=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-3)
 

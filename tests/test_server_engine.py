@@ -13,8 +13,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import telemetry
 from repro.configs.base import get_config
 from repro.core import verification
+from repro.core.engine import EngineCore, VerifySteps
 from repro.core.engine_loop import sled_generate
 from repro.core.server_engine import EdgeDeviceKit, ServerEngine
 from repro.models.kvcache import (
@@ -79,7 +81,7 @@ def test_gather_scatter_roundtrip():
     pool["length"] = jnp.arange(5, dtype=jnp.int32)
     slots = jnp.asarray([3, 0], jnp.int32)
     sub = gather_slots(pool, slots)
-    assert sub["k"].shape == (2, 2, 8, 2, 4)
+    assert sub["k"].shape == (2, 2, 2, 8, 4)  # (L, rows, Hkv, S, D)
     np.testing.assert_array_equal(np.asarray(sub["length"]), [3, 0])
     np.testing.assert_array_equal(np.asarray(sub["k"][:, 0]), np.asarray(pool["k"][:, 3]))
 
@@ -174,7 +176,7 @@ def test_slot_indexed_step_matches_gather_step():
     for row in (1, 2):
         n = int(pool_p["length"][row])
         np.testing.assert_array_equal(
-            np.asarray(pool_p["k"][:, row, : n + 1]), np.asarray(pool_g["k"][:, row, : n + 1])
+            np.asarray(pool_p["k"][:, row, :, : n + 1]), np.asarray(pool_g["k"][:, row, :, : n + 1])
         )
     # untouched row 3 stays bit-identical in both
     np.testing.assert_array_equal(np.asarray(pool_p["k"][:, 3]), np.asarray(pool["k"][:, 3]))
@@ -323,3 +325,110 @@ def test_engine_partial_batches_match_lockstep_reference():
     )
     eng = np.array([outputs[i] for i in range(B)])
     np.testing.assert_array_equal(eng, np.asarray(ref))
+
+
+# ---------------------------------------------------------------------------
+# The pool is donated to every program that writes it
+# ---------------------------------------------------------------------------
+
+
+def _core(tm, tp, kv_dtype, n_slots=3, steps=None):
+    return EngineCore(tm, tp, n_slots=n_slots, max_len=64, k_max=4, attn_chunk=32,
+                      kv_dtype=kv_dtype, steps=steps)
+
+
+def _admit(core, slot, seed):
+    prompt = jax.random.randint(jax.random.key(seed), (7 + seed,), 0, V)
+    return core.prefill_slot(slot, prompt)
+
+
+def _round(core, slots, prevs, seed):
+    """One verify call over ``slots`` with seeded drafts; returns the
+    verdict arrays on the host."""
+    rng = np.random.default_rng(seed)
+    slots = np.asarray(slots, np.int32)
+    toks = rng.integers(0, V, (slots.size, core.k_max)).astype(np.int32)
+    lens = rng.integers(1, core.k_max + 1, slots.size).astype(np.int32)
+    res, _, _ = core.verify(slots, np.asarray(prevs, np.int32), toks, None, lens)
+    return {k: np.asarray(getattr(res, k)) for k in ("out_tokens", "n_accepted", "n_commit", "extra_token")}
+
+
+def _host(tree):
+    return {k: np.asarray(v) for k, v in tree.items()}
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_donated_pool_gives_identical_verdicts(kv_dtype):
+    """The engine hands its pool to the verify and extend programs donated:
+    each call leaves the old pool's buffers deleted (the output aliases
+    them), and over several calls the verdicts and the pool are bit-identical
+    to the same programs compiled without donation."""
+    _, _, tm, tp = _models()
+    core = _core(tm, tp, kv_dtype)
+    plain = VerifySteps(tm, scratch_slot=3, attn_chunk=32, kv_dtype=kv_dtype)
+    plain.verify = jax.jit(verification.make_paged_verify_step(tm, scratch_slot=3, attn_chunk=32))
+    plain.extend = jax.jit(verification.make_force_extend_step(tm, attn_chunk=32))
+    ref = _core(tm, tp, kv_dtype, steps=plain)
+    prevs = [[_admit(c, s, s) for s in (2, 0)] for c in (core, ref)]
+    assert prevs[0] == prevs[1]
+    for call in range(4):
+        before = jax.tree.leaves(core.pool.cache)
+        got, want = _round(core, [2, 0], prevs[0], call), _round(ref, [2, 0], prevs[1], call)
+        assert all(a.is_deleted() for a in before)
+        for k in want:
+            np.testing.assert_array_equal(got[k], want[k])
+        prevs = [[int(t) for t in got["extra_token"]]] * 2
+    core.force_extend(0, np.asarray([5, 6], np.int32))
+    ref.force_extend(0, np.asarray([5, 6], np.int32))
+    got, want = _host(core.pool.cache), _host(ref.pool.cache)
+    for k in want:
+        np.testing.assert_array_equal(got[k], want[k])
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_admit_verify_export_import_round_trip(kv_dtype):
+    """Admission writes the prefilled row in place; after verify calls the
+    row exports, imports into another engine's pool and exports again
+    bit-identically (int8 rows with their scales), leaves that pool's other
+    rows alone, and the next verify call agrees on both engines."""
+    _, _, tm, tp = _models()
+    a, b = _core(tm, tp, kv_dtype), _core(tm, tp, kv_dtype, n_slots=4)
+    prev = _admit(a, 1, 3)
+    other = _admit(b, 0, 5)
+    for call in range(2):
+        prev = int(_round(a, [1], [prev], call)["extra_token"][0])
+    row = _host(a.export_row(1))
+    b_row0 = _host(b.export_row(0))
+    b.import_row(2, a.export_row(1))
+    for k, v in _host(b.export_row(2)).items():
+        np.testing.assert_array_equal(v, row[k])
+    for k, v in _host(b.export_row(0)).items():
+        np.testing.assert_array_equal(v, b_row0[k])
+    got, want = _round(b, [2, 0], [prev, other], 9), _round(a, [1], [prev], 9)
+    for k in want:
+        np.testing.assert_array_equal(got[k][:1], want[k])
+
+
+def test_warmup_counts_pool_copies_with_telemetry(caplog):
+    """With telemetry on, warm-up records engine_pool_copies for each verify
+    bucket, the extend program and the admission write, and warns for each
+    program that copies the pool.  (Whether a program copies depends on the
+    compiler: tests/test_chip_compile.py holds the TPU's programs to none.)"""
+    _, _, tm, tp = _models()
+    was = telemetry.enabled()
+    telemetry.enable(True)
+    try:
+        telemetry.registry().reset()
+        core = _core(tm, tp, "bf16")
+        with caplog.at_level("WARNING", logger="repro.core.engine"):
+            core.warmup(buckets=[1, 2])
+        gauges = telemetry.registry().snapshot()["gauges"]
+    finally:
+        telemetry.enable(was)
+    want = [("verify", 1), ("verify", 2), ("extend", 1), ("write", 1)]
+    copies = {(p, b): gauges.pop(f'engine_pool_copies{{bucket="{b}",program="{p}"}}') for p, b in want}
+    assert not [k for k in gauges if k.startswith("engine_pool_copies")]
+    assert all(n >= 0 and n == int(n) for n in copies.values())
+    assert copies["write", 1] == 0  # one row's dynamic_update_slice into the donated pool
+    warned = {(r.args[0], r.args[1]) for r in caplog.records if "pool" in r.getMessage()}
+    assert warned == {k for k, n in copies.items() if n}

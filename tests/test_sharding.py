@@ -108,17 +108,17 @@ def test_sp_attention_numerics_under_mesh():
         B, Sq, Hq, Hkv, S, D = 4, 3, 8, 2, 64, 16
         ks = jax.random.split(jax.random.key(0), 6)
         q = jax.random.normal(ks[0], (B, Sq, Hq, D))
-        kc = jax.random.normal(ks[1], (B, S, Hkv, D))
-        vc = jax.random.normal(ks[2], (B, S, Hkv, D))
-        kn = jax.random.normal(ks[3], (B, Sq, Hkv, D))
-        vn = jax.random.normal(ks[4], (B, Sq, Hkv, D))
+        kc = jax.random.normal(ks[1], (B, Hkv, S, D))  # head-major caches
+        vc = jax.random.normal(ks[2], (B, Hkv, S, D))
+        kn = jax.random.normal(ks[3], (B, Hkv, Sq, D))
+        vn = jax.random.normal(ks[4], (B, Hkv, Sq, D))
         clen = jnp.full((B,), 30, jnp.int32)
         start = jnp.int32(30)
         with jax.set_mesh(mesh):
             out, kc2, vc2 = jax.jit(lambda *a: sp_append_attend(*a, ctx, chunk=16))(
                 q, kc, vc, kn, vn, clen, start)
-        kref = kc.at[:, 30:33].set(kn)
-        vref = vc.at[:, 30:33].set(vn)
+        kref = kc.at[:, :, 30:33].set(kn)
+        vref = vc.at[:, :, 30:33].set(vn)
         q_pos = clen[:, None] + jnp.arange(Sq)[None]
         want = flash_attention(q, kref, vref, q_pos=q_pos, kv_valid=clen + Sq, chunk=16)
         np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=2e-3, atol=2e-3)
