@@ -1,7 +1,7 @@
-"""Benchmark harness: one module per paper table/figure + roofline/kernels.
+"""CPU benchmark harness: one module per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV rows per benchmark.  ``--quick``
-shrinks sim horizons for CI; the full run matches EXPERIMENTS.md.
+shrinks sim horizons for CI.  Chip numbers come from ``sled_bench`` (PERF.md).
 """
 from __future__ import annotations
 
@@ -17,7 +17,7 @@ def main() -> None:
     args = ap.parse_args()
 
     from benchmarks import availability, capacity, confidence, pareto
-    from benchmarks import roofline_bench, speclen, verify_kernel, wstgr
+    from benchmarks import speclen, wstgr
 
     suites = {
         "availability": availability.run,
@@ -26,8 +26,6 @@ def main() -> None:
         "fig4_wstgr": wstgr.run,
         "fig5_speclen": speclen.run,
         "fig6_pareto": pareto.run,
-        "roofline": roofline_bench.run,
-        "verify_kernel": verify_kernel.run,
     }
     failures = []
     for name, fn in suites.items():

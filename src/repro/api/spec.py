@@ -23,6 +23,8 @@ import dataclasses
 import json
 from typing import Optional, Tuple, Union
 
+from repro.transport import codec
+
 BACKENDS = ("reference", "engine", "transport", "cluster")
 LINKS = ("loopback", "sim")
 KCTLS = ("fixed", "adaptive")
@@ -36,10 +38,6 @@ WIDTHS = ("smoke", "published")
 # server-pool KV storage dtype: "int8" stores pool rows quantized with
 # per-(slot, layer, head) dequant scales (core/engine.py KV_DTYPES)
 KV_DTYPES = ("bf16", "int8")
-# v1: no Verdict feedback fields; v2: feedback wire; v3: + the
-# Router<->worker control plane (PlaceReplica / driver RPCs / Drain);
-# v4: + per-RPC sequence ids (replay-safe retries) and Ping/Pong heartbeat
-CODEC_VERSIONS = (1, 2, 3, 4)
 FLAVORS = ("inproc", "remote")
 FAULT_KINDS = ("kill", "hang", "drop", "delay", "flap")
 
@@ -105,9 +103,8 @@ class ModelSpec:
 class TransportSpec:
     """Wire-runtime knobs (``backend="transport"`` only).
 
-    ``codec_version`` declares the frame protocol the deployment speaks;
-    v1 Verdicts carried no accept_rate/queue_depth feedback, so adaptive
-    spec-length control is rejected on a v1 codec at validation time.
+    ``codec_version`` declares the frame protocol the deployment speaks; the
+    runtime speaks one, ``codec.VERSION``, and validation refuses any other.
     """
 
     link: str = "loopback"  # loopback | sim
@@ -117,14 +114,15 @@ class TransportSpec:
     verify_timeout: float = 30.0  # device-side round timeout (s)
     stagger_s: float = 0.0  # client i joins i * stagger_s seconds in
     draft_rate: Optional[float] = None  # emulated device tokens/s (None: unthrottled)
-    codec_version: int = 4
+    codec_version: int = codec.VERSION
 
     def validate(self) -> None:
         _check(self.link in LINKS, f"transport.link {self.link!r} not in {LINKS}")
         _check(self.qmode in QMODES, f"transport.qmode {self.qmode!r} not in {QMODES}")
         _check(
-            self.codec_version in CODEC_VERSIONS,
-            f"transport.codec_version {self.codec_version} not in {CODEC_VERSIONS}",
+            self.codec_version == codec.VERSION,
+            f"transport.codec_version {self.codec_version}: this runtime speaks "
+            f"codec v{codec.VERSION} only",
         )
         _check(self.verify_timeout > 0, "transport.verify_timeout must be > 0")
         _check(self.stagger_s >= 0, "transport.stagger_s must be >= 0")
@@ -578,7 +576,6 @@ class ServeSpec:
     cctl: str = "fixed"  # fixed | adaptive (closed-loop drafting confidence)
     max_len: int = 128
     attn_chunk: int = 32
-    paged_attention: bool = True
     # KV-pool storage dtype: "int8" roughly halves bytes-per-slot (doubling
     # server capacity at a fixed HBM budget) at the cost of quantized cache
     # reads; rejected for ssm/hybrid families at System.build (their
@@ -641,30 +638,15 @@ class ServeSpec:
             f"{self.backend!r} (a worker process is a cluster member)",
         )
         _check(
-            not self.cluster.has_remote or self.transport.codec_version >= 3,
-            "remote replicas need codec_version >= 3 (the Router<->worker "
-            "control plane — PlaceReplica, driver RPCs, stream export — is v3)",
-        )
-        _check(
             self.kctl != "adaptive" or self.backend == "transport",
             "kctl='adaptive' needs backend='transport': the acceptance/"
             "queue-depth feedback rides Verdict frames",
-        )
-        _check(
-            self.kctl != "adaptive" or self.transport.codec_version >= 2,
-            "kctl='adaptive' needs codec_version >= 2 (v1 Verdict frames "
-            "carry no accept_rate/queue_depth feedback)",
         )
         _check(self.cctl in CCTLS, f"cctl {self.cctl!r} not in {CCTLS}")
         _check(
             self.cctl != "adaptive" or self.backend == "transport",
             "cctl='adaptive' needs backend='transport': the acceptance/"
             "queue-depth feedback rides Verdict frames",
-        )
-        _check(
-            self.cctl != "adaptive" or self.transport.codec_version >= 2,
-            "cctl='adaptive' needs codec_version >= 2 (v1 Verdict frames "
-            "carry no accept_rate/queue_depth feedback)",
         )
         # heterogeneous fleets
         _check(

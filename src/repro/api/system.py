@@ -62,7 +62,6 @@ from repro.models.kvcache import supports_paged_attention
 from repro.models.model_zoo import build_model, perturb_params
 from repro.quant.quantize import dequantize_pytree, quantize_pytree
 from repro.serving.devices import NETS
-from repro.transport import codec
 from repro.transport.client import ClientStats, EdgeClient
 from repro.transport.links import make_link
 from repro.transport.server import TransportServer
@@ -333,14 +332,6 @@ class System:
             # telemetry and plain specs keep collecting (benchmarks that need
             # a clean off-state call telemetry.enable(False) explicitly)
             telemetry.enable(True)
-        if spec.backend == "transport" and spec.transport.codec_version != codec.VERSION:
-            # the spec layer can DESCRIBE other protocol versions (artifacts
-            # shipped between heterogeneous hosts), but this runtime only
-            # speaks the current one — refuse rather than silently upgrade
-            raise ValueError(
-                f"this runtime speaks codec v{codec.VERSION} only; the spec "
-                f"declares codec_version={spec.transport.codec_version}"
-            )
         if models is not None and spec.cluster.has_remote:
             log.warning(
                 "a shared ModelBundle cannot ship to remote workers: each "
@@ -360,17 +351,6 @@ class System:
                 "have no quantized layout — serve this family with "
                 "kv_dtype='bf16'"
             )
-        if (
-            spec.backend in _ENGINE_BACKENDS
-            and spec.paged_attention
-            and not supports_paged_attention(models.target_cfg)
-        ):
-            log.warning(
-                "paged attention is unavailable for model family %r (%s): "
-                "verification falls back to gather/scatter cache paging",
-                fam,
-                spec.model.arch,
-            )
         engine: Union[ServerEngine, Router, None] = None
         if spec.backend in _ENGINE_BACKENDS:
             engine_kw = dict(
@@ -382,7 +362,6 @@ class System:
                 straggler_timeout=spec.scheduler.straggler_timeout,
                 greedy=spec.greedy,
                 attn_chunk=spec.attn_chunk,
-                paged_attention=spec.paged_attention,
                 kv_dtype=spec.kv_dtype,
                 steps=steps,
             )

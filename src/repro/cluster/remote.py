@@ -299,7 +299,6 @@ class RemoteReplica:
         self.k_max = 0
         self.max_len = 0
         self.greedy = True
-        self.paged_attention = True
         self._streams: Dict[int, DeviceStream] = {}
         self._pending: Dict[int, int] = {}  # device -> tokens in flight
         self._queue_depth = 0
@@ -334,7 +333,6 @@ class RemoteReplica:
         self.k_max = ack.k_max
         self.max_len = ack.max_len
         self.greedy = ack.greedy
-        self.paged_attention = ack.paged_attention
 
     # -- supervision: retryable RPCs, chaos hooks, revive ---------------------
 
@@ -447,7 +445,7 @@ class RemoteReplica:
         # kv_dtype comes from the placed spec (the worker builds its pool
         # from it), mirroring LocalReplica's engine-derived fingerprint
         kv_dtype = getattr(self.spec, "kv_dtype", "bf16") if self.spec is not None else "bf16"
-        return (self.k_max, self.max_len, self.greedy, self.paged_attention, kv_dtype)
+        return (self.k_max, self.max_len, self.greedy, kv_dtype)
 
     # -- shadowed introspection (no round trips) -----------------------------
 

@@ -113,7 +113,7 @@ class LocalReplica:
         e = self.engine
         # kv_dtype is part of the identity: an int8 row scattered into a
         # bf16 pool (or vice versa) would silently cast and corrupt the cache
-        return (e.k_max, e.pool.max_len, e.greedy, e.paged_attention, e.kv_dtype)
+        return (e.k_max, e.pool.max_len, e.greedy, e.kv_dtype)
 
     def chaos_kill(self) -> None:
         """Fault injection: every delegated call now raises ConnectionError,
@@ -399,10 +399,6 @@ class Router:
     @property
     def k_max(self) -> int:
         return self.replicas[0].k_max
-
-    @property
-    def paged_attention(self) -> bool:
-        return self.replicas[0].paged_attention
 
     @property
     def streams(self) -> Mapping:

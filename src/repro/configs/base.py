@@ -102,7 +102,7 @@ class ModelConfig:
         return self.ssm_expand * self.d_model
 
     def param_count(self) -> int:
-        """Analytic parameter count (used for roofline MODEL_FLOPS = 6*N*D)."""
+        """Analytic parameter count (MoE: the experts this chip holds)."""
         d, L = self.d_model, self.num_layers
         hd = self.head_dim
         n = 0
@@ -151,15 +151,6 @@ class ModelConfig:
         n += di * d  # out_proj
         n += d  # norm
         return n
-
-    def active_param_count(self) -> int:
-        """Active params per token (MoE: only routed experts count)."""
-        if self.family != "moe":
-            return self.param_count()
-        n_moe = self.num_layers - self.first_k_dense
-        dense_share = self.param_count() - n_moe * self.held[1] * 3 * self.d_model * self.moe_d_ff
-        active_moe = n_moe * self.experts_per_token * 3 * self.d_model * self.moe_d_ff
-        return dense_share + active_moe
 
     def reduced(self) -> "ModelConfig":
         """A tiny same-family config for CPU smoke tests."""
@@ -274,16 +265,3 @@ def _load_all() -> None:
     for m in _CONFIG_MODULES:
         importlib.import_module(f"repro.configs.{m}")
 
-
-def all_cells() -> List[Tuple[ModelConfig, ShapeConfig]]:
-    """Every applicable (architecture x shape) pair — the dry-run grid."""
-    _load_all()
-    cells = []
-    for name in list_configs():
-        cfg = _REGISTRY[name]
-        if cfg.notes.startswith("paper-"):
-            continue  # paper draft/target pairs are not assigned grid cells
-        for shape in SHAPES.values():
-            if shape_applicable(cfg, shape):
-                cells.append((cfg, shape))
-    return cells

@@ -1,9 +1,8 @@
 """The paper's own model pairs: LLaMA-style 1B/3B drafts, 11B/70B targets.
 
 These drive the paper-reproduction benchmarks (Table I, Figs 3-6). They are
-llama-3.2/3.1-shaped configs; notes start with "paper-" so they are excluded
-from the assigned 40-cell dry-run grid (they get their own dry-run entries via
---arch on the launcher).
+llama-3.2/3.1-shaped configs; notes start with "paper-" so the sharding-policy
+tests (tests/test_sharding.py) skip them.
 """
 from repro.configs.base import ModelConfig, register
 

@@ -228,7 +228,6 @@ class VerifySteps:
         greedy: bool = True,
         temperature: float = 1.0,
         attn_chunk: int = 32,
-        paged_attention: bool = True,
         kv_dtype: Any = "bf16",
     ):
         self.model = model
@@ -244,10 +243,6 @@ class VerifySteps:
         # pool dtypes behind one bundle would silently compile everything
         # twice, defeating the shared-warmup contract
         self.kv_dtype = kv_dtype_name(kv_dtype)
-        # slot-indexed verify attention straight out of the pool; SSM/hybrid
-        # caches fall back to gather/scatter (their recurrent state leaves
-        # are not position-indexed K/V — see models/kvcache.py)
-        self.paged_attention = bool(paged_attention) and supports_paged_attention(model.cfg)
         self.verify = jax.jit(
             verification.make_paged_verify_step(
                 model,
@@ -256,7 +251,6 @@ class VerifySteps:
                 greedy=greedy,
                 temperature=temperature,
                 attn_chunk=attn_chunk,
-                paged_attention=self.paged_attention,
                 expert_rows=self.expert_rows,
             ),
             donate_argnums=(1,),
@@ -265,12 +259,7 @@ class VerifySteps:
             verification.make_prefill_step(model, ctx=ctx, attn_chunk=attn_chunk)
         )
         self.extend = jax.jit(
-            verification.make_force_extend_step(
-                model,
-                ctx=ctx,
-                attn_chunk=attn_chunk,
-                paged_attention=self.paged_attention,
-            ),
+            verification.make_force_extend_step(model, ctx=ctx, attn_chunk=attn_chunk),
             donate_argnums=(1,),
         )
 
@@ -299,7 +288,6 @@ class EngineCore:
         ctx: MeshContext = NO_MESH,
         buckets: Optional[Sequence[int]] = None,
         batch_cap: Optional[int] = None,
-        paged_attention: bool = True,
         steps: Optional[VerifySteps] = None,
         kv_dtype: Any = "bf16",
         device: Optional[jax.Device] = None,
@@ -331,7 +319,6 @@ class EngineCore:
             # a mismatched shared bundle would fail (or recompile every
             # bucket behind warmup's back) deep inside step(); fail at the
             # constructor with the actual disagreement instead
-            want_paged = bool(paged_attention) and supports_paged_attention(model.cfg)
             mismatches = [
                 (name, got, want)
                 for name, got, want in (
@@ -340,7 +327,6 @@ class EngineCore:
                     ("greedy", steps.greedy, greedy),
                     ("temperature", steps.temperature, temperature),
                     ("attn_chunk", steps.attn_chunk, attn_chunk),
-                    ("paged_attention", steps.paged_attention, want_paged),
                     ("kv_dtype", steps.kv_dtype, self.kv_dtype),
                 )
                 if got is not want and got != want
@@ -358,10 +344,8 @@ class EngineCore:
             greedy=greedy,
             temperature=temperature,
             attn_chunk=attn_chunk,
-            paged_attention=paged_attention,
             kv_dtype=self.kv_dtype,
         )
-        self.paged_attention = self.steps.paged_attention
         if telemetry.enabled():
             # pool capacity gauges: the memory-ceiling story (ISSUE: int8
             # roughly halves bytes_per_slot, doubling slots per HBM byte)

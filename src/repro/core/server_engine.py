@@ -98,7 +98,6 @@ class ServerEngine:
         attn_chunk: int = 32,
         ctx: MeshContext = NO_MESH,
         buckets: Optional[Sequence[int]] = None,
-        paged_attention: bool = True,
         steps: Optional[VerifySteps] = None,
         kv_dtype: Any = "bf16",
         device: Optional[jax.Device] = None,
@@ -116,7 +115,6 @@ class ServerEngine:
             ctx=ctx,
             buckets=buckets,
             batch_cap=cap,
-            paged_attention=paged_attention,
             steps=steps,
             kv_dtype=kv_dtype,
             device=device,
@@ -169,10 +167,6 @@ class ServerEngine:
     @property
     def device(self):
         return self.core.device
-
-    @property
-    def paged_attention(self) -> bool:
-        return self.core.paged_attention
 
     @property
     def kv_dtype(self) -> str:

@@ -19,7 +19,7 @@ Modes:
                   the draft distribution at the rejected position
                   (``draft_q_full``); without it we fall back to sampling the
                   correction from the target distribution (documented
-                  deviation — see DESIGN.md §3 changed-assumptions table).
+                  deviation from exact rejection sampling).
 """
 from __future__ import annotations
 

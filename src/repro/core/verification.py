@@ -8,10 +8,9 @@ Invariant (shared with core/drafting.py):
   * commit: attention caches set ``length += n_commit``; SSM/hybrid caches
     select the per-position state checkpoint (models emit them).
 
-This module builds the jittable step functions that the dry-run lowers for
-the decode shapes and the serving engine runs: the target model's compute is
-one chunked-attention forward over (B, K+1) tokens against (B, S) caches —
-SLED's entire server-side hot loop.
+This module builds the jittable step functions that the serving engine runs:
+the target model's compute is one chunked-attention forward over (B, K+1)
+tokens against (B, S) caches — SLED's entire server-side hot loop.
 """
 from __future__ import annotations
 
@@ -41,7 +40,8 @@ def make_verify_batch(prev_token, draft_tokens, lengths, draft_q=None, seed=0):
 
 
 def verify_batch_spec(batch_size: int, k_max: int, *, sampling: bool = False):
-    """ShapeDtypeStruct stand-ins for the verification request (dry-run)."""
+    """ShapeDtypeStruct stand-ins for the verification request (lowering
+    without data)."""
     spec = {
         "tokens_in": jax.ShapeDtypeStruct((batch_size, k_max + 1), jnp.int32),
         "draft_tokens": jax.ShapeDtypeStruct((batch_size, k_max), jnp.int32),
@@ -60,14 +60,12 @@ def make_verify_step(
     greedy: bool = True,
     temperature: float = 1.0,
     attn_chunk: int = 1024,
-    uniform: bool = False,  # static padded batches: in-place cache append
 ):
     """Returns verify_step(params, cache, batch) -> (VerifyResult, cache')."""
-    kw = {"uniform": uniform} if model.cfg.family not in ("ssm", "hybrid") else {}
 
     def verify_step(params, cache, batch) -> Tuple[VerifyResult, Any]:
         h, ck_cache, _ = model.decode_forward(
-            params, cache, batch["tokens_in"], ctx, attn_chunk=attn_chunk, **kw
+            params, cache, batch["tokens_in"], ctx, attn_chunk=attn_chunk
         )
         logits = model.lm_head(params, h)  # (B, K+1, V) fp32
         key = jax.random.key(batch["seed"])
@@ -95,7 +93,6 @@ def make_paged_verify_step(
     greedy: bool = True,
     temperature: float = 1.0,
     attn_chunk: int = 1024,
-    paged_attention: bool = True,
     expert_rows: bool = False,
 ):
     """Slot-indexed verify step for continuous batching over a row pool.
@@ -107,20 +104,22 @@ def make_paged_verify_step(
     never on which devices happen to be scheduled, so heterogeneous partial
     fills reuse one executable per bucket.
 
-    Two dispatch modes (kvcache.py module note):
+    The model's cache kind picks one of two paths
+    (``kvcache.supports_paged_attention``, the module note there); the step's
+    ``slot_indexed`` attribute says which:
 
-      * ``paged_attention=True`` (default) on attention-cache families: the
-        forward runs directly against the pool — ``decode_forward(slots=)``
-        scatters the K+1 fresh K/V rows into pool rows and attention streams
-        slot-indexed chunks, so the per-round gather/scatter round-trip of
-        every cache leaf disappears; commit is an O(B) ``length`` update at
-        the slot rows (rollback stays O(1)).
-      * gather fallback (``paged_attention=False``, or any SSM/hybrid model
-        — their recurrent state leaves cannot be slot-indexed): rows are
-        gathered into a dense sub-cache, verified by the model's ordinary
-        decode_forward/commit path, and scattered back.
+      * slot-indexed, for attention and latent-attention caches: the forward
+        runs directly against the pool — ``decode_forward(slots=)`` writes
+        the K+1 fresh K/V rows into pool rows and attention streams
+        slot-indexed chunks, so no cache leaf is gathered or scattered;
+        commit is an O(B) ``length`` update at the slot rows (rollback stays
+        O(1)).
+      * gather/scatter, for SSM and hybrid caches (their recurrent state
+        leaves cannot be slot-indexed): rows are gathered into a dense
+        sub-cache, verified by the model's ordinary decode_forward/commit
+        path, and scattered back.
 
-    Padding convention (both modes): unused entries point at
+    Padding convention (both paths): unused entries point at
     ``scratch_slot`` with ``lengths = 0``; the step resets the scratch row's
     committed length so repeated padding can never walk scratch state off
     the end of the buffer.  MoE models route only the real tokens (a real
@@ -128,7 +127,7 @@ def make_paged_verify_step(
     ``expert_rows`` the result carries the rows each held expert computed
     (``VerifyResult.expert_rows``).
     """
-    use_paged = paged_attention and supports_paged_attention(model.cfg)
+    slot_indexed = supports_paged_attention(model.cfg)
     moe = model.cfg.family == "moe"
 
     def _routed(slots, batch):
@@ -180,13 +179,12 @@ def make_paged_verify_step(
         new_pool["length"] = new_pool["length"].at[scratch_slot].set(0)
         return res, new_pool
 
-    verify_step = paged_verify_step if use_paged else gather_verify_step
-    verify_step.paged_attention = use_paged  # introspection for engine/tests
+    verify_step = paged_verify_step if slot_indexed else gather_verify_step
+    verify_step.slot_indexed = slot_indexed
     return verify_step
 
 
-def make_force_extend_step(model, *, ctx: MeshContext = NO_MESH, attn_chunk: int = 1024,
-                           paged_attention: bool = True):
+def make_force_extend_step(model, *, ctx: MeshContext = NO_MESH, attn_chunk: int = 1024):
     """Slot-indexed forced cache extension (no verification, no sampling).
 
     Returns ``extend_step(params, pool, slots, tokens_in, n) -> pool'`` that
@@ -197,11 +195,11 @@ def make_force_extend_step(model, *, ctx: MeshContext = NO_MESH, attn_chunk: int
     the stream's row and verification resumes from the new tail — lossy by
     construction (that is the paper's fallback trade), but state-consistent.
 
-    Same two dispatch modes as ``make_paged_verify_step``: pool-resident
-    slot-indexed forward on attention families, gather/scatter fallback
+    The same path as ``make_paged_verify_step`` for the model's cache kind:
+    pool-resident slot-indexed forward on attention families, gather/scatter
     otherwise.
     """
-    use_paged = paged_attention and supports_paged_attention(model.cfg)
+    slot_indexed = supports_paged_attention(model.cfg)
 
     def paged_extend_step(params, pool, slots, tokens_in, n):
         base_len = jnp.take(pool["length"], slots, axis=0)
@@ -221,13 +219,13 @@ def make_force_extend_step(model, *, ctx: MeshContext = NO_MESH, attn_chunk: int
         new_sub = model.commit(ck_sub, n.astype(jnp.int32))
         return scatter_slots(pool, slots, new_sub)
 
-    extend_step = paged_extend_step if use_paged else gather_extend_step
-    extend_step.paged_attention = use_paged
+    extend_step = paged_extend_step if slot_indexed else gather_extend_step
+    extend_step.slot_indexed = slot_indexed
     return extend_step
 
 
 def make_prefill_step(model, *, ctx: MeshContext = NO_MESH, attn_chunk: int = 1024,
-                      with_frontend: bool = False, uniform: bool = False):
+                      with_frontend: bool = False):
     """Returns prefill_step(params, cache, tokens, [stub_embeds]) for serving.
 
     Leaves the cache at ``length = prompt_len - 1`` and returns the last
@@ -241,8 +239,6 @@ def make_prefill_step(model, *, ctx: MeshContext = NO_MESH, attn_chunk: int = 10
             kw["enc_frames"] = stub
         if with_frontend and model.cfg.family == "vlm":
             kw["embeds_prefix"] = stub
-        if uniform and model.cfg.family not in ("ssm", "hybrid"):
-            kw["uniform"] = True
         logits, cache = model.prefill(params, tokens[:, :-1], cache, ctx,
                                       attn_chunk=attn_chunk, **kw)
         return logits, cache, tokens[:, -1]

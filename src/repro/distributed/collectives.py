@@ -28,12 +28,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.models.layers import MeshContext, flash_attention
 
-# §Perf iteration A2: psum the flash-decoding partials in bf16 (halves the
-# per-layer combine bytes).  fp32 default — the bf16 variant loses ~3
-# decimal digits on the softmax accumulators, acceptable for greedy
-# verification (argmax), measured via `dryrun --combine-bf16`.
-COMBINE_DTYPE = None  # None -> fp32
-
 
 def sp_append_attend(
     q: jax.Array,       # (B, Sq, Hq, D) — replicated over model axis
@@ -42,7 +36,7 @@ def sp_append_attend(
     k_new: jax.Array,   # (B, Hkv, Sq, D) fresh rows (replicated)
     v_new: jax.Array,
     cache_len: jax.Array,   # (B,) committed lengths
-    start: jax.Array,       # scalar: uniform insert position (padded batch)
+    start: jax.Array,       # scalar: the batch's insert position (cache_len[0])
     ctx: MeshContext,
     *,
     causal: bool = True,
@@ -78,12 +72,9 @@ def sp_append_attend(
         # flash-decoding combine across sequence shards
         m_g = jax.lax.pmax(m, ax)
         c = jnp.exp(m - m_g)
-        cd = COMBINE_DTYPE
-        l_g = jax.lax.psum((l * c).astype(cd) if cd else l * c, ax)
-        acc_g = jax.lax.psum(
-            (acc * c[..., None]).astype(cd) if cd else acc * c[..., None], ax)
-        out = acc_g.astype(jnp.float32) / jnp.maximum(
-            l_g.astype(jnp.float32), 1e-30)[..., None]  # (B, Sq, Hkv, G, D)
+        l_g = jax.lax.psum(l * c, ax)
+        acc_g = jax.lax.psum(acc * c[..., None], ax)
+        out = acc_g / jnp.maximum(l_g, 1e-30)[..., None]  # (B, Sq, Hkv, G, D)
         return out.reshape(q.shape[0], Sq, Hq, D).astype(q.dtype), kc, vc
 
     out, kc, vc = jax.shard_map(

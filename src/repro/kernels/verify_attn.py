@@ -2,7 +2,7 @@
 
 The server's hot loop attends Sq = K+1 fresh tokens per request against a
 long KV cache.  TPU adaptation (vs the CUDA "append attention" kernels GPU
-serving engines use — DESIGN.md §3):
+serving engines use):
 
   * the MXU wants >= 8 x 128 tiles, but Sq is tiny (5).  We PACK the GQA
     group dimension into the query rows: rows = Sq * G (granite MQA: 5 x 48
@@ -35,10 +35,9 @@ can compute each K/V tile's HBM address as ``(slots[b], j, 0, 0)`` — the
 grid walks ``(B, n_blk)``, each tile carries all Hkv heads, and every chunk
 DMA reads straight out of the pool row the slot map points at.  Nothing is ever gathered into a dense
 sub-batch and nothing but the O(K+1) fresh rows is ever written back, which
-deletes the gather/scatter paging tax the engine's verify step used to pay
-(benchmarks/verify_kernel.py --engine measures it).  Duplicate slot ids are
-legal (padding rows all point at the scratch slot); their outputs are
-garbage by construction and discarded by the caller.
+deletes the gather/scatter paging tax the engine's verify step used to pay.
+Duplicate slot ids are legal (padding rows all point at the scratch slot);
+their outputs are garbage by construction and discarded by the caller.
 
 The gather path still exists for model families whose caches hold
 non-attention leaves (Mamba2 SSM state / conv windows, hybrid checkpoints):

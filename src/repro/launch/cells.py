@@ -1,7 +1,8 @@
 """Cell builder: (architecture x workload shape x mesh) -> jittable step + specs.
 
-A "cell" is one entry of the assigned 40-cell grid.  ``build_cell`` returns
-everything the dry-run (and the benchmarks) need:
+A "cell" is one (architecture, shape) entry of ``configs.base.SHAPES``.
+``build_cell`` returns everything a lowering of the sharding policy needs
+(``tests/test_sharding.py`` lowers and compiles them on a debug mesh):
 
     fn            the step function (train_step / prefill_step / verify_step)
     args          ShapeDtypeStruct pytrees for every input (no allocation)
@@ -70,7 +71,6 @@ def build_cell(
     loss_chunk: int = 512,
     greedy: bool = True,
     fsdp: Optional[bool] = None,
-    kv_bits: int = 16,
 ) -> Cell:
     model = build_model(cfg)
     mode = "train" if shape.kind == "train" else "serve"
@@ -105,7 +105,7 @@ def build_cell(
         )
         # ZeRO-2 layout: live params TP-only (replicated over data — no
         # per-microbatch FSDP gathers), opt state fully sharded; grads are
-        # pinned to the opt layout so XLA reduce-scatters them (§Perf C2).
+        # pinned to the opt layout so XLA reduce-scatters them.
         pspecs = policy.param_specs(params, fsdp=False)
         opt_pspecs = policy.param_specs(params, fsdp=True)
         step = make_train_step(model, tcfg, ctx,
@@ -136,7 +136,7 @@ def build_cell(
         cache = model.make_cache(B, S + K + 8, spec_only=True, attn_chunk=attn_chunk)
         cspecs = policy.cache_specs(cache)
         pf = verification.make_prefill_step(model, ctx=ctx, attn_chunk=attn_chunk,
-                                            with_frontend=needs_stub, uniform=True)
+                                            with_frontend=needs_stub)
         S_tok = _token_len(cfg, S)
         tokens = _sds((B, S_tok), jnp.int32)
         from jax.sharding import PartitionSpec as P
@@ -154,15 +154,11 @@ def build_cell(
                     donate=(1,))
 
     # decode: the SLED batched-verification step over a seq_len-deep cache
-    ckw = {}
-    if kv_bits == 8 and cfg.family not in ("ssm", "hybrid"):
-        ckw["kv_dtype"] = jnp.int8
-    cache = model.make_cache(B, S + K + 8, spec_only=True, attn_chunk=attn_chunk, **ckw)
+    cache = model.make_cache(B, S + K + 8, spec_only=True, attn_chunk=attn_chunk)
     cspecs = policy.cache_specs(cache)
     batch = verification.verify_batch_spec(B, K, sampling=not greedy)
     bspecs = policy.batch_specs(batch)
-    vs = verification.make_verify_step(model, ctx=ctx, greedy=greedy,
-                                       attn_chunk=attn_chunk, uniform=True)
+    vs = verification.make_verify_step(model, ctx=ctx, greedy=greedy, attn_chunk=attn_chunk)
 
     def fn(p, c, b):
         res, new_cache = vs(p, c, b)

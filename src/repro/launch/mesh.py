@@ -1,9 +1,8 @@
-"""Production meshes. A FUNCTION, not a constant: importing this module must
-never touch jax device state (smoke tests see 1 device; only dryrun.py sets
-XLA_FLAGS=--xla_force_host_platform_device_count=512 before first jax init).
+"""Device meshes. A FUNCTION, not a constant: importing this module must
+never touch jax device state (smoke tests see 1 device; the sharding tests
+set XLA_FLAGS=--xla_force_host_platform_device_count in a subprocess).
 
-Topology: TPU v5e, 16x16 chips per pod; the multi-pod mesh adds a leading
-"pod" axis across the DCN.  Axis roles:
+The multi-pod mesh adds a leading "pod" axis across the DCN.  Axis roles:
   pod   — data parallelism across pods (training grad all-reduce crosses
           DCN) / independent service areas (serving: no cross-pod traffic)
   data  — batch (requests / data-parallel replicas) + FSDP weight sharding
@@ -22,22 +21,9 @@ def _mesh(shape, axes):
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _mesh(shape, axes)
-
-
 def make_debug_mesh(n_data: int = 2, n_model: int = 2, *, multi_pod: bool = False):
     """Small mesh for CI-sized sharding tests (8 host devices)."""
     if multi_pod:
         return _mesh((2, n_data, n_model), ("pod", "data", "model"))
     return _mesh((n_data, n_model), ("data", "model"))
 
-
-def batch_axes(mesh) -> tuple:
-    return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-
-
-def model_axis(mesh) -> str:
-    return "model"

@@ -94,7 +94,6 @@ def spec_from_args(args) -> ServeSpec:
         c_th=args.c_th,
         kctl=args.kctl,
         cctl=args.cctl,
-        paged_attention=args.paged_attention,
         telemetry=args.telemetry,
     )
 
@@ -350,9 +349,6 @@ def build_parser() -> argparse.ArgumentParser:
                     help="draft-probability payload precision on the wire")
     ap.add_argument("--pipeline", action=argparse.BooleanOptionalAction, default=True,
                     help="draft ahead while a verify round is in flight")
-    ap.add_argument("--paged-attention", action=argparse.BooleanOptionalAction, default=True,
-                    help="slot-indexed verify attention straight out of the KV "
-                         "pool (gather/scatter fallback when off or unsupported)")
     ap.add_argument("--verify-timeout", type=float, default=30.0,
                     help="device-side round timeout before §III-A fallback "
                          "(generous default: first rounds pay jit compiles)")

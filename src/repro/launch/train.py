@@ -3,7 +3,7 @@
     PYTHONPATH=src python -m repro.launch.train --arch qwen2-1.5b --steps 40 \
         --smoke --fail-at 20    # inject a crash, then rerun to resume
 
-Production-mesh training is validated by launch/dryrun.py (train_4k cells);
+Sharded train steps are lowered by tests/test_sharding.py (launch/cells.py);
 this launcher runs REAL steps at reduced scale and demonstrates the
 fault-tolerance loop: periodic async checkpoints, crash -> resume with
 bitwise-identical trajectory (restart-safe data pipeline), optional elastic

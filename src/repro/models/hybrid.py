@@ -87,7 +87,7 @@ def forward(cfg, params, tokens, ctx: MeshContext = NO_MESH, *, remat=False,
     groups, tail = _split_groups(cfg, params["ssm_layers"])
 
     def ssm_body(h, lp):
-        return M2.ssd_layer_forward(cfg, lp, h, remat_inner=remat, ctx=ctx), None
+        return M2.ssd_layer_forward(cfg, lp, h, remat_inner=remat), None
 
     if remat:
         ssm_body = jax.checkpoint(ssm_body, prevent_cse=False)
@@ -119,8 +119,7 @@ def _run_cached(cfg, params, cache, tokens, ctx, attn_chunk, decode: bool):
         if decode:
             out, h_ck, c_ck = M2.ssd_layer_decode(cfg, lp, h, h0, c0)
             return out, (h_ck, c_ck)
-        out, (hf, cf) = M2.ssd_layer_forward(cfg, lp, h, h0=h0, conv0=c0,
-                                             return_state=True, ctx=ctx)
+        out, (hf, cf) = M2.ssd_layer_forward(cfg, lp, h, h0=h0, conv0=c0, return_state=True)
         return out, (hf, cf.astype(jnp.bfloat16))
 
     def group_body(h, xs):
@@ -165,8 +164,8 @@ def decode_forward(cfg, params, cache, tokens, ctx: MeshContext = NO_MESH, *,
             "slot-indexed paged attention is not supported for the 'hybrid' "
             "family: its recurrent state leaves (ssm, conv) are not position-"
             "indexed K/V, so pool rows cannot be addressed in place.  Route "
-            "this model through the gather/scatter fallback instead "
-            "(paged_attention=False, or gate on "
+            "this model through the gather/scatter fallback instead (the "
+            "verify and extend steps choose it by "
             "models.kvcache.supports_paged_attention(cfg))."
         )
     x, ssm_ck, conv_ck, new_kv = _run_cached(cfg, params, cache, tokens, ctx, attn_chunk, True)

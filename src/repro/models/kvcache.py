@@ -38,7 +38,7 @@ def init_kv_cache(
 
 
 def kv_cache_spec(num_layers, batch, max_len, num_kv_heads, head_dim, dtype=jnp.bfloat16):
-    """ShapeDtypeStruct stand-in (dry-run: no allocation)."""
+    """ShapeDtypeStruct stand-in (no allocation)."""
     shape = (num_layers, batch, num_kv_heads, max_len, head_dim)
     return {
         "k": jax.ShapeDtypeStruct(shape, dtype),
@@ -64,21 +64,21 @@ def rollback(cache: Dict[str, jax.Array], new_length: jax.Array) -> Dict[str, ja
 # gather_slots / scatter_slots / write_slot / ExportStream move the int8 rows
 # AND their scales together, bit-exactly.
 # That makes "a device's cache" a fixed set of rows, so continuous batching
-# reduces to a slot allocator over a pool of rows.  Two dispatch modes share
-# the pool:
+# reduces to a slot allocator over a pool of rows.  Two verify paths share
+# the pool, and the model's cache kind picks one (supports_paged_attention):
 #
-#   * slot-indexed (default for attention families): the verify forward runs
+#   * slot-indexed (attention and latent-attention caches): the forward runs
 #     DIRECTLY against the pool — per-row lengths come from length[slots],
 #     fresh K/V rows scatter into pool rows, and attention streams
 #     slot-indexed chunks (transformer.decode_forward(slots=...), mirrored
 #     on TPU by kernels/verify_attn.verify_attention_paged's
 #     scalar-prefetched index maps).  Pool traffic per round is one read of
 #     the scheduled rows plus an O(B * (K+1)) fresh-row write.
-#   * gather/scatter (fallback): the scheduled subset is materialised into a
-#     dense sub-batch, verified, and scattered back.  Still required for
-#     SSM/hybrid families whose recurrent state leaves (ssm, conv,
-#     checkpoints) are not position-indexed K/V — those leaves are tiny next
-#     to the attention pool, so the fallback tax is bounded.
+#   * gather/scatter (SSM and hybrid caches): the scheduled subset is
+#     materialised into a dense sub-batch, verified, and scattered back.
+#     Their recurrent state leaves (ssm, conv, checkpoints) are not
+#     position-indexed K/V — those leaves are tiny next to the attention
+#     pool, so the gather tax is bounded.
 #
 # Either way compiled shapes depend only on the bucket size — devices can
 # join, leave, or idle without recompiles.
@@ -87,8 +87,9 @@ def rollback(cache: Dict[str, jax.Array], new_length: jax.Array) -> Dict[str, ja
 def supports_paged_attention(cfg) -> bool:
     """True when every cache leaf the verify forward touches is attention-
     shaped (k/v/cross buffers, or latent attention's ckv/kpe, + length), so
-    the slot-indexed fast path can run against the pool.  SSM and hybrid caches carry recurrent state
-    leaves that must still ride the gather/scatter fallback."""
+    the slot-indexed path runs against the pool.  SSM and hybrid caches carry
+    recurrent state leaves and take the gather/scatter path.  The verify and
+    extend steps choose their path here and nowhere else."""
     return getattr(cfg, "family", None) not in ("ssm", "hybrid")
 
 

@@ -22,21 +22,8 @@ Params = Dict[str, Any]
 
 NEG_INF = -1e30  # large-negative instead of -inf: keeps fully-masked rows NaN-free
 
-# Attention lowering mode for the dry-run perf methodology (§Perf):
-#   "xla"  — the portable chunked online-softmax scan (scores round-trip HBM
-#            between the two GEMMs: what XLA does without a fused kernel)
-#   "stub" — kernel-traffic stand-in: reads K and V exactly once and writes
-#            the O-shaped output, nothing else.  This measures the step's
-#            NON-attention traffic + the Pallas kernel's intrinsic traffic
-#            (kernels/verify_attn.py streams KV once with scores resident in
-#            VMEM), so `dryrun --attn-impl stub` models the fused-kernel
-#            deployment.  GEMM FLOPs of the kernel are added analytically in
-#            EXPERIMENTS.md §Perf (the stub does no score math).
-ATTN_IMPL = "xla"
-
-# int8 KV cache (beyond-paper: halves the cache stream and fits the two
-# cells whose bf16 caches exceed v5e HBM — qwen1.5-32b decode_32k and the
-# paper's llama-70b target).  Symmetric scale per (row, kv-head), FIXED at
+# int8 KV cache (beyond-paper: halves the cache stream and the pool's bytes
+# per slot).  Symmetric scale per (row, kv-head), FIXED at
 # prompt prefill: the first append into an empty row (cache_len == 0)
 # computes scale = max(|K|)/127 over the prompt and stores it in the
 # cache's ``k_scale``/``v_scale`` leaves; every later append reuses the
@@ -45,7 +32,8 @@ ATTN_IMPL = "xla"
 # prompt (same scale) and then force-extends, so a recovered stream's int8
 # rows are bit-identical to its fault-free twin's no matter how the appends
 # were grouped.  ``KV_SCALE`` remains the static fallback for callers that
-# pass no scale.  Opt-in: make_cache(kv_dtype=jnp.int8), dryrun --kv-bits 8.
+# pass no scale.  Opt-in: make_cache(kv_dtype=jnp.int8), the engine's
+# kv_dtype='int8'.
 KV_SCALE = 0.05
 KV_SCALE_EPS = 1e-6  # floor for amax/127 so all-zero rows stay invertible
 
@@ -224,7 +212,7 @@ def flash_attention(
     scale: Optional[float] = None,
     layer: Optional[jax.Array] = None,  # stream chunks straight from a
     # stacked (L, B, H, S, D) cache buffer — avoids materialising a per-layer
-    # slice copy of the cache inside the layer loop (§Perf memory fix)
+    # slice copy of the cache inside the layer loop
     slots: Optional[jax.Array] = None,  # (B,) pool-row indices: k/v are a
     # PagedKVCache pool ((n_pool, H, S, D), or (L, n_pool, H, S, D) with
     # ``layer``) and batch entry b attends against pool row slots[b].  Each
@@ -256,22 +244,6 @@ def flash_attention(
     if scale is None:
         scale = 1.0 / math.sqrt(D)
 
-    if ATTN_IMPL == "stub":  # fused-kernel traffic model (see module note)
-        if stacked:
-            k = jax.lax.dynamic_index_in_dim(k, layer, 0, keepdims=False)
-            v = jax.lax.dynamic_index_in_dim(v, layer, 0, keepdims=False)
-        if slots is not None:
-            k = jnp.take(k, slots, axis=0)
-            v = jnp.take(v, slots, axis=0)
-        kv = (k.astype(jnp.float32).mean(axis=2)
-              + v.astype(jnp.float32).mean(axis=2))  # one pass over K+V
-        kv = jnp.repeat(kv, G, axis=1)  # (B, Hq, D)
-        out = (q.astype(jnp.float32) * kv[:, None] * scale).astype(q.dtype)
-        if return_stats:
-            m = jnp.zeros((B, Sq, Hkv, G), jnp.float32)
-            l = jnp.ones((B, Sq, Hkv, G), jnp.float32)
-            return out.reshape(B, Sq, Hkv, G, D).astype(jnp.float32), m, l
-        return out
     if q_pos is None:
         q_pos = jnp.broadcast_to(jnp.arange(Sq, dtype=jnp.int32)[None], (B, Sq))
     if kv_valid is None:
@@ -310,7 +282,7 @@ def flash_attention(
 
     def body(carry, idx):
         # stream chunks with dynamic_slice (no transposed copy of the cache:
-        # a reshape+moveaxis here doubles the HBM traffic — §Perf iter 0)
+        # a reshape+moveaxis here doubles the HBM traffic)
         m, l, acc = carry
         kb = kv_dequant(chunk_at(k, idx), k_scale)
         vb = kv_dequant(chunk_at(v, idx), v_scale)
@@ -381,10 +353,6 @@ def attention_block(
     kv_cache: Optional[Tuple[jax.Array, jax.Array]] = None,  # (B, Hkv, Smax, D) x2
     cache_len: Optional[jax.Array] = None,  # (B,)
     cache_layer: Optional[jax.Array] = None,  # kv_cache is the full (L, ...) stack
-    uniform_start: Optional[jax.Array] = None,  # scalar: all rows share the
-    # same insert position (static padded batches, the paper's planner) ->
-    # the cache append is ONE dynamic_update_slice instead of a scatter,
-    # which XLA updates in place (scatter is charged/copied full-buffer)
     causal: bool = True,
     cross_kv: Optional[Tuple[jax.Array, jax.Array]] = None,
     cross_len: Optional[jax.Array] = None,
@@ -447,16 +415,13 @@ def attention_block(
     new_cache = None
     if slots is not None and ctx.seq_shard_kv:
         raise NotImplementedError("slot-pool caches are not sequence-sharded")
-    if slots is not None and uniform_start is not None:
-        raise ValueError("slot-pool rows have per-row lengths; uniform_start does not apply")
     if kv_cache is not None and ctx.seq_shard_kv:
         # sequence-parallel cache: append + flash-decoding combine in one
         # shard_map (distributed/collectives.py)
         from repro.distributed.collectives import sp_append_attend
 
-        start = uniform_start if uniform_start is not None else cache_len[0]
         out, ck, cv = sp_append_attend(
-            q, kv_cache[0], kv_cache[1], k, v, cache_len, start, ctx,
+            q, kv_cache[0], kv_cache[1], k, v, cache_len, cache_len[0], ctx,
             causal=causal, chunk=chunk,
         )
         return out.reshape(B, S, hq * hd) @ p["wo"], (ck, cv)
@@ -483,17 +448,7 @@ def attention_block(
             row_ks = jnp.where(cache_len[:, None] == 0, kv_fresh_scale(k), _rows(ksc))
             row_vs = jnp.where(cache_len[:, None] == 0, kv_fresh_scale(v), _rows(vsc))
         kq, vq = kv_quant(k, ck.dtype, row_ks), kv_quant(v, cv.dtype, row_vs)
-        if uniform_start is not None and cache_layer is not None:
-            start = (cache_layer, jnp.int32(0), jnp.int32(0),
-                     uniform_start.astype(jnp.int32), jnp.int32(0))
-            ck = jax.lax.dynamic_update_slice(ck, kq[None], start)
-            cv = jax.lax.dynamic_update_slice(cv, vq[None], start)
-        elif uniform_start is not None:
-            start = (jnp.int32(0), jnp.int32(0), uniform_start.astype(jnp.int32),
-                     jnp.int32(0))
-            ck = jax.lax.dynamic_update_slice(ck, kq, start)
-            cv = jax.lax.dynamic_update_slice(cv, vq, start)
-        elif slots is not None:
+        if slots is not None:
             # slot pool: batch row b appends its S fresh rows into pool row
             # slots[b].  A static unroll of per-row dynamic_update_slice
             # (one (1, H, S, D) window each) is the ONLY pool

@@ -9,7 +9,7 @@ Speculative verification support: SSM/conv states cannot be rolled back by
 masking (unlike KV caches), so ``decode_forward`` emits per-position state
 CHECKPOINTS for each of the K+1 fed tokens; ``select_checkpoint`` commits the
 state at the acceptance boundary.  This is the SSM-specific piece of SLED's
-server-side verify step (DESIGN.md §4).
+server-side verify step.
 """
 from __future__ import annotations
 
@@ -103,17 +103,6 @@ def ssd_chunked(
     def to_chunks(a):
         return jnp.moveaxis(a.reshape(B, nc, Q, *a.shape[2:]), 1, 0)
 
-    if SSD_IMPL == "stub":  # single-pass traffic model of the Pallas kernel
-        w = (dt * A[None, None]) + (
-            Bm.astype(jnp.float32).mean(-1) + Cm.astype(jnp.float32).mean(-1)
-        )[..., None]
-        y = (x.astype(jnp.float32) * w[..., None]).astype(x.dtype)
-        h = (jnp.zeros((B, H, Pd, N), jnp.float32) if h0 is None
-             else h0.astype(jnp.float32)) + jnp.einsum(
-                 "bh,bhp->bhp", w.sum(1), y.astype(jnp.float32).sum(1).reshape(B, H, Pd)
-             )[..., None] * 0.0
-        return y[:, :S], h
-
     a_log = (dt * A[None, None]).astype(jnp.float32)  # (B,Sp,H) log-decays
     xs = (to_chunks(x), to_chunks(dt), to_chunks(a_log), to_chunks(Bm), to_chunks(Cm))
     h_init = jnp.zeros((B, H, Pd, N), jnp.float32) if h0 is None else h0.astype(jnp.float32)
@@ -143,40 +132,10 @@ def ssd_chunked(
     return y, h
 
 
-HEAD_SHARD = False  # §Perf B1: REFUTED under corrected cost accounting — the
-# head-shard boundary gathers (2.9 s) exceed the memory/compute win (1.5 s);
-# kept as a dryrun flag (--ssd-headshard) for the measurement record.
-
-# §Perf B2: the Pallas ssd_scan kernel keeps the per-chunk quadratic tensors
-# (seg/decay/W, each (B,Q,Q,H)) in VMEM; "stub" models its traffic: read
-# x/dt/B/C once, write y/state once.  Kernel GEMM FLOPs re-added analytically.
-SSD_IMPL = "xla"
-
-
-def _head_shard(a: jax.Array, ctx: MeshContext, axis: int):
-    """Pin an (..., H, ...) tensor to head-sharding over the model axis.
-
-    Without this, GSPMD improvises shardings for the big SSD intermediates
-    and pays repeated model-axis gathers (the mamba2 train cells were
-    collective-BOUND — §Perf iteration B1); with it the SSD math partitions
-    cleanly per head and only the layer output is re-gathered once.
-    """
-    if not HEAD_SHARD or ctx.mesh is None or a.shape[axis] % ctx.tp != 0:
-        return a
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    spec = [None] * a.ndim
-    if ctx.batch_axes and a.shape[0] % ctx.n_batch_shards == 0:
-        spec[0] = ctx.batch_axes
-    spec[axis] = "model"
-    return jax.lax.with_sharding_constraint(a, NamedSharding(ctx.mesh, P(*spec)))
-
-
 def ssd_layer_forward(
     cfg, lp: Params, x: jax.Array, *, chunk: Optional[int] = None,
     h0: Optional[jax.Array] = None, conv0: Optional[jax.Array] = None,
     return_state: bool = False, remat_inner: bool = False,
-    ctx: MeshContext = NO_MESH,
 ):
     """Full-sequence SSD block. x: (B, S, d) -> (B, S, d)."""
     B, S, d = x.shape
@@ -193,15 +152,14 @@ def ssd_layer_forward(
         new_conv = xBC[:, -(cw - 1) :]
     xBC = jax.nn.silu(conv_out.astype(jnp.float32)).astype(jnp.bfloat16)
     di = cfg.d_inner
-    x_ssm = _head_shard(xBC[..., :di].reshape(B, S, H, Pd), ctx, 2)
+    x_ssm = xBC[..., :di].reshape(B, S, H, Pd)
     Bm = xBC[..., di : di + N]
     Cm = xBC[..., di + N :]
-    dt = _head_shard(
-        jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"][None, None]), ctx, 2)
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"][None, None])
     A = -jnp.exp(lp["A_log"])
     y, h = ssd_chunked(x_ssm, dt, A, Bm, Cm, chunk or cfg.ssm_chunk, h0=h0,
                        remat=remat_inner)
-    y = _head_shard(y, ctx, 2) + lp["D"][None, None, :, None] * x_ssm.astype(jnp.float32)
+    y = y + lp["D"][None, None, :, None] * x_ssm.astype(jnp.float32)
     out = x + _gated_out(cfg, lp, y.reshape(B, S, di), z)
     if return_state:
         return out, (h, new_conv)
@@ -298,7 +256,7 @@ def forward(cfg, params, tokens, ctx: MeshContext = NO_MESH, *, remat=False, **_
     x = L.embed_lookup(params["embed"], tokens, ctx)
 
     def body(h, lp):
-        return ssd_layer_forward(cfg, lp, h, remat_inner=remat, ctx=ctx), None
+        return ssd_layer_forward(cfg, lp, h, remat_inner=remat), None
 
     if remat:
         body = jax.checkpoint(body, prevent_cse=False)
@@ -311,8 +269,7 @@ def prefill(cfg, params, tokens, cache, ctx: MeshContext = NO_MESH, **_):
 
     def body(h, xs):
         lp, h0, c0 = xs
-        out, (hf, cf) = ssd_layer_forward(cfg, lp, h, h0=h0, conv0=c0,
-                                          return_state=True, ctx=ctx)
+        out, (hf, cf) = ssd_layer_forward(cfg, lp, h, h0=h0, conv0=c0, return_state=True)
         return out, (hf, cf.astype(jnp.bfloat16))
 
     x, (hs, convs) = jax.lax.scan(body, x, (params["layers"], cache["ssm"], cache["conv"]))
@@ -333,8 +290,8 @@ def decode_forward(cfg, params, cache, tokens, ctx: MeshContext = NO_MESH, *,
             "slot-indexed paged attention is not supported for the 'ssm' family: "
             "recurrent state leaves (ssm, conv) are not position-indexed K/V, so "
             "pool rows cannot be addressed in place.  Route this model through "
-            "the gather/scatter fallback instead (paged_attention=False, or gate "
-            "on models.kvcache.supports_paged_attention(cfg))."
+            "the gather/scatter fallback instead (the verify and extend steps "
+            "choose it by models.kvcache.supports_paged_attention(cfg))."
         )
     x = L.embed_lookup(params["embed"], tokens, ctx)
 

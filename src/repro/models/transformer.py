@@ -1,7 +1,7 @@
 """Decoder-only transformer (dense / MoE / VLM) + whisper-style enc-dec.
 
 Pure functions over parameter pytrees.  Layers are stacked along a leading
-``L`` axis and executed with ``lax.scan`` so HLO size (and hence dry-run
+``L`` axis and executed with ``lax.scan`` so HLO size (and hence
 compile time) is independent of depth.  The same ``decode_forward`` serves
 prefill (S = prompt length, cache_len = 0) and SLED verification
 (S = K draft tokens + 1, cache_len = committed length).
@@ -100,7 +100,7 @@ def init_params(cfg, key, *, max_pos: int = 0) -> Params:
 
 
 def init_params_spec(cfg, *, max_pos: int = 0):
-    """ShapeDtypeStruct pytree with the same structure (dry-run, no alloc)."""
+    """ShapeDtypeStruct pytree with the same structure (no allocation)."""
     return jax.eval_shape(lambda k: init_params(cfg, k, max_pos=max_pos), jax.random.key(0))
 
 
@@ -119,7 +119,6 @@ def _block(
     kv: Optional[Tuple[jax.Array, jax.Array]] = None,
     cache_len: Optional[jax.Array] = None,
     cache_layer: Optional[jax.Array] = None,
-    uniform_start: Optional[jax.Array] = None,
     causal: bool = True,
     cross: Optional[Tuple[jax.Array, jax.Array]] = None,
     cross_len: Optional[jax.Array] = None,
@@ -142,8 +141,7 @@ def _block(
     else:
         a, new_kv = L.attention_block(
             h, lp["attn"], cfg,
-            positions=positions, kv_cache=kv, cache_len=cache_len,
-            cache_layer=cache_layer, uniform_start=uniform_start,
+            positions=positions, kv_cache=kv, cache_len=cache_len, cache_layer=cache_layer,
             causal=causal, chunk=attn_chunk, ctx=ctx, flash_remat=flash_remat,
             slots=slots, kv_scales=kv_scales,
         )
@@ -303,7 +301,6 @@ def decode_forward(
     *,
     embeds: Optional[jax.Array] = None,  # override token embedding (VLM prefill)
     attn_chunk: int = 1024,
-    uniform: bool = False,  # all rows share one insert position (padded static batch)
     slots: Optional[jax.Array] = None,  # (B,) cache is a PagedKVCache pool;
     # batch row b runs against pool row slots[b] (continuous batching)
     valid: Optional[jax.Array] = None,  # (B, S_new) real tokens: MoE routes
@@ -337,7 +334,6 @@ def decode_forward(
     cross_len = None
     if cfg.is_encdec:
         cross_len = jnp.full((B,), cache["cross_k"].shape[3], jnp.int32)
-    uniform_start = cache_len[0] if uniform else None
 
     # fori_loop carrying the FULL cache buffers, updated in place: a scan
     # with cache xs/ys double-buffers the whole KV cache (2x HBM for the
@@ -377,8 +373,7 @@ def decode_forward(
         else:
             h, new_kv, st = _block(
                 h, lp, cfg, ctx, kv=(idx(kv[0], l), idx(kv[1], l)),
-                kv_scales=(idx(kv[2], l), idx(kv[3], l)) if len(kv) > 2 else None,
-                uniform_start=uniform_start, **kw,
+                kv_scales=(idx(kv[2], l), idx(kv[3], l)) if len(kv) > 2 else None, **kw,
             )
             kv = tuple(jax.lax.dynamic_update_index_in_dim(a, n, l, 0) for a, n in zip(kv, new_kv))
         out = (h, tuple(kv), aux + st.aux)
@@ -411,7 +406,6 @@ def prefill(
     embeds_prefix: Optional[jax.Array] = None,
     enc_frames: Optional[jax.Array] = None,
     attn_chunk: int = 1024,
-    uniform: bool = False,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Fill the cache from a prompt; returns (last-position logits, cache)."""
     if cfg.is_encdec:
@@ -423,7 +417,7 @@ def prefill(
         tok_emb = params["embed"][tokens]
         embeds = jnp.concatenate([embeds_prefix.astype(tok_emb.dtype), tok_emb], axis=1)
     h, cache, _ = decode_forward(cfg, params, cache, tokens, ctx, embeds=embeds,
-                                 attn_chunk=attn_chunk, uniform=uniform)
+                                 attn_chunk=attn_chunk)
     S_total = h.shape[1]
     cache["length"] = cache["length"] + S_total
     logits = lm_head(cfg, params, h[:, -1:, :])

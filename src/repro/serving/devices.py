@@ -8,8 +8,7 @@ industrial electricity at 0.083 $/kWh [36], 3-year amortisation at 70%
 utilisation [32].
 
 The server profile covers both the paper's testbed (4x A100-80GB) and our
-deployment target (TPU v5e pod slice) — the v5e verification latency can
-also be taken directly from the dry-run roofline (benchmarks wire that up).
+deployment target (TPU v5e pod slice).
 """
 from __future__ import annotations
 

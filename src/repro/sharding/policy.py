@@ -1,6 +1,6 @@
 """Per-architecture sharding policy -> PartitionSpec pytrees.
 
-Decisions (rationale in DESIGN.md §5):
+Decisions:
   * attention: shard q heads over `model` when num_heads >= tp (GSPMD pads
     uneven head counts, e.g. qwen1.5's 40 heads); kv likewise — small-kv GQA
     (kv < tp) replicates kv heads (Megatron GQA convention) so the KV cache
@@ -11,7 +11,7 @@ Decisions (rationale in DESIGN.md §5):
     else expert-internal f-TP (granite-moe: 512/16) — matches the shard_map
     interior in models/layers.py:moe_block;
   * SSM: baseline replicates the (small) mamba weights over `model`; batch
-    shards over `data`.  The §Perf hillclimb shards SSD heads explicitly;
+    shards over `data`;
   * FSDP (train mode): every matrix leaf additionally shards its largest
     remaining dim over `data` when divisible — opt-state masters/moments use
     the same spec (ZeRO-3 layout), GSPMD inserts the per-layer all-gathers.
@@ -64,7 +64,7 @@ class Policy:
         'flat' shards the packed (H*hd) dim when H itself doesn't divide
         (qwen1.5's 40 heads): weight memory shards perfectly; GSPMD
         re-gathers activations around the per-head reshape (compute dup —
-        an explicitly documented trade, see DESIGN.md §5).
+        an explicit trade).
         """
         cfg, tp = self.cfg, self.tp
         if cfg.num_heads == 0:
@@ -176,7 +176,7 @@ class Policy:
     def param_specs(self, params_tree: Any, fsdp: Optional[bool] = None) -> Any:
         """``fsdp`` override supports the ZeRO-2 layout: live params keep TP
         only (replicated over data: no per-microbatch all-gathers), while
-        the fp32 master/moments stay fully sharded (§Perf iteration C2)."""
+        the fp32 master/moments stay fully sharded."""
         pol = self if fsdp is None else dataclasses.replace(self, fsdp=fsdp)
 
         def walk(path, leaf):

@@ -43,6 +43,10 @@ one-shot retry over a flapped link is safe.  ``Ping``/``Pong`` add a
 lightweight heartbeat (echoed seq + sender timestamp) so a partitioned or
 hung peer is detected in seconds rather than at the 120 s RPC timeout.
 ``seq=0`` means "no replay protection" (v3-style fire-once semantics).
+
+v5 drops ``PlaceAck``'s paged-attention byte: the engine's verify path
+follows from the model's cache kind, so it is no longer part of a replica's
+shape.
 """
 from __future__ import annotations
 
@@ -55,7 +59,7 @@ import numpy as np
 from repro.quant.quantize import QTensor, dequantize, quantize
 
 MAGIC = b"SL"
-VERSION = 4  # v4: per-RPC seq ids (replay-safe retries) + Ping/Pong heartbeat
+VERSION = 5  # v5: PlaceAck without the paged-attention byte
 _HEADER = struct.Struct(">2sBBI")
 HEADER_SIZE = _HEADER.size
 # v3 control frames carry serialized KV rows (ExportStream/ImportStream), so
@@ -210,7 +214,6 @@ class PlaceAck:
     k_max: int = 0
     max_len: int = 0
     greedy: bool = True
-    paged_attention: bool = True
     error: str = ""
 
 
@@ -757,13 +760,12 @@ def encode_frame(msg: Message) -> bytes:
         mtype = T_PLACE_ACK
         out.append(
             struct.pack(
-                ">BIIIBB",
+                ">BIIIB",
                 int(msg.ok),
                 msg.n_slots,
                 msg.k_max,
                 msg.max_len,
                 int(msg.greedy),
-                int(msg.paged_attention),
             )
         )
         _put_str(out, msg.error)
@@ -937,10 +939,9 @@ def decode_frame(buf: bytes) -> tuple:
         msg = PlaceReplica(spec_json=r.string())
     elif mtype == T_PLACE_ACK:
         ok, n_slots, k_max, max_len = bool(r.u8()), r.u32(), r.u32(), r.u32()
-        greedy, paged = bool(r.u8()), bool(r.u8())
         msg = PlaceAck(
             ok=ok, n_slots=n_slots, k_max=k_max, max_len=max_len,
-            greedy=greedy, paged_attention=paged, error=r.string(),
+            greedy=bool(r.u8()), error=r.string(),
         )
     elif mtype == T_ADMIT_REQ:
         seq, dev, now = r.u32(), r.u32(), r.f64()

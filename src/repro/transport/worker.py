@@ -237,7 +237,6 @@ class WorkerCore:
             k_max=self.engine.k_max,
             max_len=self.engine.pool.max_len,
             greedy=self.engine.greedy,
-            paged_attention=self.engine.paged_attention,
         )
 
 

@@ -1,6 +1,6 @@
 """Shared fixtures. NOTE: no XLA_FLAGS here — smoke tests must see 1 device;
-only launch/dryrun.py (and tests that spawn their own debug mesh via
-xla_force_host_platform_device_count in a subprocess) use more."""
+only tests that spawn their own debug mesh (xla_force_host_platform_device_count
+in a subprocess) use more."""
 import jax
 import pytest
 

@@ -9,7 +9,6 @@ the unified front door.
 """
 
 import json
-import logging
 import pathlib
 import time
 
@@ -122,11 +121,14 @@ def test_from_json_rejects_wrong_types():
 
 
 def test_build_rejects_non_runtime_codec_version():
+    """A spec naming any codec version but the one the runtime speaks is
+    refused at validation, before anything is built."""
     from repro.transport import codec
 
-    spec = _spec(backend="transport", transport=TransportSpec(codec_version=1))
-    with pytest.raises(ValueError, match=f"codec v{codec.VERSION}"):
-        System.build(spec)
+    for version in (1, codec.VERSION - 1, codec.VERSION + 1):
+        with pytest.raises(SpecError, match=f"speaks codec v{codec.VERSION} only"):
+            _spec(backend="transport", transport=TransportSpec(codec_version=version))
+    assert TransportSpec().codec_version == codec.VERSION
 
 
 def test_with_backend_normalizes():
@@ -384,20 +386,6 @@ def test_interleaved_sessions_batch_together(bundle, ref_outputs):
     assert s1.result.tokens == ref_outputs[1]
     # both streams rode shared engine batches at least once
     assert any(r.size > 1 for r in system.engine.round_log)
-
-
-def test_paged_attention_fallback_warning(caplog):
-    spec = _spec(
-        model=ModelSpec(
-            arch="mamba2-370m", vocab_size=V, target_layers=2, draft_layers=1
-        ),
-        devices=1,
-    )
-    with caplog.at_level(logging.WARNING, logger="repro.api.system"):
-        System.build(spec)
-    assert any(
-        "gather/scatter" in r.getMessage() for r in caplog.records
-    ), "System.build must name the paging fallback for SSM/hybrid families"
 
 
 def test_reference_rejects_ragged_prompts(bundle):

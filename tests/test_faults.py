@@ -137,7 +137,6 @@ def _fake_remote(engine) -> RemoteReplica:
     remote.k_max = engine.k_max
     remote.max_len = engine.pool.max_len
     remote.greedy = engine.greedy
-    remote.paged_attention = engine.paged_attention
     return remote
 
 

@@ -10,7 +10,6 @@ from hypothesis import given, settings, strategies as st
 from repro.core.scheduler import BatchPlanner, VerifyRequest
 from repro.core.speculative import PAD_TOKEN, speculative_verify
 from repro.quant.quantize import dequantize, quantize
-from repro.roofline.hlo_cost import HloCostModel
 from repro.serving.cost_model import cost_per_1k_tokens
 
 
@@ -92,22 +91,3 @@ def test_cost_model_monotonic(rate, price, watts):
     c2 = cost_per_1k_tokens(rate * 2, price, watts)
     assert c2 < c1  # faster is always cheaper per token
     assert c1 > 0
-
-
-def test_hlo_cost_model_on_known_program():
-    """Exact flop accounting through nested scans (trip-count handling)."""
-    def f(w, x):
-        def outer(c, _):
-            def body(h, wl):
-                return jnp.tanh(h @ wl), None
-            h, _ = jax.lax.scan(body, c, w)
-            return h, ()
-        h, _ = jax.lax.scan(outer, x, jnp.arange(3))
-        return h.sum()
-
-    W = jnp.zeros((4, 64, 64), jnp.float32)
-    X = jnp.zeros((8, 64), jnp.float32)
-    hlo = jax.jit(f).lower(W, X).compile().as_text()
-    t = HloCostModel(hlo).totals()
-    expect = 3 * 4 * (2 * 8 * 64 * 64)
-    assert abs(t["flops"] - expect) / expect < 0.05

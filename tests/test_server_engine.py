@@ -134,71 +134,95 @@ def test_paged_verify_subset_matches_dense(rng):
     assert int(pool2["length"][B + 1]) == 0  # scratch row reset
 
 
-def test_slot_indexed_step_matches_gather_step():
-    """The slot-indexed fast path (pool-resident K/V, fresh-row-only writes)
-    must be bit-identical to the gather/scatter fallback on every real row —
-    including scratch-slot padded entries in the batch."""
-    _, _, tm, tp = _models()
-    n_slots, k_max, bucket = 4, 4, 4  # 2 real rows + 2 scratch-padded
-    prompts = jax.random.randint(jax.random.key(7), (2, 9), 0, V)
+# (cache kind, registered config, pool dtype): every kind of verify cache
+CACHE_KINDS = [
+    ("gqa-bf16", "qwen2-1.5b", jnp.bfloat16),
+    ("gqa-int8", "qwen2-1.5b", jnp.int8),
+    ("mha", "phi3-mini-3.8b", jnp.bfloat16),
+    ("mla", "moonlight-16b-a3b", jnp.bfloat16),
+    ("ssm", "mamba2-370m", jnp.bfloat16),
+    ("hybrid", "zamba2-1.2b", jnp.bfloat16),
+]
+# leaves indexed by position, and their position axis
+_POSITION_AXIS = {"k": 3, "v": 3, "ckv": 2, "kpe": 2}
 
-    pool = tm.make_cache(n_slots + 1, 64, attn_chunk=32)
-    prefill = jax.jit(verification.make_prefill_step(tm, attn_chunk=32))
-    row_prev = []
-    for i in range(2):
-        row = tm.make_cache(1, 64, attn_chunk=32)
-        _, row, prev = prefill(tp, row, prompts[i][None, :])
-        pool = scatter_slots(pool, jnp.asarray([i + 1], jnp.int32), row)
-        row_prev.append(int(prev[0]))
 
-    drafts = jax.random.randint(jax.random.key(8), (bucket, k_max), 0, V)
+@pytest.mark.parametrize("arch,kv_dtype", [c[1:] for c in CACHE_KINDS],
+                         ids=[c[0] for c in CACHE_KINDS])
+def test_slot_indexed_step_matches_gather_step(arch, kv_dtype):
+    """The verify step the model's cache kind selects (slot-indexed for
+    attention and latent caches, gather/scatter for SSM and hybrid ones),
+    run over pool rows in a scratch-padded batch, equals the lock-step
+    verify step on ``gather_slots`` of the same rows: verdicts, committed
+    lengths and every committed cache entry, bit for bit."""
+    from repro.models.kvcache import supports_paged_attention
+
+    cfg = dataclasses.replace(get_config(arch).reduced(), vocab_size=V)
+    model = build_model(cfg)
+    params = model.init_params(jax.random.key(2))
+    n_slots, k_max, max_len = 4, 4, 64
+    ckw = {"attn_chunk": 32, "kv_dtype": kv_dtype}
+
+    pool = model.make_cache(n_slots + 1, max_len, **ckw)
+    prefill = jax.jit(verification.make_prefill_step(model, attn_chunk=32))
+    real = [2, 1]  # pool rows of the batch's real entries, out of order
+    prev = []
+    for row, n in zip(real, (9, 6)):  # two prompt lengths: per-row positions
+        prompt = jax.random.randint(jax.random.key(7 + row), (1, n), 0, V)
+        _, row_cache, p = prefill(params, model.make_cache(1, max_len, **ckw), prompt)
+        pool = scatter_slots(pool, jnp.asarray([row], jnp.int32), row_cache)
+        prev.append(int(p[0]))
+
+    drafts = jax.random.randint(jax.random.key(8), (4, k_max), 0, V)
     lengths = jnp.asarray([4, 3, 0, 0], jnp.int32)
-    slots = jnp.asarray([2, 1, n_slots, n_slots], jnp.int32)  # scratch-padded
-    batch = verification.make_verify_batch(
-        jnp.asarray([row_prev[1], row_prev[0], 0, 0], jnp.int32), drafts, lengths
-    )
+    slots = jnp.asarray(real + [n_slots, n_slots], jnp.int32)  # scratch-padded
+    batch = verification.make_verify_batch(jnp.asarray(prev + [0, 0], jnp.int32), drafts, lengths)
 
-    paged = verification.make_paged_verify_step(
-        tm, scratch_slot=n_slots, attn_chunk=32, paged_attention=True
-    )
-    gather = verification.make_paged_verify_step(
-        tm, scratch_slot=n_slots, attn_chunk=32, paged_attention=False
-    )
-    assert paged.paged_attention and not gather.paged_attention
-    res_p, pool_p = jax.jit(paged)(tp, pool, slots, batch)
-    res_g, pool_g = jax.jit(gather)(tp, pool, slots, batch)
+    step = verification.make_paged_verify_step(model, scratch_slot=n_slots, attn_chunk=32)
+    assert step.slot_indexed == supports_paged_attention(cfg)
+    res, new_pool = jax.jit(step)(params, pool, slots, batch)
 
-    np.testing.assert_array_equal(np.asarray(res_p.n_accepted), np.asarray(res_g.n_accepted))
-    np.testing.assert_array_equal(np.asarray(res_p.out_tokens), np.asarray(res_g.out_tokens))
-    np.testing.assert_array_equal(
-        np.asarray(pool_p["length"][:n_slots]), np.asarray(pool_g["length"][:n_slots])
+    lock = verification.make_verify_step(model, greedy=True, attn_chunk=32)
+    ref_batch = verification.make_verify_batch(
+        jnp.asarray(prev, jnp.int32), drafts[:2], lengths[:2]
     )
-    for row in (1, 2):
-        n = int(pool_p["length"][row])
-        np.testing.assert_array_equal(
-            np.asarray(pool_p["k"][:, row, :, : n + 1]), np.asarray(pool_g["k"][:, row, :, : n + 1])
-        )
-    # untouched row 3 stays bit-identical in both
-    np.testing.assert_array_equal(np.asarray(pool_p["k"][:, 3]), np.asarray(pool["k"][:, 3]))
-    assert int(pool_p["length"][n_slots]) == 0  # scratch reset in the fast path
+    res_ref, ref = jax.jit(lock)(params, gather_slots(pool, jnp.asarray(real, jnp.int32)), ref_batch)
+
+    np.testing.assert_array_equal(np.asarray(res.n_accepted[:2]), np.asarray(res_ref.n_accepted))
+    np.testing.assert_array_equal(np.asarray(res.out_tokens[:2]), np.asarray(res_ref.out_tokens))
+    got = gather_slots(new_pool, jnp.asarray(real, jnp.int32))
+    np.testing.assert_array_equal(np.asarray(got["length"]), np.asarray(ref["length"]))
+    assert set(got) == set(ref)
+    for name in sorted(set(got) - {"length"}):
+        for i in range(len(real)):
+            a, b = got[name][:, i], ref[name][:, i]
+            if name in _POSITION_AXIS:  # committed positions only
+                n = int(ref["length"][i])
+                a = jax.lax.slice_in_dim(a, 0, n, axis=_POSITION_AXIS[name] - 1)
+                b = jax.lax.slice_in_dim(b, 0, n, axis=_POSITION_AXIS[name] - 1)
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=f"{name} row {i}")
+    # an untouched row stays bit-identical; the scratch row is reset
+    untouched = jnp.asarray([3], jnp.int32)
+    for name, leaf in gather_slots(pool, untouched).items():
+        np.testing.assert_array_equal(np.asarray(gather_slots(new_pool, untouched)[name]),
+                                      np.asarray(leaf), err_msg=name)
+    assert int(new_pool["length"][n_slots]) == 0
 
 
 def test_ssm_family_falls_back_to_gather():
     """SSM/hybrid caches carry recurrent state leaves — the factory must
-    refuse the slot-indexed path for them even when asked for it."""
+    choose the gather/scatter path for them, and the engine builds on it."""
     from repro.models.kvcache import supports_paged_attention
 
     mcfg = dataclasses.replace(get_config("mamba2-370m").reduced(), vocab_size=V, num_layers=2)
     assert not supports_paged_attention(mcfg)
     mm = build_model(mcfg)
-    step = verification.make_paged_verify_step(
-        mm, scratch_slot=2, attn_chunk=32, paged_attention=True
-    )
-    assert not step.paged_attention
+    step = verification.make_paged_verify_step(mm, scratch_slot=2, attn_chunk=32)
+    assert not step.slot_indexed
     engine = ServerEngine(
         mm, mm.init_params(jax.random.key(0)), n_slots=2, max_len=64, k_max=4, attn_chunk=32
     )
-    assert not engine.paged_attention
+    assert not engine.steps.verify.slot_indexed and not engine.steps.extend.slot_indexed
 
 
 # ---------------------------------------------------------------------------

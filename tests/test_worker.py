@@ -118,8 +118,7 @@ def _control_messages():
     toks = np.asarray([5, 0, V - 1, 3], np.int32)
     return [
         codec.PlaceReplica(spec_json='{"backend": "engine"}'),
-        codec.PlaceAck(ok=True, n_slots=2, k_max=3, max_len=64, greedy=True,
-                       paged_attention=False),
+        codec.PlaceAck(ok=True, n_slots=2, k_max=3, max_len=64, greedy=False),
         codec.PlaceAck(ok=False, error="no: bad spec"),
         codec.AdmitRequest(device_id=7, prompt=toks, now=0.5),
         codec.AdmitReply(device_id=7, ok=True, slot=1, prev_token=-3),
@@ -216,9 +215,14 @@ def test_codec_v3_corrupt_payload_raises_codec_error():
 
 
 def test_codec_version_is_v4():
-    assert codec.VERSION == 4
-    buf = codec.encode_frame(codec.Drain())
-    assert buf[2] == 4
+    """The runtime speaks v5 only (v4 until PlaceAck lost its paged-attention
+    byte): frames carry 5, and a v4 frame is refused."""
+    assert codec.VERSION == 5
+    buf = bytearray(codec.encode_frame(codec.Drain()))
+    assert buf[2] == 5
+    buf[2] = 4
+    with pytest.raises(codec.CodecError, match="version 4"):
+        codec.decode_frame(bytes(buf))
 
 
 # ---------------------------------------------------------------------------
@@ -341,7 +345,6 @@ def _fake_remote(engine=None) -> RemoteReplica:
         remote.k_max = engine.k_max
         remote.max_len = engine.pool.max_len
         remote.greedy = engine.greedy
-        remote.paged_attention = engine.paged_attention
     return remote
 
 
